@@ -23,7 +23,7 @@ test-fast:  ## skip multi-process (subprocess-spawning) tests
 	$(PY) -m pytest tests/ -q -m "not slow"
 
 native:  ## force-rebuild the C++ layer (-Wall -Werror)
-	rm -f alluxio_tpu/native/_libatpu_native.so
+	rm -f alluxio_tpu/native/_libatpu_native*.so
 	$(PY) -c "import alluxio_tpu.native as n; assert n.lib() is not None"
 
 bench:
@@ -77,5 +77,5 @@ sdist:
 
 clean:
 	rm -rf build dist *.egg-info .pytest_cache
-	rm -f alluxio_tpu/native/_libatpu_native.so
+	rm -f alluxio_tpu/native/_libatpu_native*.so
 	find . -name __pycache__ -type d -exec rm -rf {} + 2>/dev/null || true

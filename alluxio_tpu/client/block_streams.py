@@ -145,6 +145,13 @@ class BlockInStream:
             return "ufs"
         return "remote"
 
+    def stale(self) -> bool:
+        """True when the transport under this stream dropped what it
+        serves from (an SHM segment released by the segment cache's
+        LRU): a holder of cached streams re-opens through the routing
+        ladder instead of reading it."""
+        return False
+
     def close(self) -> None:
         pass
 

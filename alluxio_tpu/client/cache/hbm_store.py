@@ -28,6 +28,26 @@ if TYPE_CHECKING:  # pragma: no cover
     import jax
 
 
+def default_device():
+    """The device an HBM tier lands on when the caller names none: the
+    first local device of JAX's default backend. A JAX that came up on
+    the CPU without having been told to (``JAX_PLATFORMS`` /
+    ``jax_platforms`` naming ``cpu``) looked for an accelerator and
+    found none; host memory is not an HBM tier, so that is an error
+    here, never a silent default. Several chips: this is chip 0 — pass
+    ``device=`` to place a tier anywhere else."""
+    import jax
+
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu" and \
+            "cpu" not in (jax.config.jax_platforms or ""):
+        raise RuntimeError(
+            "no accelerator found: JAX fell back to the CPU. Pass "
+            "device= explicitly, or set JAX_PLATFORMS=cpu to use host "
+            "memory as the device tier on purpose")
+    return dev
+
+
 class DevicePageLease:
     """A pinned device page; ``array`` is the jax.Array. Close to unpin."""
 
@@ -67,7 +87,7 @@ class HbmPageStore:
 
         self._jax = jax
         self._capacity = capacity_bytes
-        self._device = device or jax.devices()[0]
+        self._device = device or default_device()
         self._pages: Dict[PageId, "jax.Array"] = {}
         self._sizes: Dict[PageId, int] = {}
         self._pins: Dict[PageId, int] = {}

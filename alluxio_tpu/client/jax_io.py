@@ -25,12 +25,11 @@ import threading
 import weakref
 from collections import deque
 from concurrent.futures import CancelledError
-from concurrent.futures import TimeoutError as FuturesTimeoutError
 from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 
-from alluxio_tpu.client.cache.hbm_store import HbmPageStore
+from alluxio_tpu.client.cache.hbm_store import HbmPageStore, default_device
 from alluxio_tpu.client.cache.meta import PageId
 from alluxio_tpu.client.file_system import FileSystem
 from alluxio_tpu.conf import Keys
@@ -232,7 +231,7 @@ class DeviceBlockLoader:
         self._jax = jax
         self._fs = fs
         self._dtype = np.dtype(dtype)
-        self._device = device or jax.devices()[0]
+        self._device = device or default_device()
         self._hbm = HbmPageStore(hbm_bytes, self._device) \
             if hbm_bytes > 0 else None
         if prefetch is None:
@@ -598,8 +597,7 @@ class DeviceBlockLoader:
                 fut.result(timeout=5)
             except CancelledError:  # close() shut the pool first
                 pass
-            except (TimeoutError, FuturesTimeoutError):
-                # (both spellings: distinct classes before python 3.11)
+            except TimeoutError:
                 if not cancelled:
                     # a live epoch's producer is wedged (e.g. hung
                     # worker RPC): surface it, don't mask the hang

@@ -60,12 +60,32 @@ class ShmSegment:
         #: stop cache hits
         self.dead = False
 
+    @property
+    def released(self) -> bool:
+        """The map is gone (segment-cache LRU turnover, invalidation):
+        a non-empty block must be leased again, never read from here."""
+        return self.mm is None and self.length > 0
+
+    def live_map(self) -> Optional[mmap.mmap]:
+        """The mapping, or None for an empty block; a released segment
+        raises the typed fallback error — it used to read as an EMPTY
+        block, silently."""
+        mm = self.mm  # once: the transport may close it under us
+        if mm is None and self.length > 0:
+            from alluxio_tpu.shm import ShmSegmentUnavailableError
+
+            raise ShmSegmentUnavailableError(
+                f"SHM segment of block {self.block_id} was released; "
+                f"open the block again")
+        return mm
+
     def view(self, offset: int = 0, length: int = -1) -> memoryview:
-        if self.mm is None:
+        mm = self.live_map()
+        if mm is None:
             return memoryview(b"")
         end = self.length if length < 0 else min(self.length,
                                                  offset + length)
-        return memoryview(self.mm)[offset:max(offset, end)]
+        return memoryview(mm)[offset:max(offset, end)]
 
     def close_map(self) -> None:
         mm, self.mm = self.mm, None
@@ -240,6 +260,9 @@ class ShmBlockInStream(BlockInStream):
         self._worker = worker
         self._seg = seg
 
+    def stale(self) -> bool:
+        return self._seg.released
+
     def pread(self, offset: int, n: int) -> bytes:
         self._transport.touch(self._worker, self._seg)
         out = bytes(self._seg.view(offset, n))
@@ -297,8 +320,8 @@ class ShmBlockInStream(BlockInStream):
         np.cumsum(lens, out=bounds[1:])
         dest = bytearray(int(bounds[-1]))
         if len(dest):
-            loc = native._buffer_address(seg.mm) \
-                if seg.mm is not None else None
+            mm = seg.live_map()
+            loc = native._buffer_address(mm) if mm is not None else None
             if loc is None:
                 raise fastpath.NativeExecError("no segment address")
             addr, n, keep = loc
@@ -324,13 +347,14 @@ class ShmBlockInStream(BlockInStream):
     def numpy_view(self, dtype=np.uint8) -> np.ndarray:
         """Zero-copy ndarray over the shared pages — feed straight to
         ``jax.device_put`` (the DLPack/``np.frombuffer`` handoff)."""
-        if self._seg.mm is None:
+        mm = self._seg.live_map()
+        if mm is None:
             return np.empty(0, dtype=dtype)
         from alluxio_tpu.metrics import metrics
 
         metrics().counter("Client.ShmReads").inc()
         _record_read("shm", self._seg.length)
-        return np.frombuffer(self._seg.mm, dtype=dtype)
+        return np.frombuffer(mm, dtype=dtype)
 
     def close(self) -> None:
         # the segment stays cached (and leased) for the next open — the

@@ -191,6 +191,11 @@ class FileInStream:
     def _block_stream(self, index: int,
                       exclude: Optional[Set[str]] = None) -> BlockInStream:
         cached = self._streams.get(index)
+        if cached is not None and cached.stale():
+            # its transport let go of it (SHM segment LRU): open again
+            # through the ladder — the block may have left the top tier
+            self._drop_stream(index)
+            cached = None
         if cached is not None:
             if not exclude or (cached.address is None or
                                cached.address.key() not in exclude):

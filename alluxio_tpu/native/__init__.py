@@ -1,17 +1,22 @@
 """Native (C++) runtime components, built on demand with the system
 toolchain and loaded via ctypes.
 
-``lib()`` returns the loaded library or ``None`` (no g++ / build
-failure) — callers keep their pure-Python path as the fallback, so the
-native layer is an accelerator, never a dependency.
+``lib()`` returns the loaded library or ``None`` — callers keep their
+pure-Python path as the fallback, so the native layer is an
+accelerator, never a dependency. Which rung a process runs on is never
+silent: :func:`status` names it and the reason, and a build that fails
+WITH a toolchain present is logged at warning level and reported there
+(``chip_smoke.py`` treats it as an error).
 """
 
 from __future__ import annotations
 
 import ctypes
 import glob
+import hashlib
 import logging
 import os
+import shutil
 import struct
 import subprocess
 import tempfile
@@ -21,10 +26,14 @@ from typing import Dict, List, Optional, Tuple
 LOG = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "_libatpu_native.so")
+_SO_STEM = "_libatpu_native"
+_CXX = "g++"
+_CXXFLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17", "-Wall", "-Werror"]
 
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None | bool" = None  # None=untried, False=failed
+_so_path: Optional[str] = None  # the library lib() loaded
+_error: Optional[str] = None    # why lib() is None, once tried
 
 # Every ctypes prototype the Python side relies on, as the single
 # source of truth: ``lib()`` attaches these, and the atpu-lint
@@ -50,71 +59,111 @@ _PROTOTYPES: "Dict[str, Tuple[list, object]]" = {
 }
 
 
+class NativeBuildError(RuntimeError):
+    """The toolchain is present and refused the tracked sources."""
+
+
 def _sources() -> List[str]:
     """All translation units, sorted for a deterministic compile line."""
     return sorted(glob.glob(os.path.join(_DIR, "*.cpp")))
 
 
+def _build_key(srcs: List[str]) -> str:
+    """Content key of a build: the compiler's identity, the compile
+    line and every ``*.cpp``/``*.h`` byte. The library carries it in
+    its file name, so only a library built from exactly these inputs
+    is ever loaded — a clock (mtime) says nothing about a file that
+    was copied in with the checkout."""
+    h = hashlib.sha256()
+    ident = subprocess.run([_CXX, "-dumpfullversion", "-dumpmachine"],
+                           capture_output=True, timeout=30)
+    h.update(ident.stdout)
+    h.update(" ".join([_CXX] + _CXXFLAGS).encode())
+    for path in srcs + sorted(glob.glob(os.path.join(_DIR, "*.h"))):
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 def _build() -> Optional[str]:
-    """Compile the shared library when missing or stale."""
-    try:
-        srcs = _sources()
-        if not srcs:
-            return None
-        # stale when ANY source (*.cpp or *.h) is newer than the .so —
-        # keying on a single file once served a stale library after a
-        # new translation unit landed
-        deps = srcs + glob.glob(os.path.join(_DIR, "*.h"))
-        if os.path.exists(_SO) and \
-                os.path.getmtime(_SO) >= max(map(os.path.getmtime, deps)):
-            return _SO
-        # build into a temp file then rename: concurrent processes
-        # (minicluster roles) must never dlopen a half-written .so
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
-        os.close(fd)
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-               "-Wall", "-Werror", "-o", tmp] + srcs
-        r = subprocess.run(cmd, capture_output=True, timeout=120)
-        if r.returncode != 0:
-            LOG.warning("native build failed: %s", r.stderr.decode()[:500])
-            os.unlink(tmp)
-            return None
-        os.replace(tmp, _SO)
-        return _SO
-    except Exception:  # noqa: BLE001 - no toolchain: python fallback
-        LOG.debug("native build unavailable", exc_info=True)
+    """Path of the library built from the sources as they are now,
+    compiling it when no library with this build key exists. ``None``
+    without a toolchain; :class:`NativeBuildError` when the toolchain
+    is there and the compile fails."""
+    srcs = _sources()
+    if not srcs or shutil.which(_CXX) is None:
         return None
+    so = os.path.join(_DIR, f"{_SO_STEM}.{_build_key(srcs)}.so")
+    if os.path.exists(so):
+        return so
+    # build into a temp file then rename: concurrent processes
+    # (minicluster roles) must never dlopen a half-written .so
+    fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
+    os.close(fd)
+    try:
+        r = subprocess.run([_CXX] + _CXXFLAGS + ["-o", tmp] + srcs,
+                           capture_output=True, timeout=120)
+        if r.returncode != 0:
+            raise NativeBuildError(
+                f"{_CXX} rc={r.returncode}: {r.stderr.decode()[:500]}")
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    # libraries of other build keys are stale by definition
+    for old in glob.glob(os.path.join(_DIR, _SO_STEM + "*.so")):
+        if old != so:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return so
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    global _lib
+    global _lib, _so_path, _error
     if _lib is not None:
         return _lib or None
     with _lock:
         if _lib is not None:
             return _lib or None
-        so = _build()
-        if so is None:
-            _lib = False
-            return None
         try:
+            so = _build()
+            if so is None:
+                _error = f"no toolchain ({_CXX} not found)"
+                LOG.info("native layer unavailable: %s; running the "
+                         "pure-Python rung", _error)
+                _lib = False
+                return None
             handle = ctypes.CDLL(so)
-        except OSError:
-            _lib = False
-            return None
-        try:
             for name, (argtypes, restype) in _PROTOTYPES.items():
                 fn = getattr(handle, name)
                 fn.argtypes = argtypes
                 fn.restype = restype
-        except AttributeError:
-            # .so predates a declared symbol (e.g. stale build from a
-            # read-only checkout): unusable, fall back everywhere
-            LOG.warning("native library missing symbols; rebuild needed")
+        except (NativeBuildError, OSError, AttributeError,
+                subprocess.SubprocessError) as e:
+            # toolchain present, library unusable: say so — the Python
+            # rung is correct but this is not the deployment asked for
+            _error = f"{type(e).__name__}: {e}"
+            LOG.warning("native build failed, running the pure-Python "
+                        "rung: %s", _error)
             _lib = False
             return None
-        _lib = handle
+        _lib, _so_path = handle, so
         return handle
+
+
+def status() -> Dict[str, object]:
+    """Which rung this process runs on, and why: ``rung`` is
+    ``"native"`` or ``"python"``; ``toolchain`` says whether a compiler
+    was found; ``error`` is the reason for the Python rung (a build
+    failure with ``toolchain`` true is a defect, not a fallback)."""
+    handle = lib()
+    return {"rung": "native" if handle is not None else "python",
+            "library": _so_path,
+            "toolchain": shutil.which(_CXX) is not None,
+            "error": _error}
 
 
 def _buffer_address(view) -> "Tuple[int, int, object] | None":
@@ -254,7 +303,9 @@ def exported_symbols(path: Optional[str] = None) -> Optional[List[str]]:
     dependency). Returns ``None`` when the .so is missing or not a
     64-bit little-endian ELF — used by the atpu-lint ``native-abi``
     rule to diff the C++ export surface against ``_PROTOTYPES``."""
-    so = path or (_build() if os.path.exists(_DIR) else None)
+    if path is None:
+        lib()  # builds once, or records why not
+    so = path or _so_path
     if so is None or not os.path.exists(so):
         return None
     try:

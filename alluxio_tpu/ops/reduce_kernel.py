@@ -9,8 +9,7 @@ VPU reduce with no fusion-heuristic dependence — and (b) serve as the
 repo's reference pallas pattern (guide: ``pallas_guide.md`` grid/
 BlockSpec pipelining).
 
-Falls back cleanly: callers use ``available()`` and keep the jnp path
-(e.g. ``bench.py``) when pallas/TPU is absent.
+Mosaic compiles it on a TPU; anywhere else pass ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -19,20 +18,13 @@ _LANES = 1024  # 8x128 VPU tile multiples
 _ROWS = 512    # rows per grid step: 512x1024 int32 = 2 MiB VMEM/block
 # Candidate block heights for calibration: at 819 GB/s a 2 MiB block is
 # only ~2.6 us of DMA, so fixed per-grid-step cost can be a few percent;
-# taller blocks amortize it (16 MiB = ~20 us/step, 2x16 MiB double
-# buffer = 32 MiB of ~128 MiB VMEM). bench.py times each and keeps the
-# winner rather than guessing the sweet spot for this chip stepping.
-CALIBRATION_ROWS = (512, 1024, 2048, 4096)
-
-
-def available() -> bool:
-    try:
-        import jax
-        from jax.experimental import pallas as pl  # noqa: F401
-
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+# taller blocks amortize it. bench.py times each and keeps the winner.
+# The double-buffered block must fit Mosaic's scoped-VMEM budget (16 MiB
+# on a v5e): 2048 rows = 2 x 8 MiB is the tallest that does. 4096 rows
+# compiles in a straight-line jit but is refused inside a loop body
+# ("scoped allocation 32M, limit 16M"), which is how bench.py runs it —
+# chip_smoke.py checks every height listed here in that form.
+CALIBRATION_ROWS = (512, 1024, 2048)
 
 
 def _kernel(x_ref, s_ref, o_ref):
@@ -52,8 +44,7 @@ def scaled_sum(x, scale, *, rows: int = _ROWS, interpret: bool = False):
     """``sum(x * scale)`` for int32 ``x`` of size divisible by
     ``rows * _LANES`` (use ``pad_to_kernel_shape`` otherwise — zeros
     are reduction-neutral). Trace-time shapes, so calling this inside
-    the consumer's ``jit`` compiles it once; no module-level jax import
-    (``available()`` must stay checkable on jax-less hosts)."""
+    the consumer's ``jit`` compiles it once."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl

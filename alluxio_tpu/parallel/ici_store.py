@@ -46,21 +46,6 @@ import numpy as np
 from alluxio_tpu.parallel.mesh import DATA_AXIS, named_sharding
 
 
-def _shard_map(*args, **kwargs):
-    """shard_map across jax versions: >=0.8 top-level with ``check_vma``,
-    older experimental with ``check_rep`` (the replication check cannot
-    statically infer all_gather-produced replication either way)."""
-    try:  # jax >= 0.8
-        from jax import shard_map as sm
-
-        kwargs.setdefault("check_vma", False)
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as sm
-
-        kwargs.setdefault("check_rep", False)
-    return sm(*args, **kwargs)
-
-
 class MeshBlockCache:
     """Warm block cache sharded over a mesh axis; collective reads.
 
@@ -250,9 +235,11 @@ class MeshBlockCache:
                 return jax.lax.all_gather(
                     local, self.axis, axis=0, tiled=True)
 
-            return _shard_map(
+            # check_vma off: the check cannot infer the replication
+            # an all_gather produces
+            return jax.shard_map(
                 f, mesh=self.mesh, in_specs=P(self.axis, None),
-                out_specs=P())(x)
+                out_specs=P(), check_vma=False)(x)
 
         return _gather(cached)
 
@@ -272,9 +259,9 @@ class MeshBlockCache:
                 perm = [((d + shift) % n, d) for d in range(n)]
                 return jax.lax.ppermute(local, self.axis, perm)
 
-            return _shard_map(
+            return jax.shard_map(
                 f, mesh=self.mesh, in_specs=P(self.axis, None),
-                out_specs=P(self.axis, None))(x)
+                out_specs=P(self.axis, None), check_vma=False)(x)
 
         return _shift(cached)
 
@@ -319,9 +306,9 @@ class MeshBlockCache:
                 # O(batch) collective: merge owners' contributions
                 return jax.lax.psum(rows, self.axis)
 
-            return _shard_map(
+            return jax.shard_map(
                 f, mesh=self.mesh, in_specs=(P(self.axis, None), P()),
-                out_specs=P())(x, idx)
+                out_specs=P(), check_vma=False)(x, idx)
 
         self._batch_fns[per_dev] = _assemble
         return _assemble

@@ -32,19 +32,6 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, *, mesh,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _sm  # jax >= 0.8 (check_vma)
-
-        def shard_map(f, *, mesh, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-    except ImportError:  # pragma: no cover - older jax (check_rep)
-        from jax.experimental.shard_map import shard_map as _sme
-
-        def shard_map(f, *, mesh, in_specs, out_specs):
-            return _sme(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
-
     n_stages = mesh.shape[axis]
     n_micro = x_microbatches.shape[0]
     steps = n_stages - 1 + n_micro
@@ -88,8 +75,8 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, *, mesh,
         # result is replicated — without this, rank 0's zeros win)
         return jax.lax.psum(outs, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(param_spec, P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     return fn(stage_params, x_microbatches)

@@ -93,20 +93,11 @@ def ring_attention(q, k, v, *, mesh, axis: str = "data",
     K/V rotating over ICI. XLA overlaps each ppermute with the next
     block's einsums.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     spec = P(None, axis, None, None)
     body = functools.partial(ring_attention_local, axis_name=axis,
                              causal=causal)
-    try:
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
     return fn(q, k, v)
 
 

@@ -180,7 +180,22 @@ _LAUNCHERS = {
 }
 
 
+def _keep_off_the_accelerator() -> None:
+    """A chip belongs to one process, and that process is the client
+    that feeds a jitted step from it. No role serves from device memory,
+    so every role pins JAX to the CPU before anything can initialise a
+    backend: a role started first (``bin/alluxio-tpu-start.sh``) must
+    never be what holds the client's chip."""
+    import os
+    import sys
+
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:  # the env var is only read at import
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 def launch_process(role: str, conf: Configuration) -> int:
+    _keep_off_the_accelerator()
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(levelname)s %(name)s: %(message)s")

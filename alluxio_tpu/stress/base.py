@@ -164,9 +164,8 @@ def drive(n_threads: int, op: Callable[[int, int], int], *,
 def host_speed_stamp_ms() -> float:
     """10M-adds wall time in ms: the one host-speed calibration figure
     (CI-container CPU drifts 3-4x between allocations; GIL-bound op/s
-    rows scale ~inversely with this). Used by the suite's
-    host-calibration row and the bench's host-fallback rows under the
-    SAME key name, ``python_10m_adds_ms``."""
+    rows scale ~inversely with this). The suite's host-calibration row
+    carries it as ``python_10m_adds_ms``."""
     import time as _t
 
     t0 = _t.monotonic()

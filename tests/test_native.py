@@ -148,3 +148,41 @@ class TestJournalIntegration:
         assert log2.last_index == 20
         assert [r.index for r in log2.records] == list(range(1, 21))
         log2.close()
+
+
+class TestBuildKey:
+    """The library is trusted by what it was built from, never by its
+    clock: a stale build stays stale however new its mtime."""
+
+    @pytest.fixture()
+    def tree(self, lib, tmp_path, monkeypatch):
+        import shutil
+
+        for src in native._sources():
+            shutil.copy(src, tmp_path)
+        monkeypatch.setattr(native, "_DIR", str(tmp_path))
+        return tmp_path
+
+    def test_edit_rebuilds_even_when_the_old_library_is_newer(self, tree):
+        first = native._build()
+        assert native._build() == first  # same inputs: no rebuild
+        with open(tree / "framing.cpp", "a") as f:
+            f.write("\n// edited\n")
+        future = os.path.getmtime(first) + 3600
+        os.utime(first, (future, future))  # newer than every source
+        second = native._build()
+        assert second != first and os.path.exists(second)
+        assert not os.path.exists(first)  # other build keys are removed
+
+    def test_a_library_copied_in_is_never_loaded(self, tree):
+        (tree / "_libatpu_native.so").write_bytes(b"not built here")
+        (tree / "_libatpu_native.0123456789abcdef.so").write_bytes(b"x")
+        built = native._build()
+        assert os.path.getsize(built) > 1000
+        assert [p.name for p in tree.glob("*.so")] == \
+            [os.path.basename(built)]
+
+    def test_compile_failure_with_a_toolchain_is_an_error(self, tree):
+        (tree / "broken.cpp").write_text("this is not C++\n")
+        with pytest.raises(native.NativeBuildError):
+            native._build()

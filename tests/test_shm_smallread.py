@@ -255,6 +255,31 @@ class TestSameHostE2E:
         assert metrics().counter("Worker.ShmLeasesGranted").count == \
             granted
 
+    def test_stream_outliving_its_cached_segment_reopens(self, fs):
+        """A file stream held across the transport's LRU turnover (a
+        loader keeps one per file over epochs, with more blocks than
+        ``segment.cache.max``) opens the block again — the released
+        segment used to be served as an EMPTY block, silently. A block
+        stream held directly raises instead of reading empty."""
+        data = _patterned(4 * KB, 7)
+        fs.write_all("/shm-outlive", data, write_type="MUST_CACHE")
+        f = fs.open_file("/shm-outlive")
+        held = f.block_stream(0)
+        assert bytes(held.numpy_view()) == data
+        fs.store.shm.close()  # what LRU turnover does to every segment
+        assert held.stale()
+        for read in (held.numpy_view, held.memoryview,
+                     lambda: held.pread(0, KB),
+                     lambda: held.pread_many([0, KB], [16, 16])):
+            with pytest.raises(ShmSegmentUnavailableError):
+                read()
+        fresh = f.block_stream(0)
+        assert fresh is not held and not fresh.stale()
+        assert bytes(fresh.numpy_view()) == data
+        fs.store.shm.close()
+        assert f.pread(KB, KB) == data[KB:2 * KB]
+        f.close()
+
     def test_worker_session_cleanup_releases_leases(self, cluster):
         f2 = cluster.file_system()
         f2.write_all("/shm-bye", b"z" * KB, write_type="MUST_CACHE")

@@ -1,0 +1,15 @@
+"""The benchmark's own tests run on the CPU (four virtual devices, so
+the mesh consumer has owners): they check arithmetic, discovery and
+results, never a speed. Run: ``python -m pytest benchmark/tests -q``."""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("XLA_FLAGS",
+                      "--xla_force_host_platform_device_count=4")
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)

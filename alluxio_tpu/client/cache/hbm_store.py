@@ -84,8 +84,17 @@ class HbmPageStore:
         import jax  # deferred: control-plane processes never import jax
 
         from alluxio_tpu.client.cache.evictor import CacheEvictor
+        from alluxio_tpu.metrics import metrics
 
         self._jax = jax
+        # what the tier does that its callers cannot count around it:
+        # pages taken in, pages evicted to make room for one, adopts
+        # refused. All counted in here, under the one lock, so a window
+        # never holds more evictions than adopts of equal-size pages
+        m = metrics()
+        self._adopts = m.counter("Client.JaxHbmAdopts")
+        self._evictions = m.counter("Client.JaxHbmEvictions")
+        self._adopt_rejected = m.counter("Client.JaxHbmAdoptRejected")
         self._capacity = capacity_bytes
         self._device = device or default_device()
         self._pages: Dict[PageId, "jax.Array"] = {}
@@ -141,11 +150,13 @@ class HbmPageStore:
                 return True
             size = device_array.nbytes
             if size > self._capacity or not self._ensure_room(size):
+                self._adopt_rejected.inc()
                 return False
             self._pages[page_id] = device_array
             self._sizes[page_id] = size
             self._used += size
             self._evictor.update_on_put(page_id)
+            self._adopts.inc()
             return True
 
     def get(self, page_id: PageId) -> Optional[DevicePageLease]:
@@ -197,7 +208,8 @@ class HbmPageStore:
                                if self._pins.get(pid, 0) == 0), None)
             if victim is None:
                 return False
-            self.delete(victim)
+            if self.delete(victim):
+                self._evictions.inc()
         return True
 
     def pinned_count(self) -> int:

@@ -21,7 +21,9 @@ part: "prefetch collectives must overlap compute").
 
 from __future__ import annotations
 
+import queue
 import threading
+import time
 import weakref
 from collections import deque
 from concurrent.futures import CancelledError
@@ -36,7 +38,7 @@ from alluxio_tpu.conf import Keys
 from alluxio_tpu.metrics import metrics
 from alluxio_tpu.metrics.stall import (BUCKET_ADVICE, SIZE_BUCKETS,
                                        STALL_BUCKETS, size_bucket)
-from alluxio_tpu.utils.tracing import annotate, current_span
+from alluxio_tpu.utils.tracing import current_span, tracer
 
 
 #: live StepStats instances backing the ONE process-level
@@ -247,6 +249,9 @@ class DeviceBlockLoader:
         self._svc = prefetch_service
         self._epoch_counter = 0
         self._m = metrics()
+        #: time the producer spent blocked on a FULL queue (reads 0, not
+        #: "absent", for a loader whose producer never was)
+        self._blocked_us = self._m.counter("Client.JaxProducerBlockedUs")
         #: input doctor: per-tier wait attribution for this loader
         self.step_stats = StepStats()
         #: flat list of (path, block_index, page_id)
@@ -422,10 +427,11 @@ class DeviceBlockLoader:
         executor: the queue is drained, the producer's streams closed,
         and the ``loader-host-prefetch`` thread joined before control
         returns — nothing leaks waiting for ``close()``."""
-        import time as _time
-        import queue as _q
+        from alluxio_tpu import native
 
-        q: _q.Queue = _q.Queue(maxsize=max(1, self._prefetch) + 1)
+        span = tracer().span
+        has_native = native.lib() is not None
+        q: queue.Queue = queue.Queue(maxsize=max(1, self._prefetch) + 1)
         stop = threading.Event()
         retire = threading.Event()
         SENTINEL = object()
@@ -459,17 +465,22 @@ class DeviceBlockLoader:
                         # last consume off the new epoch's cursor.
                         outcome = self._svc.on_consume(ref,
                                                        generation=gen)
-                        t0 = _time.monotonic()
-                    with annotate("atpu.loader.host_read"):
-                        host = self._host_bytes(path, index)
+                        t0 = time.monotonic()
+                    with span("atpu.loader.host_read"):
+                        # stream cache, block_stream, lease, map
+                        with span("atpu.loader.open_block") as sp:
+                            host = self._host_bytes(path, index)
+                            bucket = getattr(self._tls, "last_bucket",
+                                             "unknown")
+                            if sp is not None:
+                                sp.tags["bucket"] = bucket
                         if host.size:
                             # pre-fault mmap pages off the transfer
                             # thread's clock (native: GIL-free touch)
-                            from alluxio_tpu import native
-
-                            if not native.prefault(host):
-                                host[::4096].max()
-                    bucket = getattr(self._tls, "last_bucket", "unknown")
+                            with span("atpu.loader.prefault",
+                                      bytes=host.nbytes, native=has_native):
+                                if not native.prefault(host):
+                                    host[::4096].max()
                     if ref is not None:
                         if outcome != "stale":
                             # a stale (superseded-epoch) consume must
@@ -483,7 +494,7 @@ class DeviceBlockLoader:
                             # waited for data clairvoyance should have
                             # had resident already
                             self._svc.record_stall(
-                                _time.monotonic() - t0)
+                                time.monotonic() - t0)
                     self._put(q, stop, (pid, host, False, bucket,
                                         host.nbytes))
             except BaseException as e:  # noqa: BLE001 re-raised in consumer
@@ -527,28 +538,31 @@ class DeviceBlockLoader:
             # input-doctor accounting: each queue wait is attributed to
             # the serving tier of the item that ends it; elapsed-since-
             # last-item bounds the rolling input-bound fraction
-            last_item_t = _time.monotonic()
+            last_item_t = time.monotonic()
             while True:
-                wait_t0 = _time.monotonic()
-                while True:
-                    try:
-                        item = q.get(timeout=0.5)
-                        break
-                    except _q.Empty:
-                        if stop.is_set():
-                            # cancelled by close()/a newer epoch(): fail
-                            # loudly — a silently-truncated epoch looks
-                            # complete downstream
-                            raise RuntimeError(
-                                "epoch cancelled: the loader was closed "
-                                "or a newer epoch() superseded this "
-                                "iterator")
+                # the consumer's side of the queue: the span covers the
+                # interval StepStats.record is given as wait_s
+                with span("atpu.loader.get_wait"):
+                    wait_t0 = time.monotonic()
+                    while True:
+                        try:
+                            item = q.get(timeout=0.5)
+                            break
+                        except queue.Empty:
+                            if stop.is_set():
+                                # cancelled by close()/a newer epoch():
+                                # fail loudly — a silently-truncated
+                                # epoch looks complete downstream
+                                raise RuntimeError(
+                                    "epoch cancelled: the loader was "
+                                    "closed or a newer epoch() "
+                                    "superseded this iterator")
+                    now = time.monotonic()
                 if item is SENTINEL:
                     break
                 if item[0] == "__error__":
                     raise item[1]
                 pid, data, on_device, bucket, nbytes = item
-                now = _time.monotonic()
                 self.step_stats.record(bucket, now - wait_t0, nbytes,
                                        now - last_item_t)
                 last_item_t = now
@@ -560,15 +574,14 @@ class DeviceBlockLoader:
                 if on_device:
                     arr = data
                 else:
-                    with annotate("atpu.loader.h2d"):
-                        sp = current_span()
+                    with span("atpu.loader.h2d") as sp:
                         if sp is None:
                             arr = self._jax.device_put(data, self._device)
                         else:
-                            t_put = _time.perf_counter()
+                            t_put = time.perf_counter()
                             arr = self._jax.device_put(data, self._device)
                             sp.phase("device_put",
-                                     (_time.perf_counter() - t_put)
+                                     (time.perf_counter() - t_put)
                                      * 1000.0)
                     if self._hbm is not None:
                         self._hbm.adopt(pid, arr)  # no second transfer
@@ -622,12 +635,10 @@ class DeviceBlockLoader:
 
     @staticmethod
     def _drain(q) -> None:
-        import queue as _q
-
         while True:
             try:
                 q.get_nowait()
-            except _q.Empty:
+            except queue.Empty:
                 break
 
     def _close_streams_dict(self, streams) -> None:
@@ -646,14 +657,32 @@ class DeviceBlockLoader:
         for f in victims:
             f.close()
 
-    @staticmethod
-    def _put(q, stop, item) -> None:
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return
-            except Exception:  # noqa: BLE001 queue.Full
-                continue
+    def _put(self, q, stop, item) -> None:
+        if stop.is_set():
+            return
+        if not q.full():
+            # the one producer is the only thread that puts: room seen
+            # is room had (no span, no counter, no clock reading)
+            q.put_nowait(item)
+            return
+        # the producer's side of the queue: blocked on a FULL queue, so
+        # the consumer sets the pace. The counter grows as the time
+        # passes (every poll), not when the wait ends: a producer parked
+        # for seconds while nobody asks must not credit them all to the
+        # instant the next ask frees it
+        t0 = time.monotonic()
+        try:
+            with tracer().span("atpu.loader.put_wait"):
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.1)
+                        return
+                    except queue.Full:
+                        now = time.monotonic()
+                        self._blocked_us.inc(int((now - t0) * 1e6))
+                        t0 = now
+        finally:
+            self._blocked_us.inc(int((time.monotonic() - t0) * 1e6))
 
     def hbm_stats(self) -> dict:
         if self._hbm is None:

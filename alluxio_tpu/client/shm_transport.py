@@ -141,29 +141,32 @@ class ShmTransport:
     def _map(self, worker: WorkerClient, block_id: int) -> ShmSegment:
         from alluxio_tpu.metrics import metrics
         from alluxio_tpu.utils import faults
-        from alluxio_tpu.utils.tracing import current_span
+        from alluxio_tpu.utils.tracing import current_span, tracer
 
-        sp = current_span()
-        t0 = time.perf_counter()
+        # the enclosing span (ring on) takes each step as a typed phase;
+        # the step's own span reaches a profiler capture, ring on or off
+        outer = current_span()
+        span = tracer().span
         # lease grant: the worker pins the block against eviction before
         # we touch the file — typed denials propagate to the router
-        lease = worker.shm_open(self._session, block_id)
-        if sp is not None:
-            sp.phase("lease_wait", (time.perf_counter() - t0) * 1000.0)
-        t1 = time.perf_counter()
+        with span("atpu.shm.lease") as sp:
+            lease = worker.shm_open(self._session, block_id)
+        if outer is not None and sp is not None:
+            outer.phase("lease_wait", sp.duration_ms)
         try:
-            if faults.armed() and \
-                    faults.injector().take_shm_map_error(self._host):
-                raise OSError(
-                    f"injected shm map fault for block {block_id}")
-            if lease["length"] > 0:
-                f = open(lease["path"], "rb")
-                try:
-                    mm = mmap.mmap(f.fileno(), 0, prot=mmap.PROT_READ)
-                finally:
-                    f.close()
-            else:
-                mm = None
+            with span("atpu.shm.map") as sp:
+                if faults.armed() and \
+                        faults.injector().take_shm_map_error(self._host):
+                    raise OSError(
+                        f"injected shm map fault for block {block_id}")
+                if lease["length"] > 0:
+                    f = open(lease["path"], "rb")
+                    try:
+                        mm = mmap.mmap(f.fileno(), 0, prot=mmap.PROT_READ)
+                    finally:
+                        f.close()
+                else:
+                    mm = None
         except OSError:
             metrics().counter("Client.ShmMapFailures").inc()
             # we hold a lease we cannot use; give it back now rather
@@ -173,8 +176,8 @@ class ShmTransport:
             except Exception:  # noqa: BLE001 - TTL reclaims it anyway
                 pass
             raise
-        if sp is not None:
-            sp.phase("shm_map", (time.perf_counter() - t1) * 1000.0)
+        if outer is not None and sp is not None:
+            outer.phase("shm_map", sp.duration_ms)
         seg = ShmSegment(block_id, lease["path"], lease["length"],
                          lease["lease_id"], lease["ttl_s"],
                          self._renew_fraction, mm)

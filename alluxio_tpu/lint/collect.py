@@ -3,7 +3,7 @@
 The walk classifies the string literals the registries care about:
 
 - metric EMITS      first arg of ``.counter/.meter/.timer/.register_gauge``
-- span EMITS        first arg of ``.span(`` / ``annotate(`` / ``Span(``
+- span EMITS        first arg of ``.span(`` / ``Span(``
 - phase EMITS       first arg of ``.phase(`` (typed phase events inside
                     spans; the catalog is the ``PHASES`` tuple in
                     utils/tracing.py)
@@ -35,7 +35,7 @@ METRIC_RE = re.compile(
 CONF_RE = re.compile(r"^atpu\.[a-z][a-z0-9_.{}*<>-]*$")
 
 _METRIC_EMIT_METHODS = {"counter", "meter", "timer", "register_gauge"}
-_SPAN_EMIT_CALLEES = {"span", "annotate", "Span", "start_span"}
+_SPAN_EMIT_CALLEES = {"span", "Span", "start_span"}
 _PHASE_EMIT_CALLEES = {"phase"}
 
 #: the typed-phase catalog lives here as ``PHASES = (...)``

@@ -303,10 +303,6 @@ class RemediationEngine:
                                      summary, detail or {})
             self._c_dry.inc()
         else:
-            # tracer().span, NOT utils.tracing.annotate: annotate also
-            # stamps the jax device timeline (first use imports jax —
-            # seconds — and each use builds a TraceAnnotation), and a
-            # master control loop has no device timeline to stamp
             from alluxio_tpu.utils.tracing import tracer
 
             try:

@@ -12,6 +12,8 @@ metrics to the master for cluster-level aggregation
 
 from __future__ import annotations
 
+import bisect
+import itertools
 import re
 import threading
 import time
@@ -89,7 +91,9 @@ class Timer:
         self._samples: deque = deque(maxlen=reservoir)
         self._count = 0
         self._total_s = 0.0
-        self._bucket_counts = [0] * len(self.HISTOGRAM_BUCKETS)
+        # samples per bucket (the last slot is +Inf), NOT cumulative:
+        # update() touches one slot, histogram() adds them up
+        self._bucket_hits = [0] * (len(self.HISTOGRAM_BUCKETS) + 1)
         # bucket index -> (trace_id, observed seconds, unix ts): the most
         # recent sampled trace that landed in that bucket, so the
         # exposition can link slow buckets straight to a trace
@@ -97,15 +101,13 @@ class Timer:
         self._lock = threading.Lock()
 
     def update(self, seconds: float, exemplar: Optional[str] = None) -> None:
+        # the first bound that holds the sample (len = +Inf)
+        idx = bisect.bisect_left(self.HISTOGRAM_BUCKETS, seconds)
         with self._lock:
             self._count += 1
             self._total_s += seconds
             self._samples.append(seconds)
-            idx = len(self.HISTOGRAM_BUCKETS)  # +Inf
-            for i, le in enumerate(self.HISTOGRAM_BUCKETS):
-                if seconds <= le:
-                    self._bucket_counts[i] += 1
-                    idx = min(idx, i)
+            self._bucket_hits[idx] += 1
             if exemplar is not None:
                 self._exemplars[idx] = (exemplar, seconds, time.time())
 
@@ -160,8 +162,7 @@ class Timer:
         """Lifetime cumulative bucket counts plus (sum, count) — one
         consistent monotonic series for Prometheus exposition."""
         with self._lock:
-            counts = list(self._bucket_counts)
-            counts.append(self._count)  # +Inf
+            counts = list(itertools.accumulate(self._bucket_hits))
             return counts, self._total_s, self._count
 
     def exemplars(self) -> "Dict[int, tuple[str, float, float]]":
@@ -312,6 +313,9 @@ _default_lock = threading.Lock()
 def metrics(instance: Optional[str] = None) -> MetricsRegistry:
     """Process-default registry (set ``instance`` on first call in a process)."""
     global _default
+    reg = _default
+    if reg is not None and instance is None:
+        return reg  # hot path (every RPC's serve timer): no lock
     with _default_lock:
         if _default is None:
             _default = MetricsRegistry(instance or "Process")

@@ -22,7 +22,7 @@ from alluxio_tpu.prefetch.oracle import BlockRef
 from alluxio_tpu.prefetch.scheduler import (
     PlacementAction, PrefetchScheduler, TIER_HBM,
 )
-from alluxio_tpu.utils.tracing import annotate
+from alluxio_tpu.utils.tracing import tracer
 
 LOG = logging.getLogger(__name__)
 
@@ -286,7 +286,7 @@ class PrefetchAgent(HeartbeatExecutor):
         self._hbm_adopt = fn
 
     def heartbeat(self) -> None:
-        with annotate("atpu.prefetch.tick"):
+        with tracer().span("atpu.prefetch.tick"):
             done, failed = self._executor.poll()
             for bid in done:
                 self._scheduler.on_loaded(bid)
@@ -297,7 +297,7 @@ class PrefetchAgent(HeartbeatExecutor):
 
     def _issue(self, action: PlacementAction) -> None:
         ref = action.ref
-        with annotate("atpu.prefetch.place"):
+        with tracer().span("atpu.prefetch.place"):
             if action.tier == TIER_HBM and self._hbm_adopt is not None:
                 if self._hbm_pool is None:
                     from concurrent.futures import ThreadPoolExecutor
@@ -314,7 +314,7 @@ class PrefetchAgent(HeartbeatExecutor):
         """HBM placement body (adopt worker thread): blocking host read
         + async device_put + page-store adopt, then the scheduler
         callback either way."""
-        with annotate("atpu.prefetch.hbm_adopt"):
+        with tracer().span("atpu.prefetch.hbm_adopt"):
             adopt = self._hbm_adopt
             try:
                 ok = adopt is not None and adopt(ref)

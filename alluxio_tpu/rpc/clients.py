@@ -728,6 +728,12 @@ class WorkerClient(_BaseClient):
         return self._call("shm_open", {"session_id": session_id,
                                        "block_id": block_id})
 
+    def get_metrics(self) -> Dict[str, float]:
+        """The worker's own registry snapshot (``Worker.*`` counters,
+        gauges and timer percentiles), pulled — not waited for on the
+        metrics heartbeat."""
+        return self._call("get_metrics", {})["metrics"]
+
     def shm_renew(self, session_id: int, lease_id: int) -> dict:
         return self._call("shm_renew", {"session_id": session_id,
                                         "lease_id": lease_id})

@@ -20,12 +20,14 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from concurrent import futures
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
 import grpc
 import msgpack
 
+from alluxio_tpu.metrics import metrics
 from alluxio_tpu.utils.exceptions import (
     AlluxioTpuError, ResourceExhaustedError, UnavailableError,
 )
@@ -153,11 +155,48 @@ def _timed_admission(sp, admission, context, span_name: str) -> None:
     sp.phase("admission", (_time.perf_counter() - t0) * 1000.0)
 
 
+class ServeTimer:
+    """The always-on per-method server timer, ring on or off (the
+    reference's ``RpcUtils`` per-method timer): handler wall time as the
+    SERVER saw it, so a client-side figure splits into stub + wire
+    against service time. One a method, built when the method is
+    registered by either unary dispatcher (the gRPC wrapper below,
+    ``rpc/fastpath.py``), so an RPC pays one ``Timer.update``. The
+    instance is the serving role, read off the service's name (an
+    in-process cluster shares one registry, so the registry's own
+    instance cannot say); the timer is looked up again only when the
+    process registry was swapped (``reset_metrics``, in tests)."""
+
+    __slots__ = ("_service", "_method", "_reg", "_timer")
+
+    def __init__(self, service: str, method: str) -> None:
+        self._service, self._method = service, method
+        self._reg = self._timer = None
+
+    def _resolve(self, reg):
+        service, method = self._service, self._method
+        if service.endswith("Worker"):
+            return reg.timer(f"Worker.RpcServeTime.{method}")
+        if service.startswith("Job"):
+            return reg.timer(f"JobMaster.RpcServeTime.{method}")
+        return reg.timer(f"Master.RpcServeTime.{method}")
+
+    def update(self, seconds: float) -> None:
+        reg = metrics()
+        if reg is not self._reg:
+            self._reg, self._timer = reg, self._resolve(reg)
+        self._timer.update(seconds)
+
+
 def _wrap_unary(fn: Callable[[dict], Any], authenticator=None,
                 span_name: str = "", admission=None) -> Callable:
+    service, _, method = span_name.rpartition(".")
+    serve_timer = ServeTimer(service, method) if method else None
+
     def handler(request: dict, context: grpc.ServicerContext):
         token = None
         trace_token = _bind_trace(context)
+        t0 = time.perf_counter()
         try:
             with tracer().span(span_name or "rpc.unary") as sp:
                 token = _bind_user(context, authenticator)
@@ -171,6 +210,8 @@ def _wrap_unary(fn: Callable[[dict], Any], authenticator=None,
             LOG.exception("unhandled error in RPC handler")
             context.abort(grpc.StatusCode.INTERNAL, f"{type(e).__name__}: {e}")
         finally:
+            if serve_timer is not None:
+                serve_timer.update(time.perf_counter() - t0)
             _unbind_user(token)
             reset_remote_parent(trace_token)
 

@@ -34,6 +34,7 @@ import socket
 import socketserver
 import struct
 import threading
+import time
 from typing import Any, Dict, Optional, Tuple
 
 import msgpack
@@ -99,9 +100,12 @@ class FastPathServer:
         self._conns_lock = threading.Lock()
 
     def add_service(self, svc) -> None:
+        from alluxio_tpu.rpc.core import ServeTimer
+
         for method, (fn, kind) in svc.methods.items():
             if kind == "unary":
-                self._methods[(svc.name, method)] = fn
+                self._methods[(svc.name, method)] = (
+                    fn, ServeTimer(svc.name, method))
 
     def start(self) -> str:
         from alluxio_tpu.rpc.core import check_admission
@@ -157,7 +161,8 @@ class FastPathServer:
                         service, method, request = parts[:3]
                         # optional 4th element: the caller's traceparent
                         traceparent = parts[3] if len(parts) > 3 else None
-                        fn = methods.get((service, method))
+                        fn, serve_timer = methods.get(
+                            (service, method), (None, None))
                         if fn is None:
                             _send_frame(self.connection, {"err": {
                                 "code": "UNIMPLEMENTED",
@@ -174,6 +179,7 @@ class FastPathServer:
                             # tracing must see fastpath RPCs too, joined
                             # to the caller's trace
                             trace_token = bind_remote_parent(traceparent)
+                            t0 = time.perf_counter()
                             try:
                                 with tracer().span(f"{service}.{method}"):
                                     # admission parity too: a local
@@ -185,6 +191,8 @@ class FastPathServer:
                                         principal_hint=principal_hint)
                                     result = fn(request or {})
                             finally:
+                                serve_timer.update(
+                                    time.perf_counter() - t0)
                                 reset_remote_parent(trace_token)
                             _send_frame(self.connection, {"ok": result})
                         except AlluxioTpuError as e:

@@ -378,4 +378,14 @@ def worker_service(worker: BlockWorker) -> ServiceDefinition:
         return {}
 
     svc.unary("cleanup_session", cleanup_session)
+
+    def get_metrics(req: dict) -> dict:
+        """This worker's own registry, as ``get_metrics`` on the master
+        serves the master's: timers with their percentiles, which the
+        metrics heartbeat's ``Cluster.*`` roll-up drops."""
+        from alluxio_tpu.metrics import metrics
+
+        return {"metrics": metrics().snapshot()}
+
+    svc.unary("get_metrics", get_metrics)
     return svc

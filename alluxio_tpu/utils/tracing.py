@@ -1,13 +1,23 @@
 """Lightweight distributed span tracing + device-profiler bridge.
 
 Re-design of the reference's tracing/profiling surface (SURVEY §5.1:
-opentelemetry-style server spans + worker-side profiling hooks): a
-process-local ring of recent spans with nesting via contextvars, cheap
-enough to leave compiled in — recording is O(1) deque appends gated on
-one bool — plus the TPU side: ``device_trace`` wraps
-``jax.profiler.start_trace`` (xprof capture: MXU occupancy, HBM reads,
-ICI traffic) and ``annotate`` threads host-span names onto the device
-timeline so loader stages line up with XLA ops in the trace viewer.
+opentelemetry-style server spans + worker-side profiling hooks). ONE
+primitive, ``tracer().span(name, **tags)``, with two independent sinks:
+
+- the **ring**: a process-local deque of recent spans with nesting via
+  contextvars, cheap enough to leave compiled in — recording is O(1)
+  deque appends gated on one bool (``atpu.trace.enabled``);
+- the **device timeline**: in a process that has ALREADY imported
+  ``jax`` (this module never imports it, so role processes stay as
+  they are) every span also enters a
+  ``jax.profiler.TraceAnnotation(name, unix_ns=time.time_ns(), **tags)``.
+  Outside a profiler capture that is a no-op C object; inside one
+  (``jax.profiler.start_trace``, xprof's capture: MXU occupancy, HBM
+  reads, ICI traffic) the span lands on the ``/host:CPU`` plane of the
+  ``.xplane.pb``, on the clock of the device's ``XLA Ops`` line, ring
+  on or off. The ``unix_ns`` stat anchors that clock to
+  ``CLOCK_REALTIME``: :func:`to_trace_clock` lays any ring span of this
+  host (client, or worker/master spans out of ``get_trace``) onto it.
 
 Cross-process stitching: every span carries a W3C-traceparent-style
 context (``trace_id``, parent ``span_id``, sampled flag). Client stubs
@@ -29,10 +39,11 @@ import contextvars
 import os
 import random
 import re
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Dict, Iterator, List, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 _current_span: contextvars.ContextVar = contextvars.ContextVar(
     "atpu_span", default=None)
@@ -156,18 +167,23 @@ def reset_remote_parent(token) -> None:
 
 
 class Span:
-    __slots__ = ("name", "start_ms", "duration_ms", "parent", "span_id",
-                 "trace_id", "sampled", "tags", "thread", "error",
-                 "phases")
+    __slots__ = ("name", "start_ns", "start_ms", "duration_ms", "parent",
+                 "span_id", "trace_id", "sampled", "tags", "thread",
+                 "error", "phases")
 
     def __init__(self, name: str, span_id: str, parent: Optional[str],
-                 trace_id: str, sampled: bool = True) -> None:
+                 trace_id: str, sampled: bool = True,
+                 start_ns: Optional[int] = None) -> None:
         self.name = name
         self.span_id = span_id
         self.parent = parent
         self.trace_id = trace_id
         self.sampled = sampled
-        self.start_ms = time.time() * 1000.0
+        #: CLOCK_REALTIME in ns — what ``to_trace_clock`` maps onto a
+        #: profiler capture (``start_ms`` is the same reading, for the
+        #: wire format's older consumers)
+        self.start_ns = time.time_ns() if start_ns is None else start_ns
+        self.start_ms = self.start_ns / 1e6
         self.duration_ms: Optional[float] = None
         self.tags: Dict[str, str] = {}
         self.thread = threading.current_thread().name
@@ -192,6 +208,7 @@ class Span:
             "name": self.name, "span_id": self.span_id,
             "parent": self.parent, "trace_id": self.trace_id,
             "start_ms": round(self.start_ms, 3),
+            "start_ns": self.start_ns,
             "duration_ms": None if self.duration_ms is None
             else round(self.duration_ms, 3),
             "thread": self.thread, "tags": self.tags,
@@ -226,7 +243,12 @@ class Tracer:
         return rate >= 1.0 or (rate > 0.0 and random.random() < rate)
 
     def span(self, name: str, **tags: str):
-        """Context manager recording one span (no-op when disabled)."""
+        """Context manager for one span — the ONLY way to open one. It
+        yields the ring :class:`Span`, or None when the ring is off (the
+        device-timeline sink is entered either way, see the module
+        docstring). ``tags`` are what is known at entry; a tag known
+        only at exit goes on the yielded span (``sp.tags[...]``) and so
+        reaches the ring alone."""
         return _SpanCtx(self, name, tags)
 
     def record(self, span: Span) -> None:
@@ -262,8 +284,28 @@ class Tracer:
         self._ring.clear()
 
 
+#: ``jax.profiler.TraceAnnotation`` once this process has imported jax
+#: (None until then: looked up again on the next span, never imported)
+_TA = None
+
+
+def _trace_annotation():
+    """The device-timeline sink's class, or None in a process that has
+    not imported jax (or is only part-way through importing it)."""
+    global _TA
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        _TA = jax.profiler.TraceAnnotation
+    except AttributeError:  # another thread is mid-import
+        return None
+    return _TA
+
+
 class _SpanCtx:
-    __slots__ = ("_tracer", "_name", "_tags", "_span", "_token", "_t0")
+    __slots__ = ("_tracer", "_name", "_tags", "_span", "_token", "_t0",
+                 "_dev")
 
     def __init__(self, tracer: Tracer, name: str,
                  tags: Dict[str, str]) -> None:
@@ -271,9 +313,17 @@ class _SpanCtx:
         self._name = name
         self._tags = tags
         self._span: Optional[Span] = None
-        self._token = None
 
     def __enter__(self) -> Optional[Span]:
+        now_ns = time.time_ns()
+        ta = _TA or _trace_annotation()
+        if ta is None:
+            self._dev = None
+        else:
+            # entered first, left last: the trace twin covers the ring
+            # twin; unix_ns is the clock anchor (to_trace_clock)
+            self._dev = ta(self._name, unix_ns=now_ns, **self._tags)
+            self._dev.__enter__()
         if not self._tracer.enabled:
             return None
         parent = _current_span.get()
@@ -289,7 +339,7 @@ class _SpanCtx:
                 trace_id, parent_id = new_trace_id(), None
                 sampled = self._tracer._sample()
         self._span = Span(self._name, new_span_id(), parent_id,
-                          trace_id, sampled)
+                          trace_id, sampled, start_ns=now_ns)
         if self._tags:
             self._span.tags.update(
                 {k: str(v) for k, v in self._tags.items()})
@@ -306,6 +356,8 @@ class _SpanCtx:
             _current_span.reset(self._token)
             if self._span.sampled:
                 self._tracer.record(self._span)
+        if self._dev is not None:
+            self._dev.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -459,57 +511,21 @@ def summarize_traces(spans: List[dict]) -> List[dict]:
     return list(traces.values())
 
 
-# -- device-side (TPU) bridge ------------------------------------------------
-class device_trace:
-    """Capture an xprof/TensorBoard trace of everything the device does
-    inside the block (compiled op timeline, HBM traffic). Usage::
-
-        with device_trace("/tmp/xprof"):
-            train_step(...)
-            jax.block_until_ready(loss)
-    """
-
-    def __init__(self, log_dir: str) -> None:
-        self._dir = log_dir
-
-    def __enter__(self) -> "device_trace":
-        import jax
-
-        jax.profiler.start_trace(self._dir)
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        import jax
-
-        jax.profiler.stop_trace()
-        return False
-
-
-_TA = None  # resolved TraceAnnotation class (False = jax unavailable)
-
-
-def annotate(name: str):
-    """Host-span name on the DEVICE timeline (shows up in xprof around
-    whatever the annotated host code dispatches). Also records a host
-    span when tracing is enabled, so host and device views correlate.
-    The jax lookup is resolved once; per-call cost is one class
-    construction (a no-op C object outside an active capture)."""
-    import contextlib
-
-    global _TA
-    if _TA is None:
-        try:
-            import jax
-
-            _TA = jax.profiler.TraceAnnotation
-        except Exception:  # noqa: BLE001 - no jax in control-plane procs
-            _TA = False
-    dev = _TA(name) if _TA else contextlib.nullcontext()
-
-    @contextlib.contextmanager
-    def both() -> Iterator[None]:
-        with _TRACER.span(name):
-            with dev:
-                yield
-
-    return both()
+def to_trace_clock(spans: List[dict], anchor_start_ns: float,
+                   anchor_unix_ns: int) -> List[dict]:
+    """Lay ring spans onto a profiler capture's clock. Any event the
+    device-timeline sink wrote into the capture is an anchor: its
+    ``start_ns`` (ns from the capture's start) and its ``unix_ns`` stat
+    (``CLOCK_REALTIME`` at the same instant) give the offset between
+    the two clocks. Returns copies of ``spans`` (``Span.to_dict`` /
+    ``get_trace`` dicts of THIS host: client ring, or a role's spans)
+    with ``trace_start_ns`` added; ``start_ns`` is used where a span
+    has it, else the 3-decimal ``start_ms``."""
+    offset = anchor_start_ns - anchor_unix_ns
+    out = []
+    for s in spans:
+        ns = s.get("start_ns")
+        if ns is None:
+            ns = s["start_ms"] * 1e6
+        out.append({**s, "trace_start_ns": ns + offset})
+    return out

@@ -176,6 +176,10 @@ class TieredBlockStore:
         self._alloc_lock = threading.RLock()
         self._listeners: List[Callable[[str, int], None]] = []
         self._m = metrics()
+        # what the tier displaces reads 0 from the start, not "absent":
+        # a pull (get_metrics) must tell "none" from "not counted"
+        self._evicted = self._m.counter("Worker.BlocksEvicted")
+        self._demoted = self._m.counter("Worker.BlocksDemoted")
 
     # -- observability ------------------------------------------------------
     def add_listener(self, fn: Callable[[str, int], None]) -> None:
@@ -532,9 +536,10 @@ class TieredBlockStore:
                 if not demoted:
                     self.annotator.on_remove(bid)
                     self._emit("evicted", bid)
-                    self._m.counter("Worker.BlocksEvicted").inc()
+                    self._evicted.inc()
                 else:
                     self._emit("moved", bid)
+                    self._demoted.inc()
             finally:
                 lock.close()
         return freed

@@ -63,6 +63,7 @@ def test_legs_tiny_with_real_role_processes(tmp_path, capsys):
         facts[tag] = json.loads(payload)
     assert facts["resident"]["epoch1"] == {
         "Client.JaxShortCircuitBlocks": n,
+        "Client.JaxHbmAdopts": n,
         "Client.BytesRead.shm": n * block}
     assert facts["resident"]["epoch2"] == {"Client.JaxHbmHits": n}
     assert facts["evict"]["high_water"] <= facts["evict"]["capacity"]

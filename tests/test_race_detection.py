@@ -279,8 +279,9 @@ class TestTracing:
             assert any(n.endswith(".create_file") for n in names), names
         set_tracing_enabled(False)
 
-    def test_annotate_without_device_session(self):
-        from alluxio_tpu.utils.tracing import annotate
+    def test_span_outside_a_capture_needs_no_profiler(self):
+        import jax  # noqa: F401  the device-timeline sink is live
 
-        with annotate("host.only"):
+        with tracer().span("host.only", block=3) as sp:
             pass  # must not require an active profiler
+        assert sp is None  # ring off: the zero-cost contract holds

@@ -252,6 +252,11 @@ class DeviceBlockLoader:
         #: time the producer spent blocked on a FULL queue (reads 0, not
         #: "absent", for a loader whose producer never was)
         self._blocked_us = self._m.counter("Client.JaxProducerBlockedUs")
+        # how often the kernel mapped a missed block whole, of the
+        # non-empty host views the producer made present
+        self._prefault_blocks = self._m.counter("Client.JaxPrefaultBlocks")
+        self._prefault_populated = self._m.counter(
+            "Client.JaxPrefaultPopulated")
         #: input doctor: per-tier wait attribution for this loader
         self.step_stats = StepStats()
         #: flat list of (path, block_index, page_id)
@@ -417,11 +422,13 @@ class DeviceBlockLoader:
         """Iterate all blocks as device arrays with transfer prefetch.
 
         Two-stage pipeline: a producer thread does ALL host-side work
-        (worker RPCs, mmap setup, page pre-fault) ahead of the consumer,
-        so the device_put stream never stalls on per-block host latency
-        — that serialization was the measured ~25% gap between the
-        loader and the raw device_put ceiling. The queue is bounded, and
-        an abandoned generator unblocks the producer via a stop flag.
+        (worker RPCs, mmap setup, making the mapping's pages present)
+        ahead of the consumer, so the device_put stream never stalls on
+        per-block host latency. On an HBM miss that one thread sets the
+        pace: the consumer waits on it for 98% of a scan's window
+        (``loader.get_wait_share``; PERF.md section 5 has the split).
+        The queue is bounded, and an abandoned generator unblocks the
+        producer via a stop flag.
 
         Early consumer exit (break mid-epoch) retires the producer
         executor: the queue is drained, the producer's streams closed,
@@ -475,12 +482,21 @@ class DeviceBlockLoader:
                             if sp is not None:
                                 sp.tags["bucket"] = bucket
                         if host.size:
-                            # pre-fault mmap pages off the transfer
-                            # thread's clock (native: GIL-free touch)
+                            # make the fresh mapping's pages present off
+                            # the transfer thread's clock: one kernel
+                            # call for the block, not a fault a page
                             with span("atpu.loader.prefault",
-                                      bytes=host.nbytes, native=has_native):
-                                if not native.prefault(host):
+                                      bytes=host.nbytes,
+                                      native=has_native) as sp:
+                                mode = native.prefault(host)
+                                if mode is None:
                                     host[::4096].max()
+                                    mode = "touch"
+                                if sp is not None:
+                                    sp.tags["mode"] = mode
+                            self._prefault_blocks.inc()
+                            if mode != "touch":
+                                self._prefault_populated.inc()
                     if ref is not None:
                         if outcome != "stale":
                             # a stale (superseded-epoch) consume must

@@ -50,8 +50,8 @@ _PROTOTYPES: "Dict[str, Tuple[list, object]]" = {
          ctypes.c_size_t, ctypes.POINTER(ctypes.c_uint64)],
         ctypes.c_size_t),
     "atpu_prefault": (
-        [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t],
-        ctypes.c_uint64),
+        [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int],
+        ctypes.c_int),
     "atpu_plan_exec": (
         [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_void_p,
          ctypes.c_size_t],
@@ -236,20 +236,32 @@ def crc32(data: bytes, seed: int = 0) -> Optional[int]:
     return handle.atpu_crc32(data, len(data), seed)
 
 
-def prefault(view, stride: int = 4096) -> bool:
-    """Touch one byte per page, GIL-free, readonly-safe and zero-copy.
-    True when the native path ran (False -> caller falls back)."""
+#: ``atpu_prefault``'s rungs by their C value: what the function is
+#: given to start from, and what it returns
+PREFAULT_MODES = ("touch", "lock", "populate")
+
+
+def prefault(view, first: str = "populate") -> Optional[str]:
+    """Make the pages under ``view`` present before a consumer reads
+    them, in one kernel call where the kernel has one: GIL-free,
+    readonly-safe, zero-copy. Returns the rung that did it, one of
+    :data:`PREFAULT_MODES` — ``populate`` (``MADV_POPULATE_READ``),
+    ``lock`` (``mlock`` + ``munlock``, where the kernel refuses the
+    advice with ``EINVAL``) or ``touch`` (one read a page: any other
+    refusal, and an empty view). ``None`` when the native path is
+    unavailable (the caller falls back). ``first`` names the rung to
+    start from: callers start at the top; the tests start lower to run
+    the fallbacks on a kernel that has the advice."""
     handle = lib()
     if handle is None:
-        return False
+        return None
     loc = _buffer_address(view)
     if loc is None:
-        return False
+        return None
     addr, n, keepalive = loc
-    if n:
-        handle.atpu_prefault(addr, n, stride)
+    mode = handle.atpu_prefault(addr, n, PREFAULT_MODES.index(first))
     del keepalive
-    return True
+    return PREFAULT_MODES[mode]
 
 
 # ---------------------------------------------------------------- plan exec

@@ -64,7 +64,9 @@ def test_legs_tiny_with_real_role_processes(tmp_path, capsys):
     assert facts["resident"]["epoch1"] == {
         "Client.JaxShortCircuitBlocks": n,
         "Client.JaxHbmAdopts": n,
+        "Client.JaxPrefaultBlocks": n,
         "Client.BytesRead.shm": n * block}
+    assert facts["resident"]["populated"] in (0, n)  # one kernel, one rung
     assert facts["resident"]["epoch2"] == {"Client.JaxHbmHits": n}
     assert facts["evict"]["high_water"] <= facts["evict"]["capacity"]
     assert facts["pallas"]["equal_to_xla"]

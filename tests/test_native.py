@@ -1,10 +1,14 @@
 """Native (C++) frame scanner tests: zlib/Python parity, torn-tail
 semantics, and the journal integration paths."""
 
+import mmap
 import os
+import resource
 import struct
+import tempfile
 import zlib
 
+import numpy as np
 import pytest
 
 from alluxio_tpu import native
@@ -76,18 +80,99 @@ class TestNativeScanner:
         assert len(frames) == 500 and end == len(buf)
 
     def test_prefault_readonly_numpy_view(self, lib):
-        import numpy as np
-
         raw = os.urandom(1 << 16)
         arr = np.frombuffer(raw, dtype=np.uint8)  # readonly view
         assert not arr.flags.writeable
-        assert native.prefault(arr) is True
+        assert native.prefault(arr) in native.PREFAULT_MODES
 
     def test_prefault_runs(self, lib):
-        import numpy as np
-
         arr = np.frombuffer(os.urandom(1 << 16), dtype=np.uint8).copy()
-        assert native.prefault(arr) is True
+        assert native.prefault(arr) in native.PREFAULT_MODES
+
+
+PAGE = os.sysconf("SC_PAGESIZE")
+MAPPED = 2048 * PAGE  # fault-around maps 16 pages a trap: 128 traps cold
+
+
+def _minflt() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
+@pytest.fixture()
+def fresh_mapping(tmp_path):
+    """Read-only shared mappings of a tmpfs file another handle wrote:
+    what the SHM route hands the loader. Each call maps anew, so no page
+    of it is in this process's page table yet."""
+    shm = "/dev/shm"
+    where = shm if os.access(shm, os.W_OK) else str(tmp_path)
+    fd, path = tempfile.mkstemp(prefix="atpu_test_prefault_", dir=where)
+    with os.fdopen(fd, "wb") as f:
+        f.write(os.urandom(MAPPED))
+
+    def make():
+        with open(path, "rb") as f:
+            m = mmap.mmap(f.fileno(), 0, prot=mmap.PROT_READ)
+        # the view pins the map: it is unmapped when the view dies
+        return np.frombuffer(m, dtype=np.uint8)
+
+    yield make
+    os.unlink(path)
+
+
+def _faults_of_reading(view) -> int:
+    before = _minflt()
+    _ = int(view[::PAGE].sum()) + int(view[-1])
+    return _minflt() - before
+
+
+class TestPrefault:
+    """``atpu_prefault``'s rungs: which one reports, and that each
+    leaves the pages present (no fault when they are read after)."""
+
+    @pytest.fixture(scope="class")
+    def top(self, lib):
+        # what this kernel gives a plain range: populate on Linux
+        # >= 5.14, lock or touch below that
+        mode = native.prefault(np.zeros(PAGE, np.uint8))
+        assert mode in native.PREFAULT_MODES
+        return mode
+
+    @pytest.mark.parametrize("kind", [
+        "heap-bytes", "writable-ndarray", "file-mapping",
+        "mid-page-view", "one-byte", "odd-length", "empty"])
+    def test_reports_its_mode_and_leaves_the_pages_present(
+            self, lib, top, fresh_mapping, kind):
+        view = {
+            "heap-bytes": lambda: os.urandom(3 * PAGE + 17),
+            "writable-ndarray": lambda: np.ones(5 * PAGE, np.uint8),
+            "file-mapping": fresh_mapping,
+            "mid-page-view": lambda: fresh_mapping()[PAGE + 100:-333],
+            "one-byte": lambda: fresh_mapping()[7 * PAGE + 5:][:1],
+            "odd-length": lambda: fresh_mapping()[:MAPPED - PAGE - 1],
+            "empty": lambda: fresh_mapping()[:0],
+        }[kind]()
+        mode = native.prefault(view)
+        if kind == "empty":
+            assert mode == "touch"  # nothing to ask the kernel for
+            return
+        assert mode == top
+        if kind not in ("heap-bytes", "writable-ndarray"):
+            # a fresh mapping: cold it takes a trap every 16 pages
+            assert _faults_of_reading(view) <= 2
+
+    def test_a_fresh_mapping_does_fault_without_it(self, fresh_mapping):
+        # the reading the residency assertions stand on (a file system
+        # with large folios maps far more than 16 pages a trap)
+        assert _faults_of_reading(fresh_mapping()) >= 3
+
+    @pytest.mark.parametrize("first,allowed", [
+        ("lock", {"lock", "touch"}),  # touch where RLIMIT_MEMLOCK says no
+        ("touch", {"touch"})])
+    def test_the_fallback_rungs_still_run(self, lib, fresh_mapping, first,
+                                          allowed):
+        view = fresh_mapping()
+        assert native.prefault(view, first) in allowed
+        assert _faults_of_reading(view) <= 2
 
 
 class TestConcurrency:

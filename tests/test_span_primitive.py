@@ -213,6 +213,28 @@ class TestLoaderSpans:
             assert rs <= os_ and os_ + od <= fs_ and fs_ + fd <= rs + rd
             assert st["bytes"] == BLOCK
 
+    def test_a_miss_only_epoch_counts_and_tags_every_prefault(
+            self, cluster, ring):
+        from alluxio_tpu import native
+
+        blocks0 = _count("Client.JaxPrefaultBlocks")
+        whole0 = _count("Client.JaxPrefaultPopulated")
+        loader = _loader(cluster, 5)
+        try:
+            assert len(list(loader.epoch())) == 5
+        finally:
+            loader.close()
+        modes = [s["tags"]["mode"] for s in ring.snapshot(limit=4000)
+                 if s["name"] == "atpu.loader.prefault"]
+        assert len(modes) == 5
+        assert _count("Client.JaxPrefaultBlocks") - blocks0 == 5
+        # one kernel: every block takes the same rung, and the counter
+        # of blocks the kernel mapped whole says how many did not touch
+        expected = native.prefault(np.zeros(BLOCK, np.uint8)) or "touch"
+        assert modes == [expected] * 5
+        assert _count("Client.JaxPrefaultPopulated") - whole0 == \
+            (5 if expected != "touch" else 0)
+
     @pytest.mark.parametrize("slow,blocked", [("consumer", True),
                                               ("producer", False)])
     def test_producer_blocked_time_says_which_side_paces(

@@ -21,6 +21,8 @@ part: "prefetch collectives must overlap compute").
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import queue
 import threading
 import time
@@ -734,32 +736,116 @@ class DeviceBlockLoader:
             self._hbm.close()
 
 
+@functools.lru_cache(maxsize=16)
+def _record_batch_programs(record_bytes: int, batch_size: int):
+    """The two jitted functions of :func:`batched_device_iterator`,
+    built once a ``(record_bytes, batch_size)`` and reused by every call
+    (a new pass makes no compile request; ``jit`` keys the rest on the
+    block's shape). Both carry ``atpu_record_batch`` in their name, so
+    a device trace's ``XLA Modules`` line finds every program the
+    iterator dispatches."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    batch_bytes = batch_size * record_bytes
+
+    @jax.jit
+    def atpu_record_batch(carry, carried, block):
+        """One block -> every batch slot it can fill, and the next carry.
+
+        ``carry`` is ``(batch_size, record_bytes)`` with the ``carried``
+        rows left over from earlier blocks RIGHT-aligned, so the rows of
+        the pass so far end where the block's begin and slot ``j`` is
+        ``batch_size`` rows of [carry rows | block rows] starting
+        ``carried`` rows before row ``j * batch_size`` of the block:
+        one ``dynamic_slice`` of the block's own bytes a slot, whose
+        shape never depends on ``carried`` (a traced scalar).
+
+        ``dynamic_slice`` clamps a start that would run off the end. A
+        slot is whole iff ``(j + 1) * batch_size <= carried + rows``,
+        which is exactly when its slice ends inside the block's records;
+        only the last slot can fail that, and the host, knowing
+        ``carried`` and ``rows``, never yields a slot that does."""
+        rows = block.shape[0] // record_bytes
+        slots = (batch_size - 1 + rows) // batch_size
+        carried = carried.astype(jnp.uint32)
+        flat_carry = carry.reshape(-1)
+        head = jnp.concatenate(
+            [flat_carry, block[:min(rows, batch_size) * record_bytes]])
+        out = [lax.dynamic_slice(
+            head, ((batch_size - carried) * record_bytes,), (batch_bytes,))]
+        for j in range(1, slots):
+            out.append(lax.dynamic_slice(
+                block, ((j * batch_size - carried) * record_bytes,),
+                (batch_bytes,)))
+        # the last batch_size rows of [carry rows | block rows]: whatever
+        # is left over afterwards is their tail, right-aligned again
+        if rows >= batch_size:
+            tail = block[(rows - batch_size) * record_bytes:
+                         rows * record_bytes]
+        else:
+            tail = jnp.concatenate([flat_carry[rows * record_bytes:],
+                                    block[:rows * record_bytes]])
+        shape = (batch_size, record_bytes)
+        return tuple(b.reshape(shape) for b in out), tail.reshape(shape)
+
+    @functools.partial(jax.jit, static_argnames="rows")
+    def atpu_record_batch_tail(carry, *, rows: int):
+        """The last partial batch of a pass (``drop_remainder=False``)."""
+        return carry[batch_size - rows:]
+
+    return atpu_record_batch, atpu_record_batch_tail
+
+
 def batched_device_iterator(loader: DeviceBlockLoader, *, record_bytes: int,
                             batch_size: int, drop_remainder: bool = True):
     """Group fixed-size records from block arrays into batches on device.
 
-    The reshape happens in a jitted fn so XLA fuses it with whatever decode
-    follows; records must not straddle blocks (the writer pads — same
-    contract as TFRecord sharding)."""
+    One pass of ``loader.epoch()``: batches of ``(batch_size,
+    record_bytes)`` uint8 in shard order. Records must not straddle
+    blocks (the writer pads — same contract as TFRecord sharding); the
+    padding is skipped and the rows left over at a block's end are
+    carried into the next block's first batch. The last partial batch
+    of the pass is dropped, or yielded short with
+    ``drop_remainder=False``.
+
+    One jitted program a block returns every batch slot the block can
+    fill plus the next carry (:func:`_record_batch_programs`); the
+    carried count is a traced scalar, so a pass over equal-sized shards
+    compiles one program however the carry walks, and the host knows
+    from arithmetic how many of the slots are whole."""
     import jax
-    import jax.numpy as jnp
 
-    @jax.jit
-    def to_records(block):
-        n = block.shape[0] // record_bytes
-        return block[:n * record_bytes].reshape(n, record_bytes)
-
-    pending = None
-    for block in loader.epoch():
-        recs = to_records(block)
-        if pending is not None:
-            recs = jnp.concatenate([pending, recs], axis=0)
-            pending = None
-        n_full = recs.shape[0] // batch_size
-        for b in range(n_full):
-            yield recs[b * batch_size:(b + 1) * batch_size]
-        rem = recs.shape[0] % batch_size
-        if rem:
-            pending = recs[-rem:]
-    if pending is not None and not drop_remainder:
-        yield pending
+    assemble, tail = _record_batch_programs(record_bytes, batch_size)
+    m = metrics()
+    n_batches = m.counter("Client.JaxRecordBatches")
+    n_bytes = m.counter("Client.JaxRecordBatchBytes")
+    span = tracer().span
+    batch_bytes = batch_size * record_bytes
+    carry, carried = None, 0
+    # closing the epoch with this generator retires the loader's
+    # producer at once, not when the collector finds the inner generator
+    with contextlib.closing(loader.epoch()) as blocks:
+        for block in blocks:
+            rows = block.shape[0] // record_bytes
+            if not rows:
+                continue
+            whole, left = divmod(carried + rows, batch_size)
+            with span("atpu.loader.batch_assemble", rows=rows,
+                      carry_in=carried, batches=whole):
+                if carry is None:
+                    # rows of an empty carry are never read into a
+                    # whole slot: any bytes of the right shape do
+                    carry = jax.device_put(
+                        np.zeros((batch_size, record_bytes), np.uint8),
+                        block.sharding)
+                slots, carry = assemble(carry, np.int32(carried), block)
+                n_batches.inc(whole)
+                n_bytes.inc(whole * batch_bytes)
+            carried = left
+            yield from slots[:whole]
+    if carried and not drop_remainder:
+        n_batches.inc()
+        n_bytes.inc(carried * record_bytes)
+        yield tail(carry, rows=carried)

@@ -47,8 +47,12 @@ class BlockStoreClient:
                  shm_cache_max: int = 64,
                  shm_renew_fraction: float = 0.5,
                  batch_read: Optional[BatchReadConf] = None,
-                 native_fastpath: bool = True) -> None:
-        """``streaming_chunk_size``: per-message chunk of the gRPC read
+                 native_fastpath: bool = True,
+                 fastpath_dir: Optional[str] = None) -> None:
+        """``fastpath_dir`` (``atpu.master.fastpath.dir``): where a
+        same-host worker's RPC socket is probed, the ONE directory the
+        master clients probe too; None = the servers' default.
+        ``streaming_chunk_size``: per-message chunk of the gRPC read
         streams (``atpu.user.streaming.reader.chunk.size.bytes``);
         ``streaming_writer_chunk_size``: per-message chunk of the write
         stream (``atpu.user.streaming.writer.chunk.size.bytes``);
@@ -97,6 +101,7 @@ class BlockStoreClient:
         self.last_write_worker: Optional[WorkerClient] = None
         self.last_write_address: Optional[WorkerNetAddress] = None
         self._workers: Dict[str, WorkerClient] = {}
+        self._fastpath_dir = fastpath_dir
         self._lock = threading.Lock()
         #: workers that recently failed reads, with the failure time —
         #: entries expire after _FAILED_WORKER_TTL_S so a recovered worker
@@ -115,7 +120,7 @@ class BlockStoreClient:
         with self._lock:
             c = self._workers.get(key)
             if c is None:
-                c = WorkerClient(key)
+                c = WorkerClient(key, fastpath_dir=self._fastpath_dir)
                 self._workers[key] = c
             return c
 

@@ -225,7 +225,8 @@ class FileSystem:
                 Keys.USER_SHM_LEASE_RENEW_FRACTION),
             batch_read=BatchReadConf.from_conf(self._conf),
             native_fastpath=self._conf.get_bool(
-                Keys.USER_NATIVE_FASTPATH_ENABLED))
+                Keys.USER_NATIVE_FASTPATH_ENABLED),
+            fastpath_dir=fp_dir)
         # pull cluster defaults once at start (reference: clients load
         # cluster-default config via the meta master on first connect)
         self._path_conf: Dict[str, Dict[str, str]] = {}

@@ -410,10 +410,14 @@ class Keys:
                     "gRPC; local clients short-circuit onto it and "
                     "remote ones keep using gRPC (rpc/fastpath.py).")
     MASTER_FASTPATH_DIR = _k(
-        "atpu.master.fastpath.dir", default="/tmp",
-        description="Directory for the fastpath Unix socket "
-                    "(atpu-master-<rpc_port>.sock); clients probe the "
-                    "same conventional path.")
+        "atpu.master.fastpath.dir", default="",
+        description="Directory for the fastpath Unix sockets "
+                    "(atpu-master-<rpc_port>.sock): the master's and, "
+                    "under its own RPC port, every worker's, because a "
+                    "client probes ONE directory at the same "
+                    "conventional path. Empty: the process's temp "
+                    "directory (TMPDIR, else /tmp), on servers and "
+                    "clients alike (rpc/fastpath.socket_path_for).")
     MASTER_JOURNAL_TYPE = _k("atpu.master.journal.type", KeyType.ENUM,
                              default="LOCAL", choices=("LOCAL", "UFS", "EMBEDDED", "NOOP"),
                              scope=Scope.MASTER)

@@ -579,19 +579,12 @@ class MasterProcess:
                     Keys.MASTER_HA_PUBLISH_INTERVAL)))
             self._threads[-1].start()
         if self._conf.get_bool(Keys.MASTER_FASTPATH_ENABLED):
-            from alluxio_tpu.rpc.fastpath import (
-                FastPathServer, socket_path_for,
-            )
+            from alluxio_tpu.rpc.fastpath import serve_fastpath
 
-            self.fastpath_server = FastPathServer(
-                socket_path_for(
-                    f"localhost:{self.rpc_port}",
-                    self._conf.get(Keys.MASTER_FASTPATH_DIR)),
-                authenticator=authenticator,
-                admission=self.admission)
-            for svc in self.rpc_server._services.values():
-                self.fastpath_server.add_service(svc)
-            self.fastpath_server.start()
+            self.fastpath_server = serve_fastpath(
+                self.rpc_server.services(), self.rpc_port,
+                self._conf.get(Keys.MASTER_FASTPATH_DIR),
+                authenticator=authenticator, admission=self.admission)
         if self._conf.get_bool(Keys.MASTER_WEB_ENABLED):
             from alluxio_tpu.master.web import MasterWebServer
 

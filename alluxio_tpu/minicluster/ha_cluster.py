@@ -27,11 +27,11 @@ from typing import Dict, List, Optional, Tuple
 
 from alluxio_tpu.conf import Configuration, Keys
 from alluxio_tpu.master.process import FaultTolerantMasterProcess
+from alluxio_tpu.minicluster.local_cluster import _WorkerHandle
 from alluxio_tpu.rpc.clients import (
     BlockMasterClient, FsMasterClient, MetaMasterClient,
 )
-from alluxio_tpu.rpc.core import RpcServer
-from alluxio_tpu.rpc.worker_service import worker_service
+from alluxio_tpu.rpc.worker_service import serve_worker
 from alluxio_tpu.utils import faults
 from alluxio_tpu.utils.wire import TieredIdentity, WorkerNetAddress
 from alluxio_tpu.worker.process import BlockWorker
@@ -85,17 +85,6 @@ class WriteLedger:
         visible = set(visible_paths)
         return [p for p, s in self.entries
                 if s is not None and s <= stamp and p not in visible]
-
-
-class _WorkerHandle:
-    def __init__(self, worker: BlockWorker, server: RpcServer, port: int):
-        self.worker = worker
-        self.server = server
-        self.port = port
-
-    def stop(self) -> None:
-        self.worker.stop()
-        self.server.stop()
 
 
 class HaCluster:
@@ -197,18 +186,11 @@ class HaCluster:
                              meta_master_client=MetaMasterClient(
                                  addrs, conf=wconf))
         worker.ufs_manager = WorkerUfsManager(fs_client)
-        from alluxio_tpu.security.authentication import worker_authenticator
-
-        server = RpcServer(bind_host="127.0.0.1", port=0,
-                           authenticator=worker_authenticator(wconf))
-        server.add_service(worker_service(worker))
-        port = server.start()
-        worker.address.rpc_port = port
-        worker.address.data_port = port
+        server = serve_worker(worker, wconf, bind_host="127.0.0.1")
         # full heartbeats: failover re-registration rides the heartbeat
         # command channel, which is half the point of this cluster
         worker.start()
-        handle = _WorkerHandle(worker, server, port)
+        handle = _WorkerHandle(worker, server)
         self.workers.append(handle)
         return handle
 

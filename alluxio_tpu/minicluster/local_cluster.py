@@ -17,18 +17,17 @@ from alluxio_tpu.master.process import MasterProcess
 from alluxio_tpu.rpc.clients import (
     BlockMasterClient, FsMasterClient, MetaMasterClient, WorkerClient,
 )
-from alluxio_tpu.rpc.core import RpcServer
-from alluxio_tpu.rpc.worker_service import worker_service
+from alluxio_tpu.rpc.worker_service import WorkerEndpoint, serve_worker
 from alluxio_tpu.utils.wire import TieredIdentity, WorkerNetAddress
 from alluxio_tpu.worker.process import BlockWorker
 from alluxio_tpu.worker.ufs_manager import WorkerUfsManager
 
 
 class _WorkerHandle:
-    def __init__(self, worker: BlockWorker, server: RpcServer, port: int):
+    def __init__(self, worker: BlockWorker, server: WorkerEndpoint):
         self.worker = worker
         self.server = server
-        self.port = port
+        self.port = server.port
 
     @property
     def address(self) -> str:
@@ -109,20 +108,13 @@ class LocalCluster:
         # UFS resolution must be in place before the RPC server serves a
         # single read (a UFS-descriptor read in the gap would crash on None)
         worker.ufs_manager = WorkerUfsManager(fs_client)
-        from alluxio_tpu.security.authentication import worker_authenticator
-
-        server = RpcServer(bind_host="127.0.0.1", port=0,
-                           authenticator=worker_authenticator(wconf))
-        server.add_service(worker_service(worker))
-        port = server.start()
-        worker.address.rpc_port = port
-        worker.address.data_port = port
+        server = serve_worker(worker, wconf, bind_host="127.0.0.1")
         if self._start_hb:
             worker.start()
         else:
             worker._master_sync.register_with_master()
             worker.maybe_start_web()
-        handle = _WorkerHandle(worker, server, port)
+        handle = _WorkerHandle(worker, server)
         self.workers.append(handle)
         return handle
 

@@ -89,17 +89,17 @@ class _BaseClient:
                  metadata=None, fastpath: bool = True,
                  fastpath_dir: Optional[str] = None, conf=None,
                  standby_reads: bool = False) -> None:
-        """``fastpath_dir``: where master fastpath sockets live; pass the
+        """``fastpath_dir``: where fastpath sockets live; pass the
         ``atpu.master.fastpath.dir`` property when a Configuration is at
-        hand (FileSystem does) — otherwise the env override or /tmp.
+        hand (FileSystem does) — empty or None is the default every
+        server uses too (``fastpath.socket_path_for``).
         ``retry_duration_s`` defaults from ``conf``'s
         ``atpu.user.rpc.retry.duration`` (30s)."""
         import os as _os
 
         self._use_fast = fastpath and \
             not _os.environ.get("ATPU_FASTPATH_DISABLE")
-        self._fast_dir = fastpath_dir or \
-            _os.environ.get("ATPU_MASTER_FASTPATH_DIR", "/tmp")
+        self._fast_dir = fastpath_dir
         self._channels = []
         self._addresses: List[str] = []
         for a in str(address).split(","):

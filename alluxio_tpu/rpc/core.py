@@ -142,56 +142,84 @@ def check_admission(admission, context, method_key: str,
     admission.check(principal, method_key.rsplit(".", 1)[-1])
 
 
-def _timed_admission(sp, admission, context, span_name: str) -> None:
+def _timed_admission(sp, admission, context, span_name: str,
+                     principal_hint: Optional[str] = None) -> None:
     """check_admission, recording its cost as the server span's
-    ``admission`` phase when the dispatch is traced."""
+    ``admission`` phase when the dispatch is traced (both unary
+    dispatchers: the gRPC wrappers and ``rpc/fastpath.py``)."""
     if sp is None:
-        check_admission(admission, context, span_name)
+        check_admission(admission, context, span_name, principal_hint)
         return
     import time as _time
 
     t0 = _time.perf_counter()
-    check_admission(admission, context, span_name)
+    check_admission(admission, context, span_name, principal_hint)
     sp.phase("admission", (_time.perf_counter() - t0) * 1000.0)
+
+
+#: the two unary dispatchers, as ``RpcServed`` names them
+ROUTE_GRPC, ROUTE_FASTPATH = "grpc", "fastpath"
+
+
+def _served_metrics(reg, service: str, method: str, route: str):
+    """``(timer, counter)`` of one method on one route. The instance is
+    the serving role, read off the service's name (an in-process cluster
+    shares one registry, so the registry's own instance cannot say)."""
+    if service.endswith("Worker"):
+        return (reg.timer(f"Worker.RpcServeTime.{method}"),
+                reg.counter(f"Worker.RpcServed.{route}.{method}"))
+    if service.startswith("Job"):
+        return (reg.timer(f"JobMaster.RpcServeTime.{method}"),
+                reg.counter(f"JobMaster.RpcServed.{route}.{method}"))
+    return (reg.timer(f"Master.RpcServeTime.{method}"),
+            reg.counter(f"Master.RpcServed.{route}.{method}"))
+
+
+def register_served(service: str, methods) -> None:
+    """Make these methods' ``RpcServed`` counters exist on BOTH routes
+    (and their serve timer, empty), so that a route never taken reads 0
+    in a ``get_metrics`` pull and not "absent"."""
+    reg = metrics()
+    for route in (ROUTE_GRPC, ROUTE_FASTPATH):
+        for method in methods:
+            _served_metrics(reg, service, method, route)
 
 
 class ServeTimer:
     """The always-on per-method server timer, ring on or off (the
     reference's ``RpcUtils`` per-method timer): handler wall time as the
     SERVER saw it, so a client-side figure splits into stub + wire
-    against service time. One a method, built when the method is
-    registered by either unary dispatcher (the gRPC wrapper below,
-    ``rpc/fastpath.py``), so an RPC pays one ``Timer.update``. The
-    instance is the serving role, read off the service's name (an
-    in-process cluster shares one registry, so the registry's own
-    instance cannot say); the timer is looked up again only when the
-    process registry was swapped (``reset_metrics``, in tests)."""
+    against service time, and beside it the count of what THIS route
+    served, ``<Instance>.RpcServed.<route>.<method>`` (the routes share
+    the timer; the counter says which one a client took). One a method
+    and route, built when the method is registered by either unary
+    dispatcher (the gRPC wrapper below, ``rpc/fastpath.py``), so an RPC
+    pays one ``Timer.update`` and one ``Counter.inc``. Both are looked
+    up again only when the process registry was swapped
+    (``reset_metrics``, in tests)."""
 
-    __slots__ = ("_service", "_method", "_reg", "_timer")
+    __slots__ = ("_service", "_method", "_route", "_reg", "_timer",
+                 "_served")
 
-    def __init__(self, service: str, method: str) -> None:
-        self._service, self._method = service, method
-        self._reg = self._timer = None
-
-    def _resolve(self, reg):
-        service, method = self._service, self._method
-        if service.endswith("Worker"):
-            return reg.timer(f"Worker.RpcServeTime.{method}")
-        if service.startswith("Job"):
-            return reg.timer(f"JobMaster.RpcServeTime.{method}")
-        return reg.timer(f"Master.RpcServeTime.{method}")
+    def __init__(self, service: str, method: str, route: str) -> None:
+        self._service, self._method, self._route = service, method, route
+        self._reg = self._timer = self._served = None
 
     def update(self, seconds: float) -> None:
         reg = metrics()
         if reg is not self._reg:
-            self._reg, self._timer = reg, self._resolve(reg)
+            self._timer, self._served = _served_metrics(
+                reg, self._service, self._method, self._route)
+            self._reg = reg
         self._timer.update(seconds)
+        self._served.inc()
 
 
 def _wrap_unary(fn: Callable[[dict], Any], authenticator=None,
                 span_name: str = "", admission=None) -> Callable:
     service, _, method = span_name.rpartition(".")
-    serve_timer = ServeTimer(service, method) if method else None
+    serve_timer = ServeTimer(service, method, ROUTE_GRPC) \
+        if method else None
 
     def handler(request: dict, context: grpc.ServicerContext):
         token = None
@@ -291,6 +319,10 @@ class _GenericHandler(grpc.GenericRpcHandler):
         self._services = services
         self._auth = authenticator
         self._admission = admission
+        #: (service, method) -> (fn, handler): grpc asks for the handler
+        #: on EVERY call, the wrapper (and its serve timer) is built
+        #: once a method
+        self._unary: Dict[Tuple[str, str], Tuple[Callable, Any]] = {}
 
     def service(self, handler_call_details):
         # method path: /<service>/<method>
@@ -305,10 +337,16 @@ class _GenericHandler(grpc.GenericRpcHandler):
         fn, kind = entry
         span = f"{service_name}.{method}"
         if kind == "unary":
-            return grpc.unary_unary_rpc_method_handler(
-                _wrap_unary(fn, self._auth, span, self._admission),
-                request_deserializer=unpack,
-                response_serializer=pack)
+            cached = self._unary.get((service_name, method))
+            # a handler wrapped in place after start() (the HA primacy
+            # fence) is a new ``fn``: wrap that one afresh
+            if cached is None or cached[0] is not fn:
+                cached = (fn, grpc.unary_unary_rpc_method_handler(
+                    _wrap_unary(fn, self._auth, span, self._admission),
+                    request_deserializer=unpack,
+                    response_serializer=pack))
+                self._unary[(service_name, method)] = cached
+            return cached[1]
         if kind == "stream_out":
             return grpc.unary_stream_rpc_method_handler(
                 _wrap_stream_out(fn, self._auth, span, self._admission),
@@ -352,6 +390,11 @@ class RpcServer:
 
     def add_service(self, svc: ServiceDefinition) -> None:
         self._services[svc.name] = svc
+
+    def services(self) -> Tuple[ServiceDefinition, ...]:
+        """Everything this server hosts: what a fast path beside it
+        serves the unary half of (``fastpath.serve_fastpath``)."""
+        return tuple(self._services.values())
 
     def service(self, name: str) -> Optional[ServiceDefinition]:
         """Registered service by name — dispatch reads the definition's

@@ -20,10 +20,21 @@ Protocol (all frames are ``[u32 little-endian length][msgpack]``):
 
 Discovery is by convention: a master serving RPC port P binds
 ``<dir>/atpu-master-P.sock`` (dir from ``atpu.master.fastpath.dir``,
-default ``/tmp``). A client whose master address resolves to this host
-probes that path and silently falls back to gRPC when absent — the same
-"short-circuit if local, stream if not" decision the block-read ladder
-makes (reference: ``BlockInStream.java:80-124``).
+default the process's temp directory, ``socket_path_for``). A client
+whose master address resolves to this host probes that path and silently
+falls back to gRPC when absent — the same "short-circuit if local,
+stream if not" decision the block-read ladder makes (reference:
+``BlockInStream.java:80-124``). A call falls back only while nothing of
+it was written to the socket: once the frame is out the handler may have
+run, and a timeout or a lost connection surfaces as gRPC's would
+(``FastPathChannel.call``) instead of running the call a second time.
+
+A WORKER serves its unary calls the same way, at the same conventional
+path under its own RPC port (``rpc/worker_service.serve_worker``): the
+SHM lease plane's ``shm_open`` / ``shm_renew`` / ``shm_release`` are
+two integers out and four fields back between two processes on one
+host, and paid ~1 ms of HTTP/2 a call for it. ``WorkerClient`` is the
+same kind of client and probes the same directory.
 """
 
 from __future__ import annotations
@@ -33,6 +44,7 @@ import os
 import socket
 import socketserver
 import struct
+import tempfile
 import threading
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -41,16 +53,41 @@ import msgpack
 
 from alluxio_tpu.utils.exceptions import AlluxioTpuError, UnavailableError
 
+
+# lint: allow[wire-error-unregistered] -- raised and caught in the client process, never serialized
+class FastPathNotSentError(UnavailableError):
+    """The request never left this process: no connection, or the write
+    failed (the server reads a whole frame or none). The ONE failure
+    ``HybridChannel`` answers by re-issuing the call on gRPC; once a
+    frame is written the call may have run, and is not sent twice."""
+
+
 LOG = logging.getLogger(__name__)
 
 _LEN = struct.Struct("<I")
 _MAX_FRAME = 256 << 20
 
 
-def socket_path_for(address: str, directory: str = "/tmp") -> str:
-    """Conventional socket path for a master RPC ``host:port`` address."""
+#: what ``sockaddr_un.sun_path`` holds on Linux, the NUL included
+_SUN_PATH_MAX = 108
+
+
+def socket_path_for(address: str, directory: Optional[str] = None) -> str:
+    """Conventional socket path for a role's RPC ``host:port`` address.
+    The ONE place the directory is decided, for servers and clients
+    alike: ``directory`` (``atpu.master.fastpath.dir``) when given, else
+    ``ATPU_MASTER_FASTPATH_DIR``, else the process's temp directory
+    (``TMPDIR``), so a deployment with a temp directory of its own keeps
+    its sockets there. A path too long for the kernel to bind falls to
+    ``/tmp``, on both sides."""
     _, _, port = address.rpartition(":")
-    return os.path.join(directory, f"atpu-master-{port}.sock")
+    name = f"atpu-master-{port}.sock"
+    path = os.path.join(
+        directory or os.environ.get("ATPU_MASTER_FASTPATH_DIR")
+        or tempfile.gettempdir(), name)
+    if len(os.fsencode(path)) >= _SUN_PATH_MAX:
+        path = os.path.join("/tmp", name)
+    return path
 
 
 def is_local_host(host: str) -> bool:
@@ -100,15 +137,15 @@ class FastPathServer:
         self._conns_lock = threading.Lock()
 
     def add_service(self, svc) -> None:
-        from alluxio_tpu.rpc.core import ServeTimer
+        from alluxio_tpu.rpc.core import ROUTE_FASTPATH, ServeTimer
 
         for method, (fn, kind) in svc.methods.items():
             if kind == "unary":
                 self._methods[(svc.name, method)] = (
-                    fn, ServeTimer(svc.name, method))
+                    fn, ServeTimer(svc.name, method, ROUTE_FASTPATH))
 
     def start(self) -> str:
-        from alluxio_tpu.rpc.core import check_admission
+        from alluxio_tpu.rpc.core import _timed_admission
 
         methods = self._methods
         authenticator = self._auth
@@ -181,14 +218,14 @@ class FastPathServer:
                             trace_token = bind_remote_parent(traceparent)
                             t0 = time.perf_counter()
                             try:
-                                with tracer().span(f"{service}.{method}"):
+                                name = f"{service}.{method}"
+                                with tracer().span(name) as sp:
                                     # admission parity too: a local
                                     # flood must not bypass the gate
                                     # by riding the Unix socket
-                                    check_admission(
-                                        admission, None,
-                                        f"{service}.{method}",
-                                        principal_hint=principal_hint)
+                                    _timed_admission(
+                                        sp, admission, None, name,
+                                        principal_hint)
                                     result = fn(request or {})
                             finally:
                                 serve_timer.update(
@@ -255,6 +292,23 @@ class FastPathServer:
             pass
 
 
+def serve_fastpath(services, rpc_port: int, directory: str, *,
+                   authenticator=None,
+                   admission=None) -> Optional[FastPathServer]:
+    """Serve the unary methods of ``services`` (what the role's
+    ``RpcServer`` hosts) at the conventional socket of ``rpc_port``,
+    with the gRPC server's own authenticator and admission gate. One
+    way in for every role that has a fast path (master, worker). None
+    when the socket cannot be claimed: that is logged and the role
+    stays on gRPC alone."""
+    server = FastPathServer(
+        socket_path_for(f"localhost:{rpc_port}", directory),
+        authenticator=authenticator, admission=admission)
+    for svc in services:
+        server.add_service(svc)
+    return server if server.start() else None
+
+
 class FastPathChannel:
     """Client side: one persistent connection PER THREAD (no lock on the
     call path; bench threads never contend), lazily (re)connected.
@@ -269,15 +323,21 @@ class FastPathChannel:
 
     def _connect(self, timeout: Optional[float]) -> socket.socket:
         sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        sock.settimeout(timeout if timeout else 30.0)
-        sock.connect(self._uds_path)
-        rfile = sock.makefile("rb", buffering=64 << 10)
-        _send_frame(sock, {"metadata": self._metadata})
-        resp = _read_frame(rfile)
+        try:
+            sock.settimeout(timeout if timeout else 30.0)
+            sock.connect(self._uds_path)
+            rfile = sock.makefile("rb", buffering=64 << 10)
+            _send_frame(sock, {"metadata": self._metadata})
+            resp = _read_frame(rfile)
+        except OSError:
+            sock.close()
+            raise
         if resp is None:
-            raise UnavailableError("fastpath hello: connection closed")
+            sock.close()
+            raise FastPathNotSentError("fastpath hello: connection closed")
         resp = msgpack.unpackb(resp, raw=False, strict_map_key=False)
         if "err" in resp:
+            sock.close()
             raise AlluxioTpuError.from_wire(resp["err"])
         self._tl.sock, self._tl.rfile = sock, rfile
         self._tl.timeout = timeout
@@ -312,8 +372,19 @@ class FastPathChannel:
             tp = current_traceparent()
             _send_frame(sock, [service, method, request] +
                         ([tp] if tp else []))
+        except OSError as e:  # refused, broken pipe, timeout on the way
+            self.close_thread_connection()
+            raise FastPathNotSentError(f"fastpath: {e}") from None
+        # the frame is written: from here the handler may have run, so
+        # a failure surfaces as gRPC's would and nothing is re-issued
+        try:
             resp = _read_frame(self._tl.rfile)
-        except (ConnectionError, socket.timeout, OSError) as e:
+        except socket.timeout:
+            self.close_thread_connection()
+            raise AlluxioTpuError(
+                f"DEADLINE_EXCEEDED: fastpath: no reply to "
+                f"{service}.{method} in {timeout or 30.0}s") from None
+        except OSError as e:
             self.close_thread_connection()
             raise UnavailableError(f"fastpath: {e}") from None
         if resp is None:
@@ -328,11 +399,13 @@ class FastPathChannel:
 
 class HybridChannel:
     """gRPC channel + optional fastpath: unary calls ride the Unix
-    socket when the master is local and serving one; anything else (or a
-    broken socket) falls back to gRPC. Mirrors the short-circuit /
-    remote decision of the block-read ladder, for metadata."""
+    socket when the server is local and serving one; anything else (or a
+    socket that takes nothing of a call) falls back to gRPC. Mirrors the
+    short-circuit / remote decision of the block-read ladder, for
+    metadata and the lease plane."""
 
-    def __init__(self, grpc_channel, fastpath_dir: str = "/tmp") -> None:
+    def __init__(self, grpc_channel,
+                 fastpath_dir: Optional[str] = None) -> None:
         self._grpc = grpc_channel
         self.address = grpc_channel.address
         self._fast: Optional[FastPathChannel] = None
@@ -360,9 +433,10 @@ class HybridChannel:
         if fast is not None and not self._fast_dead:
             try:
                 return fast.call(service, method, request, timeout=timeout)
-            except UnavailableError:
-                # socket-level failure: the server may be gone entirely
-                # or only the fastpath is — let gRPC decide from here on
+            except FastPathNotSentError:
+                # the socket took nothing: the server may be gone
+                # entirely or only the fastpath is — let gRPC decide
+                # from here on
                 self._fast_dead = True
         return self._grpc.call(service, method, request, timeout=timeout)
 

@@ -20,7 +20,9 @@ from __future__ import annotations
 import threading
 from typing import Dict, Iterator, Tuple
 
-from alluxio_tpu.rpc.core import ServiceDefinition
+from alluxio_tpu.rpc.core import (
+    RpcServer, ServiceDefinition, register_served,
+)
 from alluxio_tpu.utils.exceptions import (
     BlockDoesNotExistError, InvalidArgumentError, best_effort,
 )
@@ -284,6 +286,10 @@ def worker_service(worker: BlockWorker) -> ServiceDefinition:
     svc.unary("shm_open", shm_open)
     svc.unary("shm_renew", shm_renew)
     svc.unary("shm_release", shm_release)
+    # which route a same-host client's leases took is read from a
+    # pull of these: one never taken must read 0, not "absent"
+    register_served(WORKER_SERVICE, ("shm_open", "shm_renew",
+                                     "shm_release"))
 
     # ---------------------------------------------------------- write stream
     def write_block(requests: Iterator[dict]) -> dict:
@@ -389,3 +395,50 @@ def worker_service(worker: BlockWorker) -> ServiceDefinition:
 
     svc.unary("get_metrics", get_metrics)
     return svc
+
+
+class WorkerEndpoint:
+    """A serving worker's listeners: the gRPC server and, beside it for
+    same-host clients, the Unix-socket fast path (None when its socket
+    could not be claimed, or was stopped)."""
+
+    def __init__(self, server: RpcServer, fastpath, port: int) -> None:
+        self.server = server
+        self.fastpath = fastpath
+        self.port = port
+
+    def stop_fastpath(self) -> None:
+        """Close the socket and unlink it; clients fall back to gRPC."""
+        if self.fastpath is not None:
+            self.fastpath.stop()
+            self.fastpath = None
+
+    def stop(self) -> None:
+        self.stop_fastpath()
+        self.server.stop()
+
+
+def serve_worker(worker: BlockWorker, conf, *, bind_host: str,
+                 port: int = 0) -> WorkerEndpoint:
+    """Start serving ``worker``: the one way in for every way of
+    running a worker (role process, in-process clusters). The worker's
+    address takes the bound port, and the fast path is up before the
+    caller registers the worker with the master, so a client that
+    learns the address finds the socket. Every unary method rides it
+    (``add_service`` leaves the streams to gRPC), under the gRPC
+    server's own authenticator; a worker has no admission gate."""
+    from alluxio_tpu.conf import Keys
+    from alluxio_tpu.rpc.fastpath import serve_fastpath
+    from alluxio_tpu.security.authentication import worker_authenticator
+
+    authenticator = worker_authenticator(conf)
+    server = RpcServer(bind_host=bind_host, port=port,
+                       authenticator=authenticator)
+    server.add_service(worker_service(worker))
+    port = server.start()
+    worker.address.rpc_port = port
+    worker.address.data_port = port
+    fastpath = serve_fastpath(
+        server.services(), port, conf.get(Keys.MASTER_FASTPATH_DIR),
+        authenticator=authenticator)
+    return WorkerEndpoint(server, fastpath, port)

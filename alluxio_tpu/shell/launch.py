@@ -63,8 +63,7 @@ def launch_worker(conf: Configuration) -> int:
     from alluxio_tpu.rpc.clients import (
         BlockMasterClient, FsMasterClient, MetaMasterClient,
     )
-    from alluxio_tpu.rpc.core import RpcServer
-    from alluxio_tpu.rpc.worker_service import worker_service
+    from alluxio_tpu.rpc.worker_service import serve_worker
     from alluxio_tpu.worker.process import BlockWorker
     from alluxio_tpu.worker.ufs_manager import WorkerUfsManager
 
@@ -73,23 +72,16 @@ def launch_worker(conf: Configuration) -> int:
     worker = BlockWorker(conf, BlockMasterClient(master_addr), fs_client,
                          meta_master_client=MetaMasterClient(master_addr))
     worker.ufs_manager = WorkerUfsManager(fs_client)
-    from alluxio_tpu.security.authentication import worker_authenticator
-
-    server = RpcServer(bind_host="0.0.0.0",
-                       port=conf.get_int(Keys.WORKER_RPC_PORT),
-                       authenticator=worker_authenticator(conf))
-    server.add_service(worker_service(worker))
-    port = server.start()
-    worker.address.rpc_port = port
-    worker.address.data_port = port
+    endpoint = serve_worker(worker, conf, bind_host="0.0.0.0",
+                            port=conf.get_int(Keys.WORKER_RPC_PORT))
     worker.start()
 
     def stop():
         worker.stop()
-        server.stop()
+        endpoint.stop()
 
     return _serve_until_signal(
-        stop, f"alluxio-tpu worker serving on port {port}")
+        stop, f"alluxio-tpu worker serving on port {endpoint.port}")
 
 
 def launch_job_master(conf: Configuration) -> int:

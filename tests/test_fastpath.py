@@ -11,12 +11,14 @@ chunked container-id reservation surviving replay
 import os
 import tempfile
 import threading
+import time
 
 import pytest
 
 from alluxio_tpu.rpc.core import ServiceDefinition
 from alluxio_tpu.rpc.fastpath import (
-    FastPathChannel, FastPathServer, is_local_host, socket_path_for,
+    FastPathChannel, FastPathNotSentError, FastPathServer, HybridChannel,
+    is_local_host, socket_path_for,
 )
 from alluxio_tpu.utils.exceptions import (
     AlluxioTpuError, FileDoesNotExistError, UnavailableError,
@@ -98,11 +100,116 @@ class TestFastPathServer:
         assert not errs
 
 
+class _CountingGrpc:
+    """Stands where the gRPC channel does behind a ``HybridChannel``."""
+
+    def __init__(self, address: str) -> None:
+        self.address, self.metadata, self.calls = address, (), []
+
+    def call(self, service, method, request, timeout=30.0):
+        self.calls.append(method)
+        return {"route": "grpc"}
+
+
+class TestNoResendAfterWrite:
+    """``HybridChannel`` re-issues a call on gRPC only while the socket
+    took nothing of it. Once the frame is written the handler may have
+    run: a deadline or a lost connection surfaces as gRPC's would."""
+
+    @pytest.fixture()
+    def hybrid(self, tmp_path):
+        ran = []
+        server = FastPathServer(socket_path_for("localhost:7",
+                                                str(tmp_path)))
+
+        def slow(r):
+            ran.append("slow")
+            time.sleep(0.5)
+            return {}
+
+        def die(r):
+            ran.append("die")
+            server.stop()
+            return {}
+
+        svc = ServiceDefinition("test.Svc")
+        svc.unary("echo", lambda r: {"route": "fastpath"})
+        svc.unary("slow", slow)
+        svc.unary("die", die)
+        server.add_service(svc)
+        server.start()
+        grpc = _CountingGrpc("localhost:7")
+        yield HybridChannel(grpc, fastpath_dir=str(tmp_path)), grpc, \
+            ran, server
+        server.stop()
+
+    def test_a_deadline_after_the_write_is_not_reissued(self, hybrid):
+        ch, grpc, ran, _server = hybrid
+        with pytest.raises(AlluxioTpuError,
+                           match="DEADLINE_EXCEEDED") as ei:
+            ch.call("test.Svc", "slow", {}, timeout=0.1)
+        assert not isinstance(ei.value, UnavailableError)
+        assert ran == ["slow"] and grpc.calls == []
+        # the socket is not given up for it: the next call reconnects
+        assert ch.call("test.Svc", "echo", {}) == {"route": "fastpath"}
+        assert grpc.calls == [] and not ch._fast_dead
+
+    def test_a_connection_lost_after_the_write_is_not_reissued(
+            self, hybrid):
+        ch, grpc, ran, _server = hybrid
+        assert ch.call("test.Svc", "echo", {}) == {"route": "fastpath"}
+        with pytest.raises(UnavailableError) as ei:
+            ch.call("test.Svc", "die", {})
+        assert not isinstance(ei.value, FastPathNotSentError)
+        assert ran == ["die"] and grpc.calls == []
+        # nothing listens any more: THIS call was never sent, gRPC has it
+        assert ch.call("test.Svc", "echo", {}) == {"route": "grpc"}
+        assert grpc.calls == ["echo"] and ch._fast_dead
+
+    @pytest.mark.parametrize("left", ["no-file", "stale-file"])
+    def test_a_call_the_socket_never_took_falls_back(self, hybrid, left):
+        ch, grpc, ran, server = hybrid
+        if left == "no-file":
+            server.stop()
+        else:  # what a role killed with SIGKILL leaves behind
+            server._server.shutdown()
+            server._server.server_close()
+            server._server = None
+            assert os.path.exists(server._uds_path)
+        assert ch.call("test.Svc", "echo", {}) == {"route": "grpc"}
+        assert grpc.calls == ["echo"] and ran == []
+
+
 class TestDiscovery:
-    def test_socket_path_convention(self):
-        assert socket_path_for("localhost:19998") == \
-            "/tmp/atpu-master-19998.sock"
-        assert socket_path_for("h:1", "/run") == "/run/atpu-master-1.sock"
+    @pytest.mark.parametrize("env,directory,expected", [
+        ({}, None, "{tmp}/atpu-master-19998.sock"),
+        ({}, "", "{tmp}/atpu-master-19998.sock"),
+        ({}, "/run", "/run/atpu-master-19998.sock"),
+        ({"TMPDIR": "{own}"}, None, "{own}/atpu-master-19998.sock"),
+        ({"ATPU_MASTER_FASTPATH_DIR": "/run/atpu", "TMPDIR": "{own}"},
+         None, "/run/atpu/atpu-master-19998.sock"),
+        ({"ATPU_MASTER_FASTPATH_DIR": "/run/atpu"}, "/srv",
+         "/srv/atpu-master-19998.sock"),
+        # sun_path holds 107 bytes: a longer path falls to /tmp
+        ({}, "/" + "d" * 100, "/tmp/atpu-master-19998.sock"),
+    ], ids=["default", "empty-conf", "conf", "TMPDIR", "env-override",
+            "conf-over-env", "too-long"])
+    def test_socket_path_convention(self, tmp_path, monkeypatch, env,
+                                    directory, expected):
+        """One decision for servers and clients: the conf's directory,
+        else the env override, else the process's temp directory."""
+        monkeypatch.delenv("ATPU_MASTER_FASTPATH_DIR", raising=False)
+        monkeypatch.delenv("TMPDIR", raising=False)
+        tempfile.tempdir = None  # gettempdir() caches its answer
+        own = str(tmp_path)
+        for k, v in env.items():
+            monkeypatch.setenv(k, v.format(own=own))
+        try:
+            tmp = "/tmp" if "TMPDIR" in env else tempfile.gettempdir()
+            assert socket_path_for("localhost:19998", directory) == \
+                expected.format(tmp=tmp, own=own)
+        finally:
+            tempfile.tempdir = None
 
     def test_is_local_host(self):
         assert is_local_host("localhost")

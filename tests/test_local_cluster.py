@@ -244,8 +244,15 @@ class TestFailedWorkerRetry:
                 c.worker_client(target).write_block(
                     bid, session_id=1, data=data)
             # kill the original holder
+            dead = c.workers[src].worker.address
             c.workers[src].stop()
             fs2 = c.file_system()
+            # every rung retries the dead worker for its whole budget
+            # (30 s each, two minutes in all) before the replica is
+            # asked; that long, another test process's worker can take
+            # the freed port and answer for it. Keep the window short.
+            for client in (fs, fs2):  # fs: its close() asks it too
+                client.store.worker_client(dead)._retry_duration_s = 1.0
             assert fs2.read_all("/fo") == payload
             fs2.close()
             fs.close()

@@ -1,10 +1,14 @@
 """Client-side block store: source selection + stream construction.
 
 Re-design of ``core/client/fs/src/main/java/alluxio/client/block/
-AlluxioBlockStore.java:63`` + the ladder in ``stream/BlockInStream.java:80-124``,
-including the **passive cache trigger** (``AlluxioFileInStream.java:137``
-triggerAsyncCaching): when a read was served remotely or from UFS, ask the
-nearest local worker to cache the block in the background.
+AlluxioBlockStore.java:63`` + the ladder in ``stream/BlockInStream.java:80-124``.
+``open_block`` is that ladder, three rungs (``block_streams.py`` has the
+streams): the same-host lease plane for a block in any tier of a
+co-located worker, the gRPC stream from the nearest replica, the UFS
+through a policy-chosen worker. Includes the **passive cache trigger**
+(``AlluxioFileInStream.java:137`` triggerAsyncCaching): when a read was
+served remotely or from UFS, ask the nearest local worker to cache the
+block in the background.
 """
 
 from __future__ import annotations
@@ -15,8 +19,7 @@ from typing import Dict, List, Optional, Set
 
 from alluxio_tpu.client.block_streams import (
     BatchReadConf, BlockInStream, BlockOutStream, GrpcBlockInStream,
-    GrpcBlockOutStream, LocalBlockInStream, LocalBlockOutStream,
-    is_local_worker,
+    GrpcBlockOutStream, LocalBlockOutStream, is_local_worker,
 )
 from alluxio_tpu.client.policy import BlockLocationPolicy
 from alluxio_tpu.client.remote_read import RemoteReadConf, RemoteReadRuntime
@@ -43,7 +46,6 @@ class BlockStoreClient:
                  streaming_chunk_size: int = 1 << 20,
                  streaming_writer_chunk_size: int = 1 << 20,
                  remote_read: Optional[RemoteReadConf] = None,
-                 shm_enabled: bool = True,
                  shm_cache_max: int = 64,
                  shm_renew_fraction: float = 0.5,
                  batch_read: Optional[BatchReadConf] = None,
@@ -58,10 +60,11 @@ class BlockStoreClient:
         stream (``atpu.user.streaming.writer.chunk.size.bytes``);
         ``remote_read``: striped-read tuning — the default conf stripes
         large remote reads, ``RemoteReadConf(stripe_size=0)`` pins the
-        legacy single-stream path; ``shm_enabled`` /``shm_cache_max`` /
-        ``shm_renew_fraction`` (``atpu.user.shm.*``): the same-host
-        zero-copy SHM plane — disabled, step 1 of the ladder is the
-        byte-identical short-circuit path; ``batch_read``
+        legacy single-stream path; ``short_circuit``
+        (``atpu.user.short.circuit.enabled``): the same-host lease plane
+        and the local write, off = every byte rides the remote rung;
+        ``shm_cache_max`` / ``shm_renew_fraction`` (``atpu.user.shm.*``):
+        the plane's segment cache and lazy renewal; ``batch_read``
         (``atpu.user.batch.read.*``): scatter/gather coalescing for
         ``pread_many`` on remote streams; ``native_fastpath``
         (``atpu.user.native.fastpath.enabled``): execute assembled
@@ -86,13 +89,13 @@ class BlockStoreClient:
         #: (hedging learns across reads, so it lives here, not per-stream)
         self.remote_read = RemoteReadRuntime(remote_read)
         self.session_id = id_utils.create_session_id()
-        #: same-host zero-copy plane (``atpu.user.shm.enabled``); None
-        #: pins the legacy short-circuit path byte-for-byte
-        self.shm: Optional[ShmTransport] = ShmTransport(
+        #: the same-host lease plane; None exactly when ``short_circuit``
+        #: is off, which puts a same-host client on the remote rung
+        self.shm = ShmTransport(
             self.session_id, cache_max=shm_cache_max,
             renew_fraction=shm_renew_fraction,
             host=socket.gethostname(),
-            native_fastpath=native_fastpath) if shm_enabled else None
+            native_fastpath=native_fastpath) if short_circuit else None
         #: scatter/gather coalescing conf shared by every remote stream
         self.batch_read = batch_read if batch_read is not None \
             else BatchReadConf()
@@ -162,39 +165,23 @@ class BlockStoreClient:
         info = fbi.block_info
         exclude = exclude or set()
         local_hostname = socket.gethostname()
-        # 1) same-host cached copy: SHM zero-copy map first (one lease
-        # RPC, then every read is a memoryview slice), then the legacy
-        # path-lease short-circuit — each falls one rung on failure
-        if self._short_circuit:
+        # 1) same-host cached copy, whatever tier holds it: one lease
+        # RPC and one mmap, then every read is a memoryview slice
+        if self.shm is not None:
             for loc in info.locations:
-                if loc.address.key() in exclude:
+                if loc.address.key() in exclude or \
+                        not is_local_worker(loc.address, local_hostname):
                     continue
-                if is_local_worker(loc.address, local_hostname):
-                    if self.shm is not None:
-                        try:
-                            stream = self.shm.open_stream(
-                                self.worker_client(loc.address),
-                                info.block_id)
-                            stream.address = loc.address
-                            metrics().counter(
-                                "Client.BlockOpens.shm").inc()
-                            return stream
-                        except Exception:  # noqa: BLE001 - fall through ladder
-                            # lease denied / block not in the top tier /
-                            # map failed / worker dead (UnavailableError):
-                            # the short-circuit and remote rungs still
-                            # serve it
-                            pass
-                    try:
-                        stream = LocalBlockInStream(
-                            self.worker_client(loc.address), self.session_id,
-                            info.block_id)
-                        stream.address = loc.address
-                        metrics().counter(
-                            "Client.BlockOpens.shm").inc()
-                        return stream
-                    except Exception:  # noqa: BLE001 - fall through ladder
-                        pass
+                try:
+                    stream = self.shm.open_stream(
+                        self.worker_client(loc.address), info.block_id)
+                except Exception:  # noqa: BLE001 - fall through ladder
+                    # lease denied / block gone / map failed / worker
+                    # dead (UnavailableError): the remote rung serves it
+                    continue
+                stream.address = loc.address
+                metrics().counter("Client.BlockOpens.shm").inc()
+                return stream
         # 2) remote cached copy, nearest first; the UFS descriptor rides
         # along so a stale location (block evicted since the master's last
         # heartbeat) self-heals server-side via read-through

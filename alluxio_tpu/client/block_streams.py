@@ -4,11 +4,15 @@ Re-design of ``core/client/fs/src/main/java/alluxio/client/block/stream/
 {BlockInStream.java:97,LocalFileDataReader.java:41,GrpcDataReader.java:49,
 LocalFileDataWriter,GrpcDataWriter}.java``:
 
-Read ladder (closest wins):
-1. **Short-circuit mmap** — block cached on a same-host worker: lease the
-   file path (``open_local_block``) and mmap it. Zero RPC per byte, zero
-   copy; the mmap'd buffer can be handed to ``jax.device_put`` directly.
-2. **gRPC stream** — cached on a remote worker.
+Read ladder (closest wins; ``BlockStoreClient.open_block`` walks it):
+1. **Same host: the lease plane** — block cached in any tier of a
+   same-host worker: lease its file (``shm_open``), mmap it once, and
+   serve every read as a slice of the mapping
+   (``shm_transport.ShmBlockInStream``). Zero RPC per byte, zero copy;
+   the mapped pages can be handed to ``jax.device_put`` directly. A
+   denied lease or a failed map falls to rung 2.
+2. **gRPC stream** — cached on a remote worker (``GrpcBlockInStream``;
+   ``choose_route`` picks per-op / batch / striped / single stream).
 3. **UFS fallback through a worker** — not cached anywhere: a
    policy-chosen worker read-throughs from the UFS (caching it), client
    streams from that worker.
@@ -19,7 +23,6 @@ remotely.
 
 from __future__ import annotations
 
-import mmap
 import os
 import queue
 import socket
@@ -87,8 +90,8 @@ class BatchReadConf(NamedTuple):
 
 
 def is_local_worker(address: WorkerNetAddress, local_hostname: str) -> bool:
-    """Same-host check gate for the short-circuit path: the worker's shm
-    dir must be a real local directory."""
+    """Same-host check gate for the lease plane and the short-circuit
+    write: the worker's shm dir must be a real local directory."""
     if address.host not in (local_hostname, "localhost", "127.0.0.1",
                             socket.gethostname()):
         return False
@@ -105,7 +108,7 @@ class BlockInStream:
         #: marks it when a read dies mid-stream
         self.address = None
         #: raw serving source of the LAST read: a worker tier alias
-        #: ("MEM"/"SSD"/...), "SHM" for short-circuit, or "UFS"
+        #: ("MEM"/"SSD"/...), "SHM" for the same-host lease plane, or "UFS"
         self.last_source: Optional[str] = None
 
     def pread(self, offset: int, n: int) -> bytes:
@@ -133,7 +136,7 @@ class BlockInStream:
 
     def source_bucket(self) -> str:
         """The last read's serving source, normalized to an input-doctor
-        bucket: ``shm`` (same-host /dev/shm mmap), ``remote`` (cached on
+        bucket: ``shm`` (same-host mmap under a lease), ``remote`` (cached on
         a remote worker, whatever its tier), ``ufs`` (cold
         read-through), or ``unknown``."""
         src = self.last_source
@@ -161,57 +164,6 @@ class BlockInStream:
     def __exit__(self, *exc):
         self.close()
         return False
-
-
-class LocalBlockInStream(BlockInStream):
-    """Short-circuit: mmap the worker's block file via a path lease
-    (reference: ``LocalFileDataReader.java:41``)."""
-
-    source = "LOCAL"
-
-    def __init__(self, worker: WorkerClient, session_id: int, block_id: int):
-        lease = worker.open_local_block(session_id, block_id)
-        super().__init__(block_id, lease["length"])
-        self.last_source = "SHM"
-        self._worker = worker
-        self._session = session_id
-        self._path = lease["path"]
-        self._f = open(self._path, "rb")
-        self._mm = mmap.mmap(self._f.fileno(), 0, prot=mmap.PROT_READ) \
-            if lease["length"] > 0 else None
-
-    def pread(self, offset: int, n: int) -> bytes:
-        if self._mm is None:
-            return b""
-        out = self._mm[offset:offset + n]
-        _record_read("shm", len(out))
-        return out
-
-    def memoryview(self) -> Optional[memoryview]:
-        return memoryview(self._mm) if self._mm is not None else memoryview(b"")
-
-    def numpy_view(self, dtype=np.uint8) -> np.ndarray:
-        """Zero-copy ndarray over the mmap — feed straight to device_put."""
-        if self._mm is None:
-            return np.empty(0, dtype=dtype)
-        _record_read("shm", len(self._mm))
-        return np.frombuffer(self._mm, dtype=dtype)
-
-    def close(self) -> None:
-        if self._mm is not None:
-            try:
-                self._mm.close()
-            except BufferError:
-                # a numpy view is still live (e.g. in-flight device_put);
-                # leave the mapping to GC — on Linux the pages stay valid
-                # even if the file is later unlinked by eviction
-                pass
-            self._mm = None
-        self._f.close()
-        try:
-            self._worker.close_local_block(self._session, self.block_id)
-        except Exception:  # noqa: BLE001 - lease expires with session anyway
-            pass
 
 
 class GrpcBlockInStream(BlockInStream):

@@ -218,7 +218,6 @@ class FileSystem:
             streaming_writer_chunk_size=self._conf.get_bytes(
                 Keys.USER_STREAMING_WRITER_CHUNK_SIZE),
             remote_read=RemoteReadConf.from_conf(self._conf),
-            shm_enabled=self._conf.get_bool(Keys.USER_SHM_ENABLED),
             shm_cache_max=self._conf.get_int(
                 Keys.USER_SHM_SEGMENT_CACHE_MAX),
             shm_renew_fraction=self._conf.get_float(

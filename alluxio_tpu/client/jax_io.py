@@ -8,9 +8,10 @@ per block:
 1. **HBM hit** — the block is already device-resident in the HBM page
    store: the "read" returns the live ``jax.Array``; no host traffic at
    all.
-2. **Host hit (short-circuit)** — block cached on a same-host worker in
-   /dev/shm: mmap -> zero-copy numpy view -> ``jax.device_put`` (one DMA,
-   no intermediate copy), then the HBM store retains it for next epoch.
+2. **Host hit (same-host lease plane)** — block cached on a same-host
+   worker, in any tier: lease -> mmap -> zero-copy numpy view, its pages
+   made present in one kernel call -> ``jax.device_put`` (one DMA, no
+   intermediate copy), then the HBM store retains it for next epoch.
 3. **Cold** — worker read-through from the UFS (caching it), then (2).
 
 ``device_put`` dispatches asynchronously, so the loader keeps
@@ -302,7 +303,7 @@ class DeviceBlockLoader:
         # -compile the .so, which must not land on the epoch hot path
         from alluxio_tpu import native as _native
 
-        _native.lib()
+        self._has_native = _native.lib() is not None
         if self._svc is not None and self._hbm is not None:
             # the agent's HBM placements ride this loader's host-read
             # path and page store (device_put dispatches async, so the
@@ -320,13 +321,15 @@ class DeviceBlockLoader:
 
     def host_block(self, path: str, index: int):
         """Public host-side read of one block (zero-copy numpy view on the
-        short-circuit path, else a streamed copy)."""
+        same-host lease plane, else a streamed copy): a bare open, the
+        view's pages are not made present."""
         return self._host_bytes(path, index)
 
     # -- single block --------------------------------------------------------
     def _host_bytes(self, path: str, index: int):
         """Host-side view of one block: zero-copy numpy over mmap when the
-        short-circuit path applies, else a bytes copy from the stream."""
+        same-host lease plane serves it, else a bytes copy from the
+        stream."""
         streams = getattr(self._tls, "streams", None)
         if streams is None:
             streams = self._tls.streams = {}
@@ -365,6 +368,56 @@ class DeviceBlockLoader:
         self._tls.last_bucket = stream.source_bucket()
         return data
 
+    def _host_present(self, path: str, index: int):
+        """A missed block ready for ``device_put``: open it, then make
+        the fresh mapping's pages present off the transfer's clock, in
+        one kernel call for the block and not a fault a page. Returns
+        ``(host view, serving bucket)``."""
+        from alluxio_tpu import native
+
+        span = tracer().span
+        with span("atpu.loader.host_read"):
+            # stream cache, block_stream, lease, map
+            with span("atpu.loader.open_block") as sp:
+                host = self._host_bytes(path, index)
+                bucket = getattr(self._tls, "last_bucket", "unknown")
+                if sp is not None:
+                    sp.tags["bucket"] = bucket
+            if host.size:
+                with span("atpu.loader.prefault", bytes=host.nbytes,
+                          native=self._has_native) as sp:
+                    mode = native.prefault(host)
+                    if mode is None:
+                        host[::4096].max()
+                        mode = "touch"
+                    if sp is not None:
+                        sp.tags["mode"] = mode
+                self._prefault_blocks.inc()
+                if mode != "touch":
+                    self._prefault_populated.inc()
+        return host, bucket
+
+    def _hbm_hit(self, pid: PageId):
+        """The HBM tier's copy of a block; None on a miss or with no
+        tier."""
+        lease = self._hbm.get(pid) if self._hbm is not None else None
+        if lease is None:
+            return None
+        self._m.counter("Client.JaxHbmHits").inc()
+        arr = lease.array
+        # safe to unpin before returning: eviction only drops the
+        # store's reference (never arr.delete()), so the array the
+        # consumer holds stays valid regardless
+        lease.close()
+        return arr
+
+    def _miss_to_device(self, path: str, index: int, pid: PageId):
+        """A missed block put on the device and offered to the HBM tier
+        (no second transfer). Returns ``(array, adopted)``."""
+        host, _bucket = self._host_present(path, index)
+        arr = self._jax.device_put(host, self._device)
+        return arr, self._hbm is not None and self._hbm.adopt(pid, arr)
+
     def prefetch_into_hbm(self, ref) -> bool:
         """Prefetch-agent hook: host-read one block and adopt it into
         the HBM tier ahead of its consume (runs on the agent's heartbeat
@@ -374,31 +427,19 @@ class DeviceBlockLoader:
         info = self._infos.get(ref.path)
         fid = info.file_id if info is not None else ref.file_id
         pid = PageId(f"{fid:x}", ref.block_index)
+        # presence, not a hit: the consume that follows counts the hit
         if self._hbm.has(pid):
             return True
-        host = self._host_bytes(ref.path, ref.block_index)
-        arr = self._jax.device_put(host, self._device)
-        return self._hbm.adopt(pid, arr)
+        return self._miss_to_device(ref.path, ref.block_index, pid)[1]
 
     def load_block(self, plan_index: int):
         """One block as a device uint8 array (HBM-cached across epochs)."""
         if self._closed:
             raise RuntimeError("loader is closed")
         path, index, pid = self._plan[plan_index]
-        if self._hbm is not None:
-            lease = self._hbm.get(pid)
-            if lease is not None:
-                self._m.counter("Client.JaxHbmHits").inc()
-                arr = lease.array
-                # safe to unpin before returning: eviction only drops the
-                # store's reference (never arr.delete()), so the array the
-                # consumer holds stays valid regardless
-                lease.close()
-                return arr
-        host = self._host_bytes(path, index)
-        arr = self._jax.device_put(host, self._device)
-        if self._hbm is not None:
-            self._hbm.adopt(pid, arr)  # no second transfer
+        arr = self._hbm_hit(pid)
+        if arr is None:
+            arr, _adopted = self._miss_to_device(path, index, pid)
         return arr
 
     # -- iteration -----------------------------------------------------------
@@ -436,10 +477,7 @@ class DeviceBlockLoader:
         executor: the queue is drained, the producer's streams closed,
         and the ``loader-host-prefetch`` thread joined before control
         returns — nothing leaks waiting for ``close()``."""
-        from alluxio_tpu import native
-
         span = tracer().span
-        has_native = native.lib() is not None
         q: queue.Queue = queue.Queue(maxsize=max(1, self._prefetch) + 1)
         stop = threading.Event()
         retire = threading.Event()
@@ -450,21 +488,16 @@ class DeviceBlockLoader:
                 for (path, index, pid, ref) in entries:
                     if stop.is_set():
                         return
-                    if self._hbm is not None:
-                        lease = self._hbm.get(pid)
-                        if lease is not None:
-                            self._m.counter("Client.JaxHbmHits").inc()
-                            arr = lease.array
-                            lease.close()
-                            if ref is not None:
-                                out = self._svc.on_consume(
-                                    ref, resident_hint=True,
-                                    generation=gen)
-                                if out != "stale":
-                                    self._svc.release(ref)
-                            self._put(q, stop, (pid, arr, True, "hbm",
-                                                getattr(arr, "nbytes", 0)))
-                            continue
+                    arr = self._hbm_hit(pid)
+                    if arr is not None:
+                        if ref is not None:
+                            out = self._svc.on_consume(
+                                ref, resident_hint=True, generation=gen)
+                            if out != "stale":
+                                self._svc.release(ref)
+                        self._put(q, stop, (pid, arr, True, "hbm",
+                                            getattr(arr, "nbytes", 0)))
+                        continue
                     outcome = None
                     if ref is not None:
                         # classify BEFORE the read (ready state decides
@@ -475,30 +508,7 @@ class DeviceBlockLoader:
                         outcome = self._svc.on_consume(ref,
                                                        generation=gen)
                         t0 = time.monotonic()
-                    with span("atpu.loader.host_read"):
-                        # stream cache, block_stream, lease, map
-                        with span("atpu.loader.open_block") as sp:
-                            host = self._host_bytes(path, index)
-                            bucket = getattr(self._tls, "last_bucket",
-                                             "unknown")
-                            if sp is not None:
-                                sp.tags["bucket"] = bucket
-                        if host.size:
-                            # make the fresh mapping's pages present off
-                            # the transfer thread's clock: one kernel
-                            # call for the block, not a fault a page
-                            with span("atpu.loader.prefault",
-                                      bytes=host.nbytes,
-                                      native=has_native) as sp:
-                                mode = native.prefault(host)
-                                if mode is None:
-                                    host[::4096].max()
-                                    mode = "touch"
-                                if sp is not None:
-                                    sp.tags["mode"] = mode
-                            self._prefault_blocks.inc()
-                            if mode != "touch":
-                                self._prefault_populated.inc()
+                    host, bucket = self._host_present(path, index)
                     if ref is not None:
                         if outcome != "stale":
                             # a stale (superseded-epoch) consume must

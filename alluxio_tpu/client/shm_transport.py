@@ -1,9 +1,9 @@
 """Client side of the same-host zero-copy plane: SHM segment transport.
 
-A co-located client leases a block's MEM-tier file from the worker
-(``shm_open``), mmaps it ONCE, and serves every read of that block as a
-``memoryview`` slice over the shared pages — zero RPCs, zero
-serialization, zero copies per read. ``numpy_view`` hands the same pages
+A co-located client leases a block's file from the worker, in whatever
+tier holds it (``shm_open``), mmaps it ONCE, and serves every read of
+that block as a ``memoryview`` slice over the shared pages — zero RPCs,
+zero serialization, zero copies per read. ``numpy_view`` hands the same pages
 to ``np.frombuffer`` for a single ``jax.device_put`` (the only copy a
 same-host read ever pays is host->device). See ``alluxio_tpu/shm/`` for
 the lease protocol and docs/small_reads.md for the design.
@@ -88,6 +88,8 @@ class ShmSegment:
         return memoryview(mm)[offset:max(offset, end)]
 
     def close_map(self) -> None:
+        """Drop the mapping; the segment serves no cache hit again."""
+        self.dead = True
         mm, self.mm = self.mm, None
         if mm is not None:
             try:
@@ -132,7 +134,7 @@ class ShmTransport:
             else:
                 seg = None
         if seg is not None:
-            self._maybe_renew(worker, seg)
+            self.maybe_renew(worker, seg)
             if not seg.dead:
                 return seg
             self.invalidate(block_id)
@@ -192,11 +194,12 @@ class ShmTransport:
         return seg
 
     # ------------------------------------------------------------- leases
-    def _maybe_renew(self, worker: WorkerClient, seg: ShmSegment) -> None:
-        """Lazy renewal: one RPC past the renew point, amortized over
-        the zero-copy reads in between. A refused renewal (worker
-        restarted, lease reclaimed) marks the segment dead — existing
-        views stay valid (mmap semantics), the next open re-leases."""
+    def maybe_renew(self, worker: WorkerClient, seg: ShmSegment) -> None:
+        """Lazy renewal, on every open and read of a cached segment: one
+        RPC past the renew point, amortized over the zero-copy reads in
+        between. A refused renewal (worker restarted, lease reclaimed)
+        marks the segment dead — existing views stay valid (mmap
+        semantics), the next open re-leases."""
         if seg.dead or time.monotonic() < seg.renew_at:
             return
         try:
@@ -210,36 +213,27 @@ class ShmTransport:
         else:
             seg.dead = True
 
-    def touch(self, worker: WorkerClient, seg: ShmSegment) -> None:
-        """Read-path hook: keep the lease fresh while a stream serves."""
-        self._maybe_renew(worker, seg)
-
-    def _release(self, worker: Optional[WorkerClient],
-                 seg: ShmSegment) -> None:
-        seg.dead = True
+    def _release(self, worker: WorkerClient, seg: ShmSegment) -> None:
         seg.close_map()
-        if worker is not None:
-            try:
-                worker.shm_release(self._session, seg.lease_id)
-            except Exception:  # noqa: BLE001 - TTL reclaims it anyway
-                pass
+        try:
+            worker.shm_release(self._session, seg.lease_id)
+        except Exception:  # noqa: BLE001 - TTL reclaims it anyway
+            pass
 
     def invalidate(self, block_id: int) -> None:
         with self._lock:
             seg = self._segments.pop(block_id, None)
         if seg is not None:
-            seg.dead = True
             seg.close_map()
 
-    def close(self, worker_for=None) -> None:
-        """Unmap everything; ``worker_for(block_id) -> WorkerClient``
-        enables graceful lease release (else TTL expiry reclaims)."""
+    def close(self) -> None:
+        """Unmap everything. The leases go with the session
+        (``cleanup_session`` on each worker), else with their TTL."""
         with self._lock:
             segs = list(self._segments.values())
             self._segments.clear()
         for seg in segs:
-            w = worker_for(seg.block_id) if worker_for is not None else None
-            self._release(w, seg)
+            seg.close_map()
 
     def cached_blocks(self) -> int:
         with self._lock:
@@ -251,7 +245,9 @@ class ShmBlockInStream(BlockInStream):
 
     Reads are ``memoryview`` slices of shared pages: no RPC, no
     serialization — the read-path microscope shows zero ``serialize`` /
-    ``wire`` phase time here, which `make bench-smallread` asserts."""
+    ``wire`` phase time here, which `make bench-smallread` asserts.
+    ``close`` is the base's no-op: the segment stays cached (and leased)
+    for the next open, ``BlockStoreClient.close`` releases it."""
 
     source = "LOCAL"
 
@@ -267,18 +263,12 @@ class ShmBlockInStream(BlockInStream):
         return self._seg.released
 
     def pread(self, offset: int, n: int) -> bytes:
-        self._transport.touch(self._worker, self._seg)
-        out = bytes(self._seg.view(offset, n))
-        from alluxio_tpu.metrics import metrics
-
-        metrics().counter("Client.ShmReads").inc()
-        _record_read("shm", len(out))
-        return out
+        return bytes(self.pread_view(offset, n))
 
     def pread_view(self, offset: int, n: int) -> memoryview:
         """The zero-copy form of :meth:`pread`: a live view of the
         shared pages, no intermediate ``bytes``."""
-        self._transport.touch(self._worker, self._seg)
+        self._transport.maybe_renew(self._worker, self._seg)
         out = self._seg.view(offset, n)
         from alluxio_tpu.metrics import metrics
 
@@ -309,7 +299,7 @@ class ShmBlockInStream(BlockInStream):
         from alluxio_tpu.client import fastpath
 
         seg = self._seg
-        self._transport.touch(self._worker, seg)
+        self._transport.maybe_renew(self._worker, seg)
         offs = np.asarray(offsets, dtype=np.int64)
         szs = np.asarray(sizes, dtype=np.int64)
         if offs.size and int(offs.min()) < 0:
@@ -358,8 +348,3 @@ class ShmBlockInStream(BlockInStream):
         metrics().counter("Client.ShmReads").inc()
         _record_read("shm", self._seg.length)
         return np.frombuffer(mm, dtype=dtype)
-
-    def close(self) -> None:
-        # the segment stays cached (and leased) for the next open — the
-        # whole point of the transport; BlockStoreClient.close releases
-        pass

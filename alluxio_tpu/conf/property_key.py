@@ -652,7 +652,7 @@ class Keys:
                         scope=Scope.WORKER,
                         description="Backing dir for the MEM tier; files here are "
                                     "mmap-able by same-host clients for the "
-                                    "short-circuit zero-copy read path.")
+                                    "zero-copy lease plane.")
     WORKER_SHM_LEASE_TTL = _k(
         "atpu.worker.shm.lease.ttl", KeyType.DURATION, default="30s",
         scope=Scope.WORKER,
@@ -749,8 +749,14 @@ class Keys:
         choices=("LOCAL_FIRST", "LOCAL_FIRST_AVOID_EVICTION", "MOST_AVAILABLE",
                  "ROUND_ROBIN", "DETERMINISTIC_HASH", "SPECIFIC_HOST"),
         scope=Scope.CLIENT)
-    USER_SHORT_CIRCUIT_ENABLED = _k("atpu.user.short.circuit.enabled", KeyType.BOOL,
-                                    default=True, scope=Scope.CLIENT)
+    USER_SHORT_CIRCUIT_ENABLED = _k(
+        "atpu.user.short.circuit.enabled", KeyType.BOOL, default=True,
+        scope=Scope.CLIENT,
+        description="The one same-host switch. On: a co-located worker's "
+                    "block file, in any tier, is leased and mmapped "
+                    "(zero copies per read; failures fall to the remote "
+                    "stream) and local writes go to its file. Off: the "
+                    "remote rung serves both, byte-identically.")
     USER_STANDBY_READS_ENABLED = _k(
         "atpu.user.standby.reads.enabled", KeyType.BOOL, default=False,
         scope=Scope.CLIENT,
@@ -798,17 +804,6 @@ class Keys:
                     "worker's rolling EWMA is re-issued to another "
                     "replica/channel; first answer wins, the loser is "
                     "cancelled. 0 disables hedging.")
-    USER_SHM_ENABLED = _k(
-        "atpu.user.shm.enabled", KeyType.BOOL, default=True,
-        scope=Scope.CLIENT,
-        description="Same-host zero-copy SHM transport: when the serving "
-                    "worker is co-located, the client leases the block's "
-                    "MEM-tier segment (shm_open RPC), mmaps it, and reads "
-                    "through a memoryview with no RPC, serialization, or "
-                    "copy per read. Fallback to the remote path is "
-                    "transparent (segment unavailable, lease denied, "
-                    "worker restart). Off: reads are byte-identical to a "
-                    "build without the subsystem.")
     USER_SHM_SEGMENT_CACHE_MAX = _k(
         "atpu.user.shm.segment.cache.max", KeyType.INT, default=64,
         scope=Scope.CLIENT,

@@ -19,7 +19,7 @@ Design:
   ``d`` holds blocks ``[d*per_dev, (d+1)*per_dev)`` in its HBM. Placement
   IS the mesh position — the client-side block map for the warm set.
   Each host loads only ITS devices' blocks from the co-located worker
-  (short-circuit mmap); assembly uses
+  (same-host lease plane, an mmap); assembly uses
   ``jax.make_array_from_single_device_arrays`` — the idiomatic multi-host
   pattern (no host ever materializes the global array).
 - Warm "remote reads" are jitted collectives over the cached array:
@@ -94,11 +94,12 @@ class MeshBlockCache:
                     loader=None, report: bool = True,
                     io_threads: int = 8):
         """Materialize the warm set: every addressable device's shard is
-        loaded from the host-local worker tier (short-circuit mmap ->
-        one device_put per device), then assembled into one global sharded
-        array WITHOUT any host seeing the whole dataset. Per-device host
-        reads run in an IO thread pool and the device_puts are issued
-        as each shard completes, so transfer overlaps the next reads.
+        loaded from the host-local worker tier (leased mmap, its pages
+        made present -> one device_put per device), then assembled into
+        one global sharded array WITHOUT any host seeing the whole
+        dataset. Per-device host reads run in an IO thread pool and the
+        device_puts are issued as each shard completes, so transfer
+        overlaps the next reads.
 
         ``report=True`` registers this host's device placement with the
         master block map (SURVEY §2.11 "block map keyed by device mesh
@@ -158,7 +159,11 @@ class MeshBlockCache:
     def _host_row(self, loader, g: int, n: int, elems: int):
         if g >= n:  # pad the ragged tail with zeros
             return np.zeros(elems, self.dtype)
+        from alluxio_tpu import native
+
         host = loader.host_block(*self.plan[g])
+        # one kernel call maps the block; np.stack would fault it a page
+        native.prefault(host)
         if host.shape[0] != elems:
             padded = np.zeros(elems, self.dtype)
             padded[:host.shape[0]] = host

@@ -739,8 +739,9 @@ class WorkerClient(_BaseClient):
                                         "lease_id": lease_id})
 
     def shm_release(self, session_id: int, lease_id: int) -> None:
-        # advisory like close_local_block: the worker's TTL reclaims it
-        # anyway — short deadline, no retry against a dead worker
+        # advisory: the worker's TTL reclaims the lease anyway, so NO
+        # retry and a short deadline — a release against a dead worker
+        # must not block the caller's thread for the full retry window
         self._channel.call(self.service, "shm_release",
                            {"session_id": session_id,
                             "lease_id": lease_id}, timeout=2.0)
@@ -756,19 +757,6 @@ class WorkerClient(_BaseClient):
 
         resp = self._channel.call_stream_in(self.service, "write_block", gen())
         return resp["length"]
-
-    def open_local_block(self, session_id: int, block_id: int) -> dict:
-        return self._call("open_local_block", {"session_id": session_id,
-                                               "block_id": block_id})
-
-    def close_local_block(self, session_id: int, block_id: int) -> None:
-        # advisory lease release: the worker's session cleanup expires it
-        # anyway, so NO retry and a short deadline — a GC-time close of a
-        # leaked stream against a dead cluster must not block for the
-        # full retry window (observed: 30s stalls on the caller's thread)
-        self._channel.call(self.service, "close_local_block",
-                           {"session_id": session_id,
-                            "block_id": block_id}, timeout=2.0)
 
     def create_local_block(self, session_id: int, block_id: int, *,
                            size_hint: int, tier: str = "") -> str:

@@ -9,16 +9,17 @@ ShortCircuitBlockWriteHandler}.java`` + ``grpc/block_worker.proto:13-29``:
   flow control replaces the reference's hand-rolled ``offset_received``
   receipts.
 - ``write_block``: client-stream (header, chunks..., commit) -> length.
-- ``open_local_block`` / ``close_local_block``: short-circuit **path
-  leases** for same-host clients; the server holds the shared block lock
-  until the lease closes, exactly like the reference's lease stream.
+- ``shm_open`` / ``shm_renew`` / ``shm_release``: the same-host lease
+  plane; a lease is a TTL pin on the block's file in whatever tier holds
+  it (``worker/shm_store.py``), which the client mmaps.
+- ``create_local_block`` / ``complete_local_block``: the short-circuit
+  write of a same-host client.
 - ``async_cache``, ``remove_block``, ``move_block``: unary control ops.
 """
 
 from __future__ import annotations
 
-import threading
-from typing import Dict, Iterator, Tuple
+from typing import Iterator
 
 from alluxio_tpu.rpc.core import (
     RpcServer, ServiceDefinition, register_served,
@@ -41,34 +42,6 @@ P99_SAMPLE_MIN_BYTES = 1 << 18
 P99_SAMPLE_MIN_CHUNK = 1 << 16
 
 
-class _LeaseRegistry:
-    def __init__(self) -> None:
-        self._leases: Dict[Tuple[int, int], object] = {}
-        self._lock = threading.Lock()
-
-    def put(self, session_id: int, block_id: int, lease) -> None:
-        with self._lock:
-            old = self._leases.pop((session_id, block_id), None)
-            self._leases[(session_id, block_id)] = lease
-        if old is not None:
-            old.close()
-
-    def close(self, session_id: int, block_id: int) -> bool:
-        with self._lock:
-            lease = self._leases.pop((session_id, block_id), None)
-        if lease is not None:
-            lease.close()
-            return True
-        return False
-
-    def close_session(self, session_id: int) -> None:
-        with self._lock:
-            victims = [k for k in self._leases if k[0] == session_id]
-            leases = [self._leases.pop(k) for k in victims]
-        for lease in leases:
-            lease.close()
-
-
 def _principal() -> str:
     """The authenticated caller's name, for per-tenant QoS accounting;
     empty (one anonymous tenant) when the worker runs no authenticator
@@ -81,8 +54,6 @@ def _principal() -> str:
 
 def worker_service(worker: BlockWorker) -> ServiceDefinition:
     svc = ServiceDefinition(WORKER_SERVICE)
-    leases = _LeaseRegistry()
-    worker._short_circuit_leases = leases  # session cleanup hook
 
     # ---------------------------------------------------------- read stream
     def read_block(req: dict) -> Iterator[dict]:
@@ -321,14 +292,6 @@ def worker_service(worker: BlockWorker) -> ServiceDefinition:
     svc.stream_in("write_block", write_block)
 
     # ------------------------------------------------------- short circuit
-    def open_local_block(req: dict) -> dict:
-        lease = worker.open_local_block(req["block_id"])
-        leases.put(req["session_id"], req["block_id"], lease)
-        return {"path": lease.path, "length": lease.length}
-
-    def close_local_block(req: dict) -> dict:
-        return {"closed": leases.close(req["session_id"], req["block_id"])}
-
     def create_local_block(req: dict) -> dict:
         path = worker.create_block(
             req["session_id"], req["block_id"],
@@ -344,8 +307,6 @@ def worker_service(worker: BlockWorker) -> ServiceDefinition:
                                 pinned=req.get("pinned", False))
         return {}
 
-    svc.unary("open_local_block", open_local_block)
-    svc.unary("close_local_block", close_local_block)
     svc.unary("create_local_block", create_local_block)
     svc.unary("complete_local_block", complete_local_block)
 
@@ -378,12 +339,8 @@ def worker_service(worker: BlockWorker) -> ServiceDefinition:
     svc.unary("persist_file", lambda r: {"fingerprint": worker.persist_file(
         r["ufs_path"], r["block_ids"], r.get("mount_id", 0))})
 
-    def cleanup_session(req: dict) -> dict:
-        leases.close_session(req["session_id"])
-        worker.cleanup_session(req["session_id"])
-        return {}
-
-    svc.unary("cleanup_session", cleanup_session)
+    svc.unary("cleanup_session", lambda r: (
+        worker.cleanup_session(r["session_id"]), {})[-1])
 
     def get_metrics(req: dict) -> dict:
         """This worker's own registry, as ``get_metrics`` on the master

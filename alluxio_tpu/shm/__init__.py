@@ -1,8 +1,10 @@
 """Same-host zero-copy data plane: the SHM lease protocol.
 
 The worker's MEM tier lives on ``/dev/shm`` (``atpu.worker.shm.dir``) —
-a committed top-tier block file *is* a named shared-memory segment. This
-package holds the protocol both sides of the zero-copy path speak:
+a committed top-tier block file *is* a named shared-memory segment, and a
+lower tier's is an ordinary file that ``mmap`` takes the same way. This
+package holds the protocol both sides of the zero-copy path speak, for a
+block in any tier:
 
 - the **worker** (``worker/shm_store.py``) grants a co-located client a
   *lease* on a segment: ``shm_open`` returns the file path + a lease id,
@@ -31,8 +33,8 @@ RPC                     semantics
 ======================  ================================================
 ``shm_open``            grant lease: {lease_id, path, length, ttl_s};
                         raises ShmLeaseDeniedError (table full / fault)
-                        or ShmSegmentUnavailableError (not cached in
-                        the top tier)
+                        or ShmSegmentUnavailableError (not cached on
+                        this worker)
 ``shm_renew``           extend lease TTL; {ok: False} for an unknown
                         lease (worker restarted) — client re-opens
 ``shm_release``         drop lease; last lease on a block unpins it
@@ -40,8 +42,6 @@ RPC                     semantics
 """
 
 from __future__ import annotations
-
-from typing import NamedTuple
 
 from alluxio_tpu.utils.exceptions import (
     AlluxioTpuError, register_wire_error,
@@ -60,20 +60,9 @@ class ShmLeaseDeniedError(AlluxioTpuError):
 
 @register_wire_error
 class ShmSegmentUnavailableError(AlluxioTpuError):
-    """The block has no mappable top-tier segment on this worker (not
-    cached, mid-eviction, or resident on a lower tier). Not an error for
-    the read itself — the remote path serves it."""
+    """The block has no mappable file on this worker (not cached, or
+    evicted during the grant), or the client's own mapping of it was
+    released. Not an error for the read itself — the remote path serves
+    it, or the block is opened again."""
 
     code = "NOT_FOUND"
-
-
-class ShmLease(NamedTuple):
-    """A granted lease, as the client tracks it."""
-
-    lease_id: int
-    block_id: int
-    path: str
-    length: int
-    ttl_s: float
-    #: monotonic deadline after which the worker may reclaim the pin
-    expires_at: float

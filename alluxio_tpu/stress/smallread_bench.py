@@ -60,9 +60,8 @@ def run_batch(*, file_mb: int = 2, ops: int = 400,
                           worker_mem_bytes=8 * size) as c:
             conf = c.conf.copy()
             # force the wire: the row measures RPC coalescing, so the
-            # same-host shortcuts (SHM map, path-lease mmap) are off
+            # same-host lease plane is off
             conf.set(Keys.USER_SHORT_CIRCUIT_ENABLED, False)
-            conf.set(Keys.USER_SHM_ENABLED, False)
             fs = FileSystem(c.master.address, conf=conf)
             try:
                 path = "/smallread-batch.bin"

@@ -2,9 +2,10 @@
 
 Re-design of ``core/server/worker/.../block/{BlockLockManager.java,
 ClientRWLock.java}``: readers hold shared locks while a block is being
-served (or mmap'd by a short-circuit client); remove/move/evict need the
-exclusive lock. ``try_`` variants let eviction skip in-use blocks instead
-of blocking the allocation path.
+served; remove/move/evict need the exclusive lock. ``try_`` variants let
+eviction skip in-use blocks instead of blocking the allocation path. (A
+same-host client's mmap is shielded by a TTL pin, not by a lock here:
+``TieredBlockStore.pin_shm``.)
 """
 
 from __future__ import annotations

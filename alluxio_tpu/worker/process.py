@@ -36,25 +36,6 @@ from alluxio_tpu.worker.ufs_io import AsyncCacheManager, UfsBlockDescriptor
 LOG = logging.getLogger(__name__)
 
 
-class LocalBlockLease:
-    """Short-circuit lease: path + held shared lock; close() releases."""
-
-    def __init__(self, path: str, length: int, lock) -> None:
-        self.path = path
-        self.length = length
-        self._lock = lock
-
-    def close(self) -> None:
-        self._lock.close()
-
-    def __enter__(self) -> "LocalBlockLease":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        self.close()
-        return False
-
-
 def build_store_from_conf(conf: Configuration) -> TieredBlockStore:
     """Tier layout from the template keys
     (reference: WORKER_TIERED_STORE_LEVELS + per-level templates)."""
@@ -333,21 +314,6 @@ class BlockWorker:
     def open_reader(self, block_id: int) -> BlockReader:
         """Local committed-block reader (holds the shared lock)."""
         return self.store.get_reader(block_id)
-
-    def open_local_block(self, block_id: int) -> "LocalBlockLease":
-        """Short-circuit read lease: the committed block file's path plus a
-        shared lock held until the lease closes, so eviction cannot unlink
-        the file mid-mmap (reference: ``OpenLocalBlock`` +
-        ``ShortCircuitBlockReadHandler`` keep a block lock for the stream's
-        lifetime)."""
-        lock = self.store.pin_block(block_id)
-        meta = self.store.get_block_meta(block_id)
-        if meta is None:  # raced with eviction between pin and lookup
-            lock.close()
-            from alluxio_tpu.utils.exceptions import BlockDoesNotExistError
-
-            raise BlockDoesNotExistError(f"block {block_id} not cached")
-        return LocalBlockLease(meta.path, meta.length, lock)
 
     def open_ufs_fetch(self, desc: UfsBlockDescriptor, *,
                        cache: bool = True, priority: int = 0,

@@ -1,10 +1,11 @@
 """Worker side of the same-host zero-copy plane: the SHM lease store.
 
-Grants, renews, releases and reclaims leases on MEM-tier block files
-(named shared-memory segments under ``atpu.worker.shm.dir``) so a
-co-located client can mmap them and read with zero copies. See
-``alluxio_tpu/shm/`` for the protocol contract and
-docs/small_reads.md for the design.
+Grants, renews, releases and reclaims leases on committed block files,
+in whatever tier holds them (the MEM tier's are named shared-memory
+segments under ``atpu.worker.shm.dir``; a lower tier's is an ordinary
+path that ``mmap`` takes as well), so a co-located client can mmap them
+and read with zero copies. See ``alluxio_tpu/shm/`` for the protocol
+contract and docs/small_reads.md for the design.
 
 Pin integration: a granted lease calls
 :meth:`TieredBlockStore.pin_shm`, which shields the block from eviction
@@ -58,18 +59,14 @@ class ShmStore:
         self._by_session: Dict[int, Set[int]] = {}
         self._ids = itertools.count(1)
         self._m = metrics()
-        # the MEM tier (top tier) is the only mappable one: its files
-        # sit on /dev/shm, lower tiers are ordinary disk paths
-        self._top_alias = store.meta.tiers[0].alias if store.meta.tiers \
-            else "MEM"
 
     # ------------------------------------------------------------- grant
     def open(self, session_id: int, block_id: int) -> dict:
         """Grant a lease: ``{lease_id, path, length, ttl_s}``.
 
         Raises :class:`ShmLeaseDeniedError` (table full / injected
-        fault) or :class:`ShmSegmentUnavailableError` (no mappable
-        top-tier segment) — both of which the client treats as
+        fault) or :class:`ShmSegmentUnavailableError` (the block is
+        not cached here) — both of which the client treats as
         "serve this read remotely", never as a read failure."""
         from alluxio_tpu.utils import faults
 
@@ -79,11 +76,9 @@ class ShmStore:
             raise ShmLeaseDeniedError(
                 f"shm lease for block {block_id} denied (injected fault)")
         meta = self._store.get_block_meta(block_id)
-        if meta is None or meta.tier_alias != self._top_alias:
+        if meta is None:
             raise ShmSegmentUnavailableError(
-                f"block {block_id} has no mappable {self._top_alias} "
-                f"segment (tier: "
-                f"{meta.tier_alias if meta else 'not cached'})")
+                f"block {block_id} is not cached on this worker")
         now = time.monotonic()
         unpins: List[int] = []
         try:

@@ -8,8 +8,9 @@ tier, move/free, and lock-guarded reads.
 Storage layout: one file per block, ``<dir>/<block_id>``; temp blocks at
 ``<dir>/.tmp/<session>_<block_id>``. The MEM tier sits on ``/dev/shm`` so a
 same-host client can ``mmap`` the committed file and hand the pages to XLA
-without a copy (the short-circuit read path; reference:
-``OpenLocalBlock`` leases in ``block_worker.proto:18-21``).
+without a copy (the lease plane, ``worker/shm_store.py``; a lower tier's
+file is mapped the same way; reference: ``OpenLocalBlock`` leases in
+``block_worker.proto:18-21``).
 """
 
 from __future__ import annotations
@@ -165,7 +166,7 @@ class TieredBlockStore:
         #: worker-side reclamation path.
         self.prefetch_pinned_blocks: Dict[int, float] = {}
         #: SHM-lease pins: block_id -> expiry (monotonic). A same-host
-        #: client holding an shm lease (shm/) has the MEM-tier file
+        #: client holding an shm lease (shm/) has the block's file
         #: mmapped; eviction must not demote/unlink it mid-read. Same
         #: crash-safety shape as prefetch pins — TTL-bounded, NOT
         #: session-bound: a SIGKILLed client's pins self-expire one
@@ -323,23 +324,11 @@ class TieredBlockStore:
         self._m.counter(f"Worker.BlocksAccessed.{meta.tier_alias}").inc()
         return reader
 
-    def pin_block(self, block_id: int) -> Optional[BlockLock]:
-        """Shared-lock lease without opening the file — backs the
-        short-circuit read lease so eviction cannot unlink a file a client
-        is mmapping (reference: OpenLocalBlock holds a block lock for the
-        stream's lifetime)."""
-        lock = self._locks.lock_read(block_id)
-        if self.meta.get_block(block_id) is None:
-            lock.close()
-            raise BlockDoesNotExistError(f"block {block_id} not cached")
-        self.annotator.on_access(block_id)
-        return lock
-
     def pin_prefetch(self, block_id: int, ttl_s: float = 600.0) -> bool:
         """Shield a committed block from eviction until the prefetch
-        consumer reads it. Unlike :meth:`pin_block` this holds no lock
-        object a remote caller would have to keep alive — it is an
-        expiring entry the evictor respects, dropped by
+        consumer reads it. It holds no lock object a remote caller would
+        have to keep alive — it is an expiring entry the evictor
+        respects, dropped by
         :meth:`unpin_prefetch`, block removal, or TTL expiry (the
         backstop for clients that die without unpinning)."""
         import time

@@ -61,6 +61,38 @@ class TestEpoch:
             loader.close()
 
 
+    def test_a_miss_is_made_present_once_and_a_hit_never(
+            self, cluster, monkeypatch):
+        """``load_block`` and ``prefetch_into_hbm`` take the producer's
+        miss: one ``native.prefault`` of the mapped block before its
+        ``device_put``, none for a block the HBM tier already holds."""
+        from alluxio_tpu import native
+        from alluxio_tpu.prefetch.oracle import BlockRef
+
+        loader, data = _make_loader(cluster, hbm_bytes=16 << 20)
+        calls = []
+        real = native.prefault
+        monkeypatch.setattr(
+            native, "prefault",
+            lambda view, *a: calls.append(view.nbytes) or real(view, *a))
+        ref = BlockRef("/loader/data.bin", 2, block_id=0, length=BLOCK)
+        try:
+            arr = np.asarray(loader.load_block(1))  # a miss
+            assert arr.tobytes() == data[BLOCK:2 * BLOCK]
+            assert calls == [BLOCK]
+            assert loader.prefetch_into_hbm(ref)  # a miss, adopted
+            assert calls == [BLOCK, BLOCK]
+            hits0 = _hbm_hits()
+            assert np.asarray(loader.load_block(2)).tobytes() == \
+                data[2 * BLOCK:3 * BLOCK]  # the adopted block: a hit
+            loader.load_block(1)
+            assert loader.prefetch_into_hbm(ref)
+            assert _hbm_hits() - hits0 == 2
+            assert calls == [BLOCK, BLOCK]
+        finally:
+            loader.close()
+
+
 def _hbm_hits():
     from alluxio_tpu.metrics import metrics
 

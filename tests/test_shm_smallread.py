@@ -63,14 +63,19 @@ class TestShmStoreLeases:
         assert shm.stats()["live_leases"] == 1
         assert 1 in store.shm_leased_blocks
 
-    def test_only_top_tier_is_mappable(self, tmp_path):
-        """Lower tiers are ordinary disk paths — the client must be
-        told to read remotely, not handed an unmappable file."""
+    def test_every_local_tier_is_mappable(self, tmp_path):
+        """A lower tier's file is an ordinary path that mmap takes: the
+        lease is granted whatever tier holds the block. Only a block
+        this worker does not hold sends the client to the remote rung."""
         store = make_store(tmp_path)
         put_block(store, 2, b"on-ssd", tier="SSD")
         shm = ShmStore(store)
-        with pytest.raises(ShmSegmentUnavailableError):
-            shm.open(SESSION, 2)
+        lease = shm.open(SESSION, 2)
+        assert lease["path"] == store.get_block_meta(2).path
+        assert store.get_block_meta(2).tier_alias == "SSD"
+        with open(lease["path"], "rb") as f:
+            assert f.read() == b"on-ssd"
+        assert 2 in store.shm_leased_blocks
         with pytest.raises(ShmSegmentUnavailableError):
             shm.open(SESSION, 999)  # not cached at all
 
@@ -280,6 +285,30 @@ class TestSameHostE2E:
         assert f.pread(KB, KB) == data[KB:2 * KB]
         f.close()
 
+    def test_a_lower_tier_block_rides_the_lease_plane(self, cluster):
+        """A block committed to the SSD tier of a same-host worker is
+        leased and mapped like a MEM-tier one, byte for byte."""
+        data = _patterned(BLOCK, 0x55D)
+        f2 = cluster.file_system()
+        try:
+            f2.write_all("/shm-ssd", data, write_type="MUST_CACHE",
+                         tier="SSD")
+            bid = f2.get_status("/shm-ssd").block_ids[0]
+            store = cluster.workers[0].worker.store
+            assert store.get_block_meta(bid).tier_alias == "SSD"
+            read = metrics().counter("Client.BytesRead.shm").count
+            with f2.open_file("/shm-ssd") as f:
+                bs = f.block_stream(0)
+                assert type(bs).__name__ == "ShmBlockInStream"
+                nv = bs.numpy_view()
+                assert nv.tobytes() == data
+                del nv
+            assert metrics().counter("Client.BytesRead.shm").count == \
+                read + len(data)
+            assert bid in store.shm_leased_blocks
+        finally:
+            f2.close()
+
     def test_worker_session_cleanup_releases_leases(self, cluster):
         f2 = cluster.file_system()
         f2.write_all("/shm-bye", b"z" * KB, write_type="MUST_CACHE")
@@ -299,7 +328,6 @@ class TestScatterGather:
     def _remote_fs(self, cluster):
         conf = cluster.conf.copy()
         conf.set(Keys.USER_SHORT_CIRCUIT_ENABLED, False)
-        conf.set(Keys.USER_SHM_ENABLED, False)
         from alluxio_tpu.client.file_system import FileSystem
 
         return FileSystem(cluster.master.address, conf=conf)
@@ -376,13 +404,13 @@ class TestScatterGather:
 # -------------------------------------------------- disabled-path parity
 class TestDisabledByteIdentity:
     def test_disabled_path_is_byte_identical(self, cluster):
-        """`atpu.user.shm.enabled=false` + batching off: the ladder
-        must serve the exact bytes of the enabled path through the
-        legacy streams — over real gRPC, not mocks."""
+        """`atpu.user.short.circuit.enabled=false` + batching off: the
+        remote rung must serve the exact bytes of the same-host plane —
+        over real gRPC, not mocks."""
         data = _patterned(2 * BLOCK, 0xD15)
         enabled = cluster.file_system()
         conf = cluster.conf.copy()
-        conf.set(Keys.USER_SHM_ENABLED, False)
+        conf.set(Keys.USER_SHORT_CIRCUIT_ENABLED, False)
         conf.set(Keys.USER_BATCH_READ_ENABLED, False)
         from alluxio_tpu.client.file_system import FileSystem
 
@@ -394,7 +422,7 @@ class TestDisabledByteIdentity:
             assert disabled.store.shm is None
             with disabled.open_file("/parity") as f:
                 bs = f.block_stream(0)
-                assert type(bs).__name__ != "ShmBlockInStream"
+                assert type(bs).__name__ == "GrpcBlockInStream"
                 # pread_many still works — the per-op default path
                 got = bs.pread_many([0, 5, BLOCK - 3], [4, 4, 10])
                 assert got == [data[:4], data[5:9],
@@ -414,7 +442,7 @@ class TestChaosFallback:
 
     def test_map_fault_falls_back_and_still_serves(self, cluster):
         """Injected mmap failure: the read must transparently fall one
-        rung (legacy short-circuit / remote) and return the bytes."""
+        rung (remote) and return the bytes."""
         data = _patterned(KB, 0xFA)
         f2 = cluster.file_system()
         try:
@@ -428,6 +456,41 @@ class TestChaosFallback:
                 assert type(bs).__name__ != "ShmBlockInStream"
             assert m.counter("Client.ShmMapFailures").count > failures
             assert faults.injector().injected.get("shm_map_error", 0) > 0
+        finally:
+            f2.close()
+
+    def test_a_failed_map_goes_remote_in_one_step(self, cluster,
+                                                  monkeypatch):
+        """After a failed map the ladder does not lease and map the same
+        file again: ONE lease, given back at once, then the remote
+        stream."""
+        data = _patterned(KB, 0xFC)
+        f2 = cluster.file_system()
+        shm_store = cluster.workers[0].worker.shm_store
+        opened, released, leases = [], [], {}
+        real_open, real_release = shm_store.open, shm_store.release
+
+        def open_(session_id, block_id):
+            lease = real_open(session_id, block_id)
+            opened.append(block_id)
+            leases[lease["lease_id"]] = block_id
+            return lease
+
+        def release(session_id, lease_id):
+            released.append(leases.get(lease_id))
+            return real_release(session_id, lease_id)
+
+        monkeypatch.setattr(shm_store, "open", open_)
+        monkeypatch.setattr(shm_store, "release", release)
+        try:
+            f2.write_all("/chaos-once", data, write_type="MUST_CACHE")
+            bid = f2.get_status("/chaos-once").block_ids[0]
+            faults.injector().set(shm_map_error_rate=1.0)
+            with f2.open_file("/chaos-once") as f:
+                bs = f.block_stream(0)
+                assert type(bs).__name__ == "GrpcBlockInStream"
+                assert bs.pread(0, KB) == data
+            assert opened.count(bid) == 1 and released.count(bid) == 1
         finally:
             f2.close()
 

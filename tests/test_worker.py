@@ -135,16 +135,32 @@ def test_pin_list_sync(cluster):
     assert worker.store.master_pinned_blocks == set()
 
 
-def test_short_circuit_lease_pins_block(cluster):
+def test_lease_pins_a_lower_tier_block_until_released(cluster):
+    """The lease plane's pin on an SSD-tier block: eviction pressure
+    skips the block while it is leased and takes it after release."""
     bm, fsm, worker = cluster
-    worker.create_block(SESSION, 42, initial_bytes=KB, tier_alias="MEM")
-    with worker.get_temp_writer(SESSION, 42) as w:
-        w.append(b"mmap me")
-    worker.commit_block(SESSION, 42)
-    with worker.open_local_block(42) as lease:
-        with open(lease.path, "rb") as f:  # a client would mmap this
-            assert f.read() == b"mmap me"
-        # while leased, the block cannot be removed (eviction-safe mmap)
-        with pytest.raises(Exception):
-            worker.store.remove_block(42, timeout=0.05)
-    worker.store.remove_block(42)  # lease released -> removable
+    size = 16 * KB  # the SSD tier is 4x the 16 KB ramdisk: four blocks
+
+    def put(bid):
+        worker.create_block(SESSION, bid, initial_bytes=size,
+                            tier_alias="SSD")
+        with worker.get_temp_writer(SESSION, bid) as w:
+            w.append(bytes([bid]) * size)
+        worker.commit_block(SESSION, bid)
+
+    def ssd():
+        return set(worker.store.block_report()["SSD"])
+
+    for bid in (1, 2, 3, 4):
+        put(bid)
+    lease = worker.shm_store.open(SESSION, 1)
+    with open(lease["path"], "rb") as f:  # a client would mmap this
+        assert f.read() == bytes([1]) * size
+    for bid in (2, 3, 4):  # the grant touched 1: make it coldest again
+        worker.store.access_block(bid)
+    put(5)  # must evict: 1 is the coldest, and leased
+    assert ssd() == {1, 3, 4, 5}
+    assert worker.shm_store.release(SESSION, lease["lease_id"])
+    put(6)  # 1 is still the coldest, and no longer shielded
+    assert ssd() == {3, 4, 5, 6}
+    assert not worker.store.has_block(1)

@@ -13,7 +13,7 @@ import socket
 import threading
 import time
 from collections import OrderedDict
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 from alluxio_tpu.client.block_store import BlockStoreClient
 from alluxio_tpu.client.block_streams import BatchReadConf
@@ -27,6 +27,12 @@ from alluxio_tpu.rpc.clients import (
 from alluxio_tpu.utils.exceptions import best_effort
 from alluxio_tpu.utils.uri import AlluxioURI
 from alluxio_tpu.utils.wire import FileInfo, MountPointInfo, TieredIdentity
+
+
+#: the most paths one ``get_status_many`` call carries: a frame of about
+#: a megabyte and a few tens of milliseconds inside the master, however
+#: long the caller's list
+STATUS_BATCH_PATHS = 1024
 
 
 class _MetadataCache:
@@ -256,6 +262,10 @@ class FileSystem:
         self._md_hits = _m().counter("Client.MetadataCacheHits")
         self._md_misses = _m().counter("Client.MetadataCacheMisses")
         self._md_inval = _m().counter("Client.MetadataCacheInvalidated")
+        # how often a file list went to the master as ONE status call,
+        # and how many paths those calls carried
+        self._status_batch_calls = _m().counter("Client.StatusBatchCalls")
+        self._status_batch_paths = _m().counter("Client.StatusBatchPaths")
         self._sync_interval_ms = int(1000 * self._conf.get_duration_s(
             Keys.USER_FILE_METADATA_SYNC_INTERVAL))
         self._page_cache = None
@@ -400,6 +410,44 @@ class FileSystem:
             p, sync_interval_ms=self._sync_interval_ms, want_version=True)
         self._md_cache.put(p, info, stamp)
         return info
+
+    def get_status_many(self, paths: "Sequence[str | AlluxioURI]"
+                        ) -> List[FileInfo]:
+        """:meth:`get_status` of every path, in request order
+        (duplicates allowed), in one call to the master where
+        ``get_status`` makes one a path. With the metadata cache on,
+        hits are answered from it and the misses fetched in one call and
+        stored under that reply's stamp. A failed path raises its own
+        typed error, the first in list order. A list longer than
+        ``STATUS_BATCH_PATHS`` goes as successive calls, so that one
+        frame and one hold of the master's locks stay bounded."""
+        ps = [AlluxioURI(p).path for p in paths]
+        out: list = [None] * len(ps)
+        cache = self._md_cache
+        if cache is None:
+            todo = list(range(len(ps)))
+        else:
+            todo = []
+            for i, p in enumerate(ps):
+                out[i] = cache.get(p)
+                if out[i] is None:
+                    todo.append(i)
+            self._md_hits.inc(len(ps) - len(todo))
+            self._md_misses.inc(len(todo))
+        for lo in range(0, len(todo), STATUS_BATCH_PATHS):
+            chunk = todo[lo:lo + STATUS_BATCH_PATHS]
+            answers, stamp = self.fs_master.get_status_many(
+                [ps[i] for i in chunk],
+                sync_interval_ms=self._sync_interval_ms, want_version=True)
+            self._status_batch_calls.inc()
+            self._status_batch_paths.inc(len(chunk))
+            for i, answer in zip(chunk, answers):
+                if isinstance(answer, Exception):
+                    raise answer
+                out[i] = answer
+                if cache is not None:
+                    cache.put(ps[i], answer, stamp)
+        return out
 
     def exists(self, path: "str | AlluxioURI") -> bool:
         return self.fs_master.exists(AlluxioURI(path).path)

@@ -36,7 +36,7 @@ import numpy as np
 
 from alluxio_tpu.client.cache.hbm_store import HbmPageStore, default_device
 from alluxio_tpu.client.cache.meta import PageId
-from alluxio_tpu.client.file_system import FileSystem
+from alluxio_tpu.client.file_system import STATUS_BATCH_PATHS, FileSystem
 from alluxio_tpu.conf import Keys
 from alluxio_tpu.metrics import metrics
 from alluxio_tpu.metrics.stall import (BUCKET_ADVICE, SIZE_BUCKETS,
@@ -273,8 +273,14 @@ class DeviceBlockLoader:
         # get_status round per file on the startup path
         resolved = dict(prefetch_service.oracle.manifest.file_infos) \
             if prefetch_service is not None else {}
+        # every other path in ONE status call for the list, where a
+        # call a path was 93-95% of job start
+        todo = [p for p in paths if str(p) not in resolved]
+        with tracer().span("atpu.loader.resolve", paths=len(todo),
+                           calls=-(-len(todo) // STATUS_BATCH_PATHS)):
+            resolved.update(zip(map(str, todo), fs.get_status_many(todo)))
         for path in paths:
-            info = resolved.get(str(path)) or fs.get_status(path)
+            info = resolved[str(path)]
             self._infos[path] = info
             self.block_ids_by_path[path] = list(info.block_ids)
             for i in range(len(info.block_ids)):

@@ -25,7 +25,7 @@ import logging
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Set
+from typing import Dict, List, Optional, Sequence, Set
 
 from alluxio_tpu.journal.format import EntryType
 from alluxio_tpu.journal.system import JournalSystem
@@ -42,10 +42,10 @@ from alluxio_tpu.underfs.registry import UfsManager
 from alluxio_tpu.utils import ids
 from alluxio_tpu.utils.clock import Clock, SystemClock
 from alluxio_tpu.utils.exceptions import (
-    DirectoryNotEmptyError, FileAlreadyCompletedError, FileAlreadyExistsError,
-    FileDoesNotExistError, FileIncompleteError, InvalidArgumentError,
-    InvalidPathError, NotFoundError, PermissionDeniedError, UnavailableError,
-    register_wire_error,
+    AlluxioTpuError, DirectoryNotEmptyError, FileAlreadyCompletedError,
+    FileAlreadyExistsError, FileDoesNotExistError, FileIncompleteError,
+    InvalidArgumentError, InvalidPathError, NotFoundError,
+    PermissionDeniedError, UnavailableError, register_wire_error,
 )
 from alluxio_tpu.utils.fingerprint import Fingerprint
 from alluxio_tpu.utils.uri import AlluxioURI
@@ -61,7 +61,7 @@ ROOT_MOUNT_ID = 1
 _DEFAULT_DEVICE_TIERS = frozenset(("HBM", "MEM"))
 
 
-def _transpose(rows: "List[dict]") -> dict:
+def transpose(rows: "List[dict]") -> dict:
     """Row wire-dicts -> struct-of-arrays listing payload. Every row
     comes from ``_file_info_dict`` so the field set is uniform."""
     if not rows:
@@ -266,6 +266,92 @@ class FileSystemMaster:
         except FileDoesNotExistError:
             return False
 
+    def get_status_many(self, paths: Sequence["str | AlluxioURI"],
+                        sync_interval_ms: int = -1) -> list:
+        """``get_status`` of every path of a list, answered in request
+        order as WIRE DICTS (``_file_info_dict``: what the batched RPC
+        ships; no ``FileInfo`` is built to be turned back into one); a
+        path that fails holds its own exception in its place, and the
+        others are answered.
+
+        Each path keeps what :meth:`get_status` gives it: the on-access
+        sync, the traverse check of every ancestor, the metadata load
+        of a path absent from the tree. The paths of one parent
+        directory are answered under ONE lock list, the parent's (read
+        locks root to parent: what a listing of that directory takes,
+        and a snapshot at least as consistent as a call a path), so the
+        shared ancestors' locks and their permission check are paid
+        once a directory and not once a file. Nothing is remembered
+        between calls."""
+        out: list = [None] * len(paths)
+        #: parent path -> [(position, uri)]; None holds the root itself
+        groups: Dict[Optional[str], list] = {}
+        for i, path in enumerate(paths):
+            try:
+                uri = AlluxioURI(path)
+                if sync_interval_ms >= 0:
+                    self._maybe_sync(uri, sync_interval_ms)
+            except AlluxioTpuError as e:
+                out[i] = e
+                continue
+            parent = uri.parent()
+            groups.setdefault(None if parent is None else parent.path,
+                              []).append((i, uri))
+        absent: list = []
+        for parent_path, members in groups.items():
+            try:
+                if parent_path is None:
+                    with self.inode_tree.lock_path(members[0][1]) as lip:
+                        root = self._file_info_dict(lip.lookup.inode,
+                                                    members[0][1])
+                    for i, _uri in members:
+                        out[i] = root
+                else:
+                    absent += self._status_of_children(
+                        AlluxioURI(parent_path), members, out)
+            except AlluxioTpuError as e:
+                for i, _uri in members:
+                    if out[i] is None:
+                        out[i] = e
+        # absent from the tree: load from the UFS on access, outside
+        # every lock (UFS IO), as get_status does
+        for i, uri in absent:
+            try:
+                loaded = self._load_metadata_if_exists(uri)
+                if loaded is None:
+                    raise FileDoesNotExistError(
+                        f"path {uri} does not exist")
+                out[i] = loaded.to_wire()
+            except AlluxioTpuError as e:
+                out[i] = e
+        return out
+
+    def _status_of_children(self, parent: AlluxioURI, members: list,
+                            out: list) -> list:
+        """Answer ``members`` (``(position, uri)`` pairs, all children
+        of ``parent``) into ``out`` under the parent's lock list;
+        returns those the tree does not hold."""
+        store = self.inode_tree._store
+        absent: list = []
+        with self.inode_tree.lock_path(parent) as lip:
+            lookup = lip.lookup
+            # the chain that exists IS every member's chain of
+            # ancestors, whether or not the parent itself exists
+            self._perm.check_traverse(self._auth_user(), lookup.inodes)
+            if not (lookup.exists and lookup.inode.is_directory):
+                return members
+            d_ufs, d_mount = self._dir_mount(parent)
+            parent_id = lookup.inode.id
+            for i, uri in members:
+                cid = store.get_child_id(parent_id, uri.name)
+                child = store.get(cid) if cid is not None else None
+                if child is None:
+                    absent.append((i, uri))
+                else:
+                    out[i] = self._child_info_dict(
+                        child, uri.name, parent, d_ufs, d_mount)
+        return absent
+
     def list_status(self, path: "str | AlluxioURI", *, recursive: bool = False,
                     load_direct_children: bool = True,
                     sync_interval_ms: int = -1,
@@ -286,7 +372,7 @@ class FileSystemMaster:
         status = self.get_status(uri)  # loads the inode itself if needed
         if not status.folder:
             if columnar:
-                return _transpose([status.to_wire()])
+                return transpose([status.to_wire()])
             return [status.to_wire()] if wire else [status]
         if load_direct_children:
             self._load_children_if_needed(uri, force=synced)
@@ -341,7 +427,7 @@ class FileSystemMaster:
                     if not columnar:
                         return hit[2]
                     if hit[3] is None:
-                        hit = hit[:3] + (_transpose(hit[2]),)
+                        hit = hit[:3] + (transpose(hit[2]),)
                         with self._listing_cache_lock:
                             self._listing_cache[dir_id] = hit
                     return hit[3]
@@ -352,12 +438,7 @@ class FileSystemMaster:
                 # nested mount lands exactly one level down) needs its
                 # own resolution — the rest skip the per-child mount
                 # walk + URI construction that dominated listing CPU.
-                try:
-                    dres = self.mount_table.resolve(dir_uri)
-                    d_ufs = dres.ufs_path.rstrip("/")
-                    d_mount = dres.mount_id
-                except (NotFoundError, InvalidPathError):
-                    d_ufs, d_mount = "", 0  # unmounted region
+                d_ufs, d_mount = self._dir_mount(dir_uri)
                 d_path = dir_uri.path if dir_uri.path != "/" else ""
                 for child in self.inode_tree.children(dir_inode):
                     child_path = f"{d_path}/{child.name}"
@@ -378,7 +459,7 @@ class FileSystemMaster:
                 # a mutation anywhere (version moved) or a location
                 # change mid-emit makes this listing uncacheable —
                 # serve it, but don't memoize a potentially torn view
-                cols = _transpose(out) if columnar else None
+                cols = transpose(out) if columnar else None
                 with self._listing_cache_lock:
                     # multiple listing threads share the tree READ lock;
                     # dict iteration for eviction needs its own mutex
@@ -389,7 +470,7 @@ class FileSystemMaster:
                         tree_ver, loc_ver, out, cols)
                 if columnar:
                     return cols
-        return _transpose(out) if columnar else out
+        return transpose(out) if columnar else out
 
     def list_status_page(self, path: "str | AlluxioURI", *,
                          start_after: Optional[str] = None,
@@ -419,26 +500,13 @@ class FileSystemMaster:
                     [self._file_info_dict(inode, uri)]
                 return {"infos": entry, "next": None,
                         "md_version": self.invalidations.version}
-            try:
-                dres = self.mount_table.resolve(uri)
-                d_ufs = dres.ufs_path.rstrip("/")
-                d_mount = dres.mount_id
-            except (NotFoundError, InvalidPathError):
-                d_ufs, d_mount = "", 0
-            d_path = uri.path if uri.path != "/" else ""
+            d_ufs, d_mount = self._dir_mount(uri)
             infos: List[dict] = []
             last_name: Optional[str] = None
             for child in self.inode_tree.children(inode,
                                                   start_after=start_after):
-                child_path = f"{d_path}/{child.name}"
-                if self.mount_table.is_mount_path(child_path):
-                    infos.append(self._file_info_dict(
-                        child, uri.join(child.name)))
-                else:
-                    mount = (f"{d_ufs}/{child.name}" if d_ufs else "",
-                             d_mount)
-                    infos.append(self._file_info_dict(
-                        child, child_path, mount=mount))
+                infos.append(self._child_info_dict(
+                    child, child.name, uri, d_ufs, d_mount))
                 last_name = child.name
                 if len(infos) >= limit:
                     break
@@ -473,6 +541,31 @@ class FileSystemMaster:
             out.append(FileBlockInfo(block_info=bi,
                                      offset=i * inode.block_size_bytes))
         return out
+
+    def _dir_mount(self, dir_uri: AlluxioURI) -> "tuple[str, int]":
+        """``(ufs path, mount id)`` of a directory, resolved ONCE for
+        its children, who extend it by name (``_child_info_dict``):
+        the per-child mount walk dominated listing CPU."""
+        try:
+            dres = self.mount_table.resolve(dir_uri)
+            return dres.ufs_path.rstrip("/"), dres.mount_id
+        except (NotFoundError, InvalidPathError):
+            return "", 0  # unmounted region
+
+    def _child_info_dict(self, child: Inode, name: str,
+                         dir_uri: AlluxioURI, d_ufs: str,
+                         d_mount: int) -> dict:
+        """Wire dict of the child ``name`` of a directory whose mount
+        ``_dir_mount`` resolved. Only a child that is itself a mount
+        point (a nested mount lands exactly one level down) needs its
+        own resolution."""
+        d_path = dir_uri.path if dir_uri.path != "/" else ""
+        child_path = f"{d_path}/{name}"
+        if self.mount_table.is_mount_path(child_path):
+            return self._file_info_dict(child, dir_uri.join(name))
+        return self._file_info_dict(
+            child, child_path,
+            mount=(f"{d_ufs}/{name}" if d_ufs else "", d_mount))
 
     def _file_info(self, inode: Inode, uri: "AlluxioURI | str",
                    mount: Optional[tuple] = None) -> FileInfo:

@@ -174,12 +174,14 @@ class MeshBlockCache:
         if loader is not None:  # loader already fetched every status
             self._bids_by_path.update(
                 getattr(loader, "block_ids_by_path", {}))
+        # the paths no loader resolved: one status call for the list
+        todo = list(dict.fromkeys(
+            p for p, _ in self.plan if p not in self._bids_by_path))
+        for path, info in zip(todo, fs.get_status_many(todo)):
+            self._bids_by_path[path] = list(info.block_ids)
         self.block_ids = []
         for path, idx in self.plan:
-            bids = self._bids_by_path.get(path)
-            if bids is None:
-                bids = self._bids_by_path[path] = \
-                    list(fs.get_status(path).block_ids)
+            bids = self._bids_by_path[path]
             self.block_ids.append(bids[idx] if idx < len(bids) else -1)
 
     # -- control-plane placement reporting -----------------------------------

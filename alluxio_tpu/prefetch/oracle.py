@@ -56,8 +56,7 @@ class DatasetManifest:
         async-cache path needs for cold loads)."""
         blocks: List[BlockRef] = []
         file_infos: List[tuple] = []
-        for path in paths:
-            info = fs.get_status(path)
+        for path, info in zip(paths, fs.get_status_many(paths)):
             file_infos.append((str(path), info))
             fbis = fs.fs_master.get_file_block_info_list(info.path)
             for i, fbi in enumerate(fbis):

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from alluxio_tpu.rpc.core import RpcChannel
 from alluxio_tpu.rpc.master_service import (
@@ -261,6 +261,28 @@ class FsMasterClient(_BaseClient):
     def exists(self, path: str) -> bool:
         return self._call("exists", {"path": str(path)},
                           read=True)["exists"]
+
+    def get_status_many(self, paths: Sequence[str],
+                        sync_interval_ms: int = -1, *,
+                        want_version: bool = False):
+        """:meth:`get_status` of every path in ONE call, in request
+        order (duplicates allowed): a ``FileInfo`` a path, or in its
+        place the typed error that path's ``get_status`` raises (the
+        others are answered: the caller decides what a failure is).
+        ``want_version=True`` -> ``(answers, stamp)``, one stamp for the
+        call, taken before the first lookup. One call, one frame: a
+        caller with a long list cuts it (``FileSystem.get_status_many``
+        does)."""
+        from alluxio_tpu.utils.exceptions import AlluxioTpuError
+
+        resp = self._call(
+            "get_status_many", {"paths": [str(p) for p in paths],
+                                "sync_interval_ms": sync_interval_ms},
+            read=True)
+        answers: list = self._decode_columnar(resp["columnar"]["cols"])
+        for i, err in resp["errors"]:  # ascending: each lands in place
+            answers.insert(i, AlluxioTpuError.from_wire(err))
+        return (answers, resp["md_version"]) if want_version else answers
 
     @staticmethod
     def _decode_columnar(cols: dict) -> List[FileInfo]:

@@ -14,7 +14,7 @@ from typing import Optional
 
 from alluxio_tpu.conf import Configuration, Source
 from alluxio_tpu.master.block_master import BlockMaster
-from alluxio_tpu.master.file_master import FileSystemMaster
+from alluxio_tpu.master.file_master import FileSystemMaster, transpose
 from alluxio_tpu.metrics import metrics
 from alluxio_tpu.rpc.core import RpcServer, ServiceDefinition
 from alluxio_tpu.utils.wire import WorkerNetAddress
@@ -28,7 +28,8 @@ META_SERVICE = "atpu.MetaMaster"
 #: cannot journal the sync's effects — and everything NOT in this set
 #: is refused with a typed NotPrimaryError + leader hint.
 STANDBY_FS_READS = frozenset({
-    "get_status", "exists", "list_status", "list_status_stream",
+    "get_status", "get_status_many", "exists", "list_status",
+    "list_status_stream",
 })
 
 #: Meta RPCs a standby answers itself: cluster/config introspection and
@@ -65,13 +66,14 @@ def fs_master_service(fsm: FileSystemMaster,
                       audit_writer=None) -> ServiceDefinition:
     svc = ServiceDefinition(FS_SERVICE)
 
-    def u(name, fn, register=True):
+    def u(name, fn, register=True, audit=True):
         """Wrap ``fn`` with timing + audit; ``register=False`` returns
         the wrapped callable instead of registering a unary method
         (stream handlers reuse the same discipline for their resolve
-        step)."""
+        step); ``audit=False`` is for a handler that writes its own
+        records (one a path of a batched call)."""
         timed = _timed(name, fn, journal=fsm._journal)
-        if audit_writer is None:
+        if audit_writer is None or not audit:
             if register:
                 svc.unary(name, timed)
             return timed
@@ -129,6 +131,48 @@ def fs_master_service(fsm: FileSystemMaster,
         return out
 
     u("get_status", _get_status)
+
+    def _get_status_many(r):
+        """One status RPC for a list of paths: what ``get_status`` gives
+        each, in request order (duplicates allowed), in the columnar
+        form a listing ships. ONE stamp, taken before the first lookup
+        (every payload is at least as new as it). A path that fails
+        carries its own typed error in ``errors`` as ``[position,
+        error]`` and the others are answered; one audit record a path,
+        under the command ``get_status``. An error that speaks of this
+        master and not of the path (not primary, journal closed) fails
+        the call."""
+        from alluxio_tpu.utils.exceptions import (
+            PermissionDeniedError, UnavailableError,
+        )
+
+        v = fsm.invalidations.version
+        paths = r.get("paths") or []
+        answers = fsm.get_status_many(
+            paths, sync_interval_ms=r.get("sync_interval_ms", -1))
+        if audit_writer is not None:
+            from alluxio_tpu.security.audit import AuditContext
+            from alluxio_tpu.security.user import authenticated_user
+
+            user = authenticated_user()
+            name = user.name if user else ""
+            for path, a in zip(paths, answers):
+                audit_writer.append(AuditContext(
+                    command="get_status", src_path=str(path), user=name,
+                    allowed=not isinstance(a, PermissionDeniedError),
+                    succeeded=not isinstance(a, Exception)))
+        rows, errors = [], []
+        for i, a in enumerate(answers):
+            if isinstance(a, UnavailableError):
+                raise a
+            if isinstance(a, Exception):
+                errors.append([i, a.to_wire()])
+            else:
+                rows.append(a)
+        return {"md_version": v, "columnar": transpose(rows),
+                "errors": errors}
+
+    u("get_status_many", _get_status_many, audit=False)
     u("exists", lambda r: {"exists": fsm.exists(r["path"])})
     def _list_status_stream(r: dict):
         """Partial-response listing (reference: the streamed ListStatus

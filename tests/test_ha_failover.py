@@ -186,6 +186,34 @@ class TestStandbyReadServing:
         finally:
             m2.stop(), m1.stop()
 
+    def test_standby_serves_a_batched_status_call(self, tmp_path):
+        """``get_status_many`` is a standby read like ``get_status``:
+        served off the tailed state under the standby's own stamp and
+        marked, so a strong multi-endpoint client turns it into a
+        redirect; a path's own error stays that path's."""
+        from alluxio_tpu.utils.exceptions import FileDoesNotExistError
+
+        m1, m2 = start_primary_standby(tmp_path)
+        try:
+            pc = FsMasterClient(m1.address)
+            for name in ("/sa", "/sb"):
+                pc.create_directory(name)
+            standby = f"localhost:{m2.standby_rpc_port}"
+            sc = FsMasterClient(standby, retry_duration_s=10.0,
+                                fastpath=False)
+            wait_until(lambda: sc.exists("/sb"), msg="standby tail")
+            answers, stamp = sc.get_status_many(
+                ["/sb", "/absent", "/sa"], want_version=True)
+            assert [a.path for a in answers[::2]] == ["/sb", "/sa"]
+            assert isinstance(answers[1], FileDoesNotExistError)
+            assert stamp == m2.fs_master.invalidations.version >= 1
+            raw = RpcChannel(standby).call(
+                FS_SERVICE, "get_status_many", {"paths": ["/sa"]})
+            assert raw["standby"] is True
+            assert raw["leader"] == m1.client_address
+        finally:
+            m2.stop(), m1.stop()
+
     def test_standby_md_version_matches_primary(self, tmp_path):
         """The invalidation log is journal-driven, so a caught-up
         standby counts the EXACT version sequence the primary stamps —
@@ -395,6 +423,13 @@ class TestLocationDriftInvalidation:
             with pytest.raises(NotPrimaryError) as ei:
                 RpcChannel(standby).call(FS_SERVICE, "get_status",
                                          {"path": "/ufs-only.bin"})
+            assert ei.value.leader == m1.client_address
+            # in a batched call the load fails the CALL, not the path:
+            # the error speaks of this master, and the primary can answer
+            with pytest.raises(NotPrimaryError) as ei:
+                RpcChannel(standby).call(
+                    FS_SERVICE, "get_status_many",
+                    {"paths": ["/warm", "/ufs-only.bin"]})
             assert ei.value.leader == m1.client_address
         finally:
             m2.stop(), m1.stop()

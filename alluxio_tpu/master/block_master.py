@@ -17,7 +17,7 @@ import logging
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set
+from typing import Dict, List, Optional, Set, Tuple
 
 from alluxio_tpu.journal.format import EntryType, JournalEntry, Journaled
 from alluxio_tpu.journal.system import JournalSystem
@@ -105,13 +105,16 @@ class BlockMaster(Journaled):
         #: every derived per-file residency figure (in_memory_percentage,
         #: top tiers) is still valid — consumed by the listing cache
         self.location_version = 0
-        #: block id -> {mesh position -> reporting host}: the HBM warm
-        #: set reported by JAX clients (§2.11 device-mesh block map)
-        self._device_locations: Dict[int, Dict[int, str]] = {}
-        #: reporting host -> last report time (ms); reports are leases —
+        #: block id -> {(reporter, mesh position) -> reporting host}: the
+        #: HBM warm sets reported by JAX clients (§2.11 device-mesh block
+        #: map). A record belongs to its REPORTER (one warm set; the host
+        #: itself for a caller that names none), so two warm sets of one
+        #: host holding the same block at the same position stay apart
+        self._device_locations: Dict[int, Dict[Tuple[str, int], str]] = {}
+        #: reporter -> (host, last report time ms); reports are leases —
         #: a client that dies without clearing ages out (see
         #: prune_device_reports, driven by the lost-worker heartbeat)
-        self._device_report_ms: Dict[str, int] = {}
+        self._device_reports: Dict[str, Tuple[str, int]] = {}
         self.device_report_ttl_ms = 5 * 60 * 1000
         #: ids below this mark are covered by a journaled reservation
         self._container_reserved = 0
@@ -470,62 +473,70 @@ class BlockMaster(Journaled):
                     host=host,
                     tiered_identity=TieredIdentity.from_spec(
                         f"host={host},mesh={pos}")))
-            for pos, host in self._device_locations.get(
-                meta.block_id, {}).items()]
+            # two warm sets of one host at one position: one location
+            for pos, host in dict.fromkeys(
+                (pos, host) for (_rep, pos), host in
+                self._device_locations.get(meta.block_id, {}).items())]
         return BlockInfo(block_id=meta.block_id,
                          length=max(meta.length, 0), locations=locations,
                          device_locations=device_locations)
 
     # ------------------------------------------ device (HBM) warm-set map
     def report_device_blocks(self, host: str,
-                             mesh_blocks: Dict[int, List[int]]) -> None:
-        """A JAX client reports its warm set: mesh position -> resident
+                             mesh_blocks: Dict[int, List[int]],
+                             reporter: str = "") -> None:
+        """A JAX client reports one warm set: mesh position -> resident
         block ids (SURVEY §2.11 "block map keyed by device mesh
-        position"). Replaces that host's previous report, so a warm-set
-        turnover is one call. Device residency is cache state like worker
-        tiers — volatile, never journaled."""
+        position"). Replaces that REPORTER's previous report (``reporter``
+        names the warm set; empty = the host itself), so a warm-set
+        turnover is one call and a second warm set of the same host
+        leaves this one's record alone. Device residency is cache state
+        like worker tiers — volatile, never journaled."""
+        reporter = reporter or host
         with self._lock:
-            self._drop_device_host(host)
+            self._drop_device_reporter(reporter)
             for pos, bids in mesh_blocks.items():
                 for bid in bids:
                     self._device_locations.setdefault(
-                        int(bid), {})[int(pos)] = host
+                        int(bid), {})[(reporter, int(pos))] = host
             self.location_version += 1
             if mesh_blocks:
-                self._device_report_ms[host] = self._clock.millis()
+                self._device_reports[reporter] = (
+                    host, self._clock.millis())
 
-    def _drop_device_host(self, host: str) -> None:
+    def _drop_device_reporter(self, reporter: str) -> None:
         for bid in list(self._device_locations):
             entry = self._device_locations[bid]
-            for pos in [p for p, h in entry.items() if h == host]:
-                del entry[pos]
+            for key in [k for k in entry if k[0] == reporter]:
+                del entry[key]
             if not entry:
                 del self._device_locations[bid]
-        self._device_report_ms.pop(host, None)
+        self._device_reports.pop(reporter, None)
         # device (HBM) residency feeds listing wire dicts — stale cache
         # entries would steer locality reads at hosts that dropped out
         self.location_version += 1
 
     def prune_device_reports(self) -> List[str]:
-        """Age out device reports from hosts that stopped renewing (a
+        """Age out device reports whose reporter stopped renewing (a
         crashed JAX client can't call clear); driven by the same
-        heartbeat as lost-worker detection."""
+        heartbeat as lost-worker detection. Returns the hosts whose
+        reports expired."""
         now = self._clock.millis()
         expired = []
         with self._lock:
-            for host, ts in list(self._device_report_ms.items()):
+            for reporter, (host, ts) in list(self._device_reports.items()):
                 if now - ts > self.device_report_ttl_ms:
-                    self._drop_device_host(host)
+                    self._drop_device_reporter(reporter)
                     expired.append(host)
-        return expired
+        return list(dict.fromkeys(expired))
 
-    def clear_device_blocks(self, host: str) -> None:
-        self.report_device_blocks(host, {})
+    def clear_device_blocks(self, host: str, reporter: str = "") -> None:
+        self.report_device_blocks(host, {}, reporter)
 
     def device_block_map(self) -> Dict[int, Dict[int, str]]:
         """block id -> {mesh position: host} (introspection/report)."""
         with self._lock:
-            return {bid: dict(m)
+            return {bid: {pos: host for (_rep, pos), host in m.items()}
                     for bid, m in self._device_locations.items()}
 
     def get_block_infos(self, block_ids: List[int]) -> List[BlockInfo]:

@@ -38,12 +38,31 @@ Cold loads still ride the worker data plane (UFS -> worker tier -> host
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import time
+import uuid
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from alluxio_tpu.metrics import metrics
 from alluxio_tpu.parallel.mesh import DATA_AXIS, named_sharding
+from alluxio_tpu.utils.tracing import tracer
+
+
+#: opens of one row before a segment released under it is an error
+_REOPEN_TRIES = 16
+
+
+@contextlib.contextmanager
+def _timed(span, counter):
+    """Enter ``span``; its time is also added to ``counter``, in us."""
+    t0 = time.perf_counter()
+    with span:
+        yield
+    counter.inc(int((time.perf_counter() - t0) * 1e6))
 
 
 class MeshBlockCache:
@@ -75,7 +94,21 @@ class MeshBlockCache:
         #: global block index -> master block id (for placement reports)
         self.block_ids: List[int] = []
         self.client_host = client_host or socket.gethostname()
+        #: this warm set's own name in the master's device block map:
+        #: two caches of one host keep separate records there
+        self.reporter = f"{self.client_host}/{uuid.uuid4().hex[:12]}"
         self._block_client = None
+        m = metrics()
+        self._blocks_loaded = m.counter("Client.JaxMeshBlocksLoaded")
+        self._bytes_loaded = m.counter("Client.JaxMeshBytesLoaded")
+        #: host time of a load, summed over the pool's threads
+        self._host_read_us = m.counter("Client.JaxMeshHostReadUs")
+        self._stack_us = m.counter("Client.JaxMeshStackUs")
+        #: a shard's device_put, from its dispatch to its being ready
+        self._put_us = m.counter("Client.JaxMeshPutUs")
+        self._reports = m.counter("Client.JaxMeshPlacementReports")
+        self._report_failures = m.counter(
+            "Client.JaxMeshPlacementReportFailures")
         #: path -> master block ids (filled from loaders; avoids a
         #: get_status RPC per path on every resolve)
         self._bids_by_path: Dict[str, List[int]] = {}
@@ -94,20 +127,24 @@ class MeshBlockCache:
                     loader=None, report: bool = True,
                     io_threads: int = 8):
         """Materialize the warm set: every addressable device's shard is
-        loaded from the host-local worker tier (leased mmap, its pages
-        made present -> one device_put per device), then assembled into
-        one global sharded array WITHOUT any host seeing the whole
-        dataset. Per-device host reads run in an IO thread pool and the
-        device_puts are issued as each shard completes, so transfer
-        overlaps the next reads.
+        loaded from the host-local worker tier and assembled into one
+        global sharded array WITHOUT any host seeing the whole dataset.
+        One pool thread a mesh position of this host (so at most
+        ``min(io_threads, positions)`` threads ever work: 4 of the
+        default 8 on a 4-chip host) reads its shard's rows (leased mmap,
+        pages made present), stacks them into ONE heap array (a copy of
+        the shard beside the mapped views) and hands that to ONE
+        ``device_put``, waited for in the same thread: transfers overlap
+        each other and slower positions' reads, never the reads of
+        their own shard. Returns with the set resident.
 
         ``report=True`` registers this host's device placement with the
         master block map (SURVEY §2.11 "block map keyed by device mesh
         position") so the control plane can steer consumers at warm
         copies one ICI hop away.
 
-        ``loader``: an existing DeviceBlockLoader to reuse (tests); else
-        one is built per call.
+        ``loader``: an existing DeviceBlockLoader to reuse (a caller
+        that made one over the same list); else one is built per call.
         """
         from concurrent.futures import ThreadPoolExecutor
 
@@ -132,36 +169,74 @@ class MeshBlockCache:
             my_positions = [p for p in range(self.n_devices)
                             if mesh_devs[p].id in addressable]
 
-            def read_shard(d_pos: int):
-                rows = []
-                for k in range(per_dev):
-                    g = d_pos * per_dev + k
-                    rows.append(self._host_row(loader, g, n, elems))
-                return d_pos, np.stack(rows)  # (per_dev, elems)
+            def load_shard(d_pos: int):
+                globals_ = range(d_pos * per_dev, (d_pos + 1) * per_dev)
+                return self._put_rows(loader, d_pos, globals_, n, elems,
+                                      mesh_devs[d_pos])
 
-            shards = {}
-            # host reads (mmap/stream) parallelize; device_put is issued
-            # the moment a shard's rows are ready (async transfer)
-            with ThreadPoolExecutor(max_workers=max(1, io_threads)) as ex:
-                for d_pos, local in ex.map(read_shard, my_positions):
-                    shards[d_pos] = jax.device_put(local, mesh_devs[d_pos])
-            global_shape = (per_dev * self.n_devices, elems)
-            cached = jax.make_array_from_single_device_arrays(
-                global_shape, sharding,
-                [shards[p] for p in my_positions])
-            if report:
-                self.report_placement(fs, my_positions, per_dev, n)
+            with tracer().span(
+                    "atpu.mesh.load_global", blocks=n,
+                    devices=len(my_positions),
+                    bytes=len(my_positions) * per_dev * self.block_bytes):
+                with ThreadPoolExecutor(
+                        max_workers=max(1, io_threads)) as ex:
+                    # a copy of THIS context a shard: its spans are the
+                    # children of load_global's in the ring
+                    loading = [ex.submit(contextvars.copy_context().run,
+                                         load_shard, p)
+                               for p in my_positions]
+                    shards = {p: f.result()
+                              for p, f in zip(my_positions, loading)}
+                global_shape = (per_dev * self.n_devices, elems)
+                cached = jax.make_array_from_single_device_arrays(
+                    global_shape, sharding,
+                    [shards[p] for p in my_positions])
+                if report:
+                    self.report_placement(fs, my_positions, per_dev, n)
             return cached
         finally:
             if own_loader:
                 loader.close()
 
+    def _put_rows(self, loader, d_pos: int, globals_, n: int, elems: int,
+                  device):
+        """The rows ``globals_`` of mesh position ``d_pos`` on its
+        device, ready: host rows, one stack, one ``device_put``."""
+        import jax
+
+        span = tracer().span
+        real = sum(1 for g in globals_ if g < n)
+        with _timed(span("atpu.mesh.read_shard", pos=d_pos, blocks=real),
+                    self._host_read_us):
+            rows = [self._host_row(loader, g, n, elems) for g in globals_]
+        with _timed(span("atpu.mesh.stack"), self._stack_us):
+            local = np.stack(rows)  # (len(globals_), elems)
+        del rows
+        with _timed(span("atpu.mesh.device_put", bytes=local.nbytes),
+                    self._put_us):
+            on_device = jax.device_put(local, device)
+            on_device.block_until_ready()
+        self._blocks_loaded.inc(real)
+        self._bytes_loaded.inc(real * self.block_bytes)
+        return on_device
+
     def _host_row(self, loader, g: int, n: int, elems: int):
         if g >= n:  # pad the ragged tail with zeros
             return np.zeros(elems, self.dtype)
         from alluxio_tpu import native
+        from alluxio_tpu.shm import ShmSegmentUnavailableError
 
-        host = loader.host_block(*self.plan[g])
+        # the pool's threads open through ONE segment cache: where it is
+        # smaller than their number, a row's segment can be released
+        # (the others' opens turn the LRU over) between its open and its
+        # view, which raises, typed; the next open leases it again
+        for attempt in range(_REOPEN_TRIES):
+            try:
+                host = loader.host_block(*self.plan[g])
+                break
+            except ShmSegmentUnavailableError:
+                if attempt == _REOPEN_TRIES - 1:
+                    raise
         # one kernel call maps the block; np.stack would fault it a page
         native.prefault(host)
         if host.shape[0] != elems:
@@ -189,7 +264,8 @@ class MeshBlockCache:
                          per_dev: int, n: int) -> None:
         """Tell the master which blocks are HBM-resident at which mesh
         position (this host's shard of the warm set only — each host
-        reports its own; the master merges)."""
+        reports its own; the master merges). A failure is counted
+        (``Client.JaxMeshPlacementReportFailures``), never raised."""
         client = self._block_master_client(fs)
         if client is None:
             return
@@ -201,20 +277,25 @@ class MeshBlockCache:
                     if self.block_ids[g] >= 0]
             if bids:
                 mesh_blocks[pos] = bids
-        try:
-            client.report_device_blocks(self.client_host, mesh_blocks)
-        except Exception:  # noqa: BLE001 placement is advisory cache state
-            pass
+        with tracer().span("atpu.mesh.report_placement",
+                           positions=len(mesh_blocks)):
+            try:
+                client.report_device_blocks(self.client_host, mesh_blocks,
+                                            reporter=self.reporter)
+                self._reports.inc()
+            except Exception:  # noqa: BLE001 placement is advisory cache state
+                self._report_failures.inc()
 
     def drop_placement(self, fs) -> None:
-        """Warm set released: clear this host's device block map entries
-        (pairs with eviction/close)."""
+        """Warm set released: clear this warm set's device block map
+        entries (pairs with eviction/close)."""
         client = self._block_master_client(fs)
         if client is not None:
             try:
-                client.clear_device_blocks(self.client_host)
+                client.clear_device_blocks(self.client_host,
+                                           reporter=self.reporter)
             except Exception:  # noqa: BLE001 advisory
-                pass
+                self._report_failures.inc()
 
     def _block_master_client(self, fs):
         if self._block_client is None:
@@ -392,12 +473,10 @@ class MeshBlockCache:
                                  if g // per_dev == pos)
                 if not touched:
                     continue
-                data = np.stack([self._host_row(loader, g, n, elems)
-                                 for g in touched])
+                data = self._put_rows(loader, pos, touched, n, elems, dev)
                 rows = np.asarray([g - pos * per_dev for g in touched])
                 shards[dev] = _update(shards[dev],
-                                      jax.device_put(rows, dev),
-                                      jax.device_put(data, dev))
+                                      jax.device_put(rows, dev), data)
             cached = jax.make_array_from_single_device_arrays(
                 (per_dev * self.n_devices, elems), sharding,
                 [shards[mesh_devs[p]] for p in my_positions])

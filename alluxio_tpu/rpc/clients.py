@@ -504,16 +504,18 @@ class BlockMasterClient(_BaseClient):
                                               {"block_id": block_id}))
 
     def report_device_blocks(self, host: str,
-                             mesh_blocks: "Dict[int, List[int]]") -> None:
-        """Report this client's HBM warm set (mesh pos -> block ids);
-        replaces the previous report from the same host."""
+                             mesh_blocks: "Dict[int, List[int]]",
+                             reporter: str = "") -> None:
+        """Report one HBM warm set of this client (mesh pos -> block
+        ids); replaces the previous report of the same ``reporter`` (the
+        warm set's own name; empty = the host itself)."""
         self._call("report_device_blocks", {
-            "host": host,
+            "host": host, "reporter": reporter,
             "mesh_blocks": {str(k): [int(b) for b in v]
                             for k, v in mesh_blocks.items()}})
 
-    def clear_device_blocks(self, host: str) -> None:
-        self.report_device_blocks(host, {})
+    def clear_device_blocks(self, host: str, reporter: str = "") -> None:
+        self.report_device_blocks(host, {}, reporter)
 
     def device_block_map(self) -> "Dict[int, Dict[int, str]]":
         resp = self._call("device_block_map", {})

@@ -330,8 +330,8 @@ def block_master_service(bm: BlockMaster) -> ServiceDefinition:
     u("get_block_infos", lambda r: {"infos": [
         b.to_wire() for b in bm.get_block_infos(r["block_ids"])]})
     u("report_device_blocks", lambda r: (bm.report_device_blocks(
-        r["host"], {int(k): v for k, v in r["mesh_blocks"].items()}),
-        {})[-1])
+        r["host"], {int(k): v for k, v in r["mesh_blocks"].items()},
+        r.get("reporter", "")), {})[-1])
     u("device_block_map", lambda r: {"map": {
         str(bid): m for bid, m in bm.device_block_map().items()}})
     # wire default EXCLUDES quarantined workers: remote callers of this

@@ -26,7 +26,11 @@ Design:
   ``gather_all`` (every device sees every block; ICI all-gather),
   ``ring_shift`` (each device reads its neighbor's shard; ICI ppermute —
   the sequence-parallel access pattern), and ``global_batch`` (assemble a
-  batch from blocks wherever they live, fused into the consumer's jit).
+  batch from blocks wherever they live, fused into the consumer's jit:
+  every device copies the rows it owns into a zero-padded (batch, elems)
+  buffer, one Pallas kernel of row copies with the ownership mask folded
+  into the fetch, ``ops/row_copy_kernel.py``, and ONE ``psum`` merges
+  the buffers; no gather, no all-gather).
 - ``replicate`` broadcasts a hot shard to every device
   (``device_put_replicated`` fan-out; reference analogue:
   ``ReplicationChecker`` + ``job/plan/replicate`` — but one collective,
@@ -356,11 +360,15 @@ class MeshBlockCache:
     def global_batch(self, cached, indices):
         """Assemble a batch of blocks by GLOBAL index regardless of which
         device caches them, moving O(batch) bytes over ICI — NOT the
-        whole warm set. Each device takes the requested rows it owns from
-        its local shard (others contribute zeros), then ONE psum merges
-        the batch: ICI traffic is the reduction of a (batch, elems)
-        buffer, independent of warm-set size. ``indices``: 1-D array of
-        global block ids. Output is replicated (every device gets the
+        whole warm set. Each device COPIES the requested rows it owns
+        out of its local shard into a zero-padded (batch, elems) buffer
+        (``ops/row_copy_kernel.masked_rows``: row copies with the
+        ownership mask folded in, no gather; rows of other owners stay
+        zero and are never read), then ONE psum merges the batch: ICI
+        traffic is the reduction of a (batch, elems) buffer, independent
+        of warm-set size. ``indices``: 1-D array of global block ids; an
+        index no device owns, negative or past the last padded row, is
+        a row of zeros. Output is replicated (every device gets the
         whole batch); compose into the consumer's jit so XLA overlaps
         the collective with compute."""
         import jax.numpy as jnp
@@ -371,13 +379,20 @@ class MeshBlockCache:
     def batch_fn(self, per_dev: int):
         """The jitted O(batch) assembler, cached per ``per_dev`` (exposed
         so consumers can fuse it into their step and tests can inspect
-        the lowering)."""
+        the lowering): ``masked_rows`` on every device's shard (Mosaic
+        on TPUs, the Pallas interpreter on any other mesh), then one
+        ``psum`` over the mesh axis. The cache's elements are 1, 2 or 4
+        bytes wide."""
         cached_fn = self._batch_fns.get(per_dev)
         if cached_fn is not None:
             return cached_fn
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
+
+        from alluxio_tpu.ops.row_copy_kernel import masked_rows
+
+        interpret = self.mesh.devices.flat[0].platform != "tpu"
 
         @jax.jit
         def _assemble(x, idx):
@@ -386,11 +401,9 @@ class MeshBlockCache:
                 pos = jax.lax.axis_index(self.axis)
                 local_idx = idx_rep - pos * per_dev
                 mine = (local_idx >= 0) & (local_idx < per_dev)
-                rows = jnp.take(local,
-                                jnp.clip(local_idx, 0, per_dev - 1),
-                                axis=0)           # (B, elems)
-                rows = jnp.where(mine[:, None], rows,
-                                 jnp.zeros((), local.dtype))
+                rows = masked_rows(
+                    local, jnp.clip(local_idx, 0, per_dev - 1), mine,
+                    interpret=interpret)          # (B, elems)
                 # O(batch) collective: merge owners' contributions
                 return jax.lax.psum(rows, self.axis)
 

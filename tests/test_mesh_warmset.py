@@ -111,19 +111,24 @@ def test_load_global_equals_the_reference_table_row_for_row(
     fs.close()
 
 
-@pytest.mark.parametrize("case", ["every_owner", "duplicates",
-                                  "last_padded_index"])
-def test_global_batch_and_batch_fn_equal_the_reference(cluster, mesh, case):
-    import jax.numpy as jnp
+#: case -> (blocks written, the cache's dtype). 11 blocks: per_dev 3,
+#: the last owner holds two blocks and a zero row
+BATCH_CASES = {
+    "every_owner": (11, np.uint8),
+    "duplicates": (11, np.uint8),
+    "last_padded_index": (11, np.uint8),
+    "one_row": (11, np.uint8),
+    "batch_not_a_multiple_of_the_devices": (11, np.uint8),
+    "more_rows_than_one_tile": (11, np.uint8),
+    "one_block_a_device": (4, np.uint8),
+    "index_past_the_end": (11, np.uint8),
+    "negative_index": (11, np.uint8),
+    "uint16_cache": (11, np.uint16),
+    "int32_cache": (6, np.int32),
+}
 
-    n = 11  # per_dev 3: the last owner holds two blocks and a zero row
-    fs = cluster.file_system()
-    paths, blocks = _write(fs, 34, n)
-    cache = MeshBlockCache(mesh, block_bytes=BLOCK)
-    cached = cache.load_global(fs, paths, report=False)
-    want = ref.table(blocks, N_DEV, BLOCK)
-    per_dev = ref.per_dev(n, N_DEV)
-    rng = np.random.default_rng([34, len(case)])
+
+def _batches(case: str, n: int, per_dev: int, rng):
     if case == "every_owner":
         # two rows of every owner's shard, shuffled
         batches = [rng.permutation(np.concatenate([
@@ -132,14 +137,55 @@ def test_global_batch_and_batch_fn_equal_the_reference(cluster, mesh, case):
             for pos in range(N_DEV)])) for _ in range(4)]
         assert all(sorted(ref.owner(g, n, N_DEV) for g in b)
                    == [0, 0, 1, 1, 2, 2, 3, 3] for b in batches)
-    elif case == "duplicates":
-        batches = [np.array([4, 4, 9, 0, 4, 9, 0, 0]), np.full(8, 7)]
-    else:
-        batches = [np.array([11, 0, 10, 9]), np.array([11])]
+        return batches
+    if case == "duplicates":
+        return [np.array([4, 4, 9, 0, 4, 9, 0, 0]), np.full(8, 7)]
+    if case == "last_padded_index":
+        return [np.array([11, 0, 10, 9]), np.array([11])]
+    if case == "one_row":
+        return [np.array([g]) for g in (0, 5, 10)]
+    if case == "batch_not_a_multiple_of_the_devices":
+        return [np.array([9, 2, 6]), np.array([1, 1, 10, 3, 8]),
+                np.array([7, 0, 5, 4, 2, 9, 9])]
+    if case == "more_rows_than_one_tile":
+        # 9, 16 and 19 rows: past one group of eight, two whole groups,
+        # two groups and a tail
+        return [rng.integers(0, N_DEV * per_dev, size=b) for b in (9, 16, 19)]
+    if case == "one_block_a_device":
+        return [np.array([3, 0, 2, 1]), np.array([2, 2, 0]), np.array([1])]
+    if case == "index_past_the_end":
+        # 12 rows in the table: 12 is the first index no owner holds
+        return [np.array([12, 3, 100, 11]), np.array([2 ** 31 - 1])]
+    if case == "negative_index":
+        return [np.array([-1, 3, -12, 0]), np.array([-13]),
+                np.array([-(2 ** 31), 10])]
+    # a cache of wider elements: any rows, every owner among them
+    return [rng.permutation(n)[:5], np.array([n - 1, 0, n - 1])]
+
+
+@pytest.mark.parametrize("case", list(BATCH_CASES))
+def test_global_batch_and_batch_fn_equal_the_reference(cluster, mesh, case):
+    """Byte for byte against the plain reference; an index no owner
+    holds (past the last padded row, or negative) is a zero row."""
+    import jax.numpy as jnp
+
+    n, dtype = BATCH_CASES[case]
+    fs = cluster.file_system()
+    paths, blocks = _write(fs, 34, n)
+    cache = MeshBlockCache(mesh, block_bytes=BLOCK, dtype=dtype)
+    cached = cache.load_global(fs, paths, report=False)
+    assert cached.dtype == dtype
+    assert cached.shape == (N_DEV * ref.per_dev(n, N_DEV),
+                            BLOCK // np.dtype(dtype).itemsize)
+    want = ref.table(blocks, N_DEV, BLOCK)
+    per_dev = ref.per_dev(n, N_DEV)
+    rng = np.random.default_rng([34, len(case)])
     fn = cache.batch_fn(per_dev)
-    for idx in batches:
-        got = np.asarray(cache.global_batch(cached, idx))
-        assert np.array_equal(got, ref.batch(want, idx))
+    for idx in _batches(case, n, per_dev, rng):
+        got = cache.global_batch(cached, idx)
+        assert got.dtype == dtype and got.sharding.is_fully_replicated
+        got = np.asarray(got)
+        assert np.array_equal(got.view(np.uint8), ref.batch(want, idx))
         fused = np.asarray(fn(cached, jnp.asarray(idx, jnp.int32)))
         assert np.array_equal(fused, got)
     fs.close()
@@ -155,6 +201,27 @@ def test_batch_assembly_lowers_without_all_gather(cluster, mesh):
     hlo = cache.batch_fn(2).lower(
         cached, jnp.arange(8, dtype=jnp.int32)).compile().as_text()
     assert "all-gather" not in hlo and "all-reduce" in hlo
+    fs.close()
+
+
+@pytest.mark.parametrize("batch", [8, 19])
+def test_batch_assembly_lowers_to_row_copies_and_one_all_reduce(
+        cluster, mesh, batch):
+    """A chip builds its contribution by row copies out of its shard,
+    not by a gather, and the exchange stays ONE all-reduce of the batch
+    (here the Pallas interpreter's lowering; the chip's own is compiled
+    in ``test_record_batches.py``, beside the other v5e compiles)."""
+    import jax.numpy as jnp
+
+    fs = cluster.file_system()
+    paths, _blocks = _write(fs, 42, 8)
+    cache = MeshBlockCache(mesh, block_bytes=BLOCK)
+    cached = cache.load_global(fs, paths, report=False)
+    hlo = cache.batch_fn(2).lower(
+        cached, jnp.arange(batch, dtype=jnp.int32) % 8).compile().as_text()
+    assert " all-reduce(" in hlo and " all-gather(" not in hlo
+    assert hlo.count(" all-reduce(") == 1
+    assert " gather(" not in hlo  # "all-gather(" does not hide it
     fs.close()
 
 

@@ -291,18 +291,23 @@ def test_counters_and_span_say_what_left_the_iterator(cluster,
 
 # ---- the deployment's own shapes, compiled for the v5e without a chip ------
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -330,3 +335,38 @@ def test_imagenet64_block_program_compiles_for_the_v5e(one_chip):
     assert "atpu_record_batch" in text.split("\n", 1)[0]
     rows = block_bytes // record_bytes
     assert f"u8[{rows},{record_bytes}]" not in text
+
+
+@pytest.mark.parametrize("dtype,batch", [
+    ("uint8", 8),     # the four-chip cell's own step
+    ("uint8", 19),    # two groups of eight and a tail
+    ("uint16", 8), ("int32", 8)])
+def test_mesh_warmset_batch_program_compiles_for_four_v5e_chips(
+        topo, dtype, batch):
+    """``MeshBlockCache.batch_fn`` at the four-chip cell's shapes (128
+    blocks of 32 MiB a chip): Mosaic takes the row-copy kernel on the
+    shard as it lies in HBM (rows packed into words, no copy of the
+    shard to another layout), and the exchange is one all-reduce."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from alluxio_tpu.parallel.ici_store import MeshBlockCache
+    from alluxio_tpu.parallel.mesh import make_mesh
+
+    block_bytes, per_dev = 32 << 20, 128
+    mesh = make_mesh(devices=topo.devices)
+    cache = MeshBlockCache(mesh, block_bytes=block_bytes, dtype=dtype)
+    elems = block_bytes // np.dtype(dtype).itemsize
+    text = cache.batch_fn(per_dev).lower(
+        jax.ShapeDtypeStruct((4 * per_dev, elems), dtype,
+                             sharding=NamedSharding(mesh, P("data", None))),
+        jax.ShapeDtypeStruct((batch,), jnp.int32,
+                             sharding=NamedSharding(mesh, P())),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "atpu_masked_rows" in text
+    assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1
+    assert " all-gather(" not in text and " gather(" not in text
+    shard = f"[{per_dev},{elems}]"
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and shard in ln]

@@ -34,5 +34,9 @@ def owner(g: int, n_blocks: int, n_devices: int) -> int:
 
 def batch(tab: np.ndarray, indices) -> np.ndarray:
     """``(len(indices), block_bytes)``: row ``r`` is global row
-    ``indices[r]`` (duplicates and padded rows included)."""
-    return np.stack([tab[int(g)] for g in indices])
+    ``indices[r]`` (duplicates and padded rows included); an index that
+    names no row of the table, negative or past its end, is a zero
+    row."""
+    zero = np.zeros(tab.shape[1], tab.dtype)
+    return np.stack([tab[int(g)] if 0 <= int(g) < len(tab) else zero
+                     for g in indices])

@@ -89,12 +89,14 @@ class HbmPageStore:
         self._jax = jax
         # what the tier does that its callers cannot count around it:
         # pages taken in, pages evicted to make room for one, adopts
-        # refused. All counted in here, under the one lock, so a window
-        # never holds more evictions than adopts of equal-size pages
+        # refused, adopts of a page already held. All counted in here,
+        # under the one lock, so a window never holds more evictions
+        # than adopts of equal-size pages
         m = metrics()
         self._adopts = m.counter("Client.JaxHbmAdopts")
         self._evictions = m.counter("Client.JaxHbmEvictions")
         self._adopt_rejected = m.counter("Client.JaxHbmAdoptRejected")
+        self._adopt_duplicates = m.counter("Client.JaxHbmAdoptDuplicates")
         self._capacity = capacity_bytes
         self._device = device or default_device()
         self._pages: Dict[PageId, "jax.Array"] = {}
@@ -144,9 +146,13 @@ class HbmPageStore:
     def adopt(self, page_id: PageId, device_array) -> bool:
         """Retain an ALREADY device-resident array (e.g. the loader just
         ``device_put`` it for a consumer) without a second transfer.
-        Returns False when it cannot fit after eviction."""
+        Returns False when it cannot fit after eviction. A page the
+        store already holds keeps its array: the one offered was a
+        wasted transfer (two threads missed the same block), counted
+        and dropped with the caller's reference."""
         with self._lock:
             if page_id in self._pages:
+                self._adopt_duplicates.inc()
                 return True
             size = device_array.nbytes
             if size > self._capacity or not self._ensure_room(size):

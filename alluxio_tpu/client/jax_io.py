@@ -41,7 +41,11 @@ from alluxio_tpu.conf import Keys
 from alluxio_tpu.metrics import metrics
 from alluxio_tpu.metrics.stall import (BUCKET_ADVICE, SIZE_BUCKETS,
                                        STALL_BUCKETS, size_bucket)
+from alluxio_tpu.shm import ShmSegmentUnavailableError
 from alluxio_tpu.utils.tracing import current_span, tracer
+
+#: opens of one block before a segment released under it is an error
+_REOPEN_TRIES = 16
 
 
 #: live StepStats instances backing the ONE process-level
@@ -356,12 +360,26 @@ class DeviceBlockLoader:
                     raise RuntimeError("loader is closed")
                 self._all_streams.append(f)
             streams[path] = f
-        stream = f.block_stream(index)
-        view = getattr(stream, "numpy_view", None)
-        if view is not None:
+        # every thread of the process opens through ONE segment cache
+        # (the producer, the prefetch agent's adopt thread, a mesh
+        # load's pool): another thread's opens can turn its LRU over and
+        # release this block's segment between its open and its view,
+        # which raises, typed; the next open finds the stream stale and
+        # leases the block again
+        for attempt in range(_REOPEN_TRIES):
+            stream = f.block_stream(index)
+            view = getattr(stream, "numpy_view", None)
+            if view is None:
+                break
+            try:
+                host = view(dtype=self._dtype)
+            except ShmSegmentUnavailableError:
+                if attempt == _REOPEN_TRIES - 1:
+                    raise
+                continue
             self._m.counter("Client.JaxShortCircuitBlocks").inc()
             self._tls.last_bucket = "shm"
-            return view(dtype=self._dtype)
+            return host
         self._m.counter("Client.JaxStreamedBlocks").inc()
         # striped remote reads expose their assembly buffer as a view:
         # frombuffer wraps it zero-copy, so the bytes go straight from
@@ -426,8 +444,11 @@ class DeviceBlockLoader:
 
     def prefetch_into_hbm(self, ref) -> bool:
         """Prefetch-agent hook: host-read one block and adopt it into
-        the HBM tier ahead of its consume (runs on the agent's heartbeat
-        thread; per-thread streams keep it off the producer's state)."""
+        the HBM tier ahead of its consume (runs on the agent's adopt
+        thread; per-thread streams keep it off the producer's state).
+        Where the producer missed the same block meanwhile, one of the
+        two transfers is dropped by the store and counted there
+        (``Client.JaxHbmAdoptDuplicates``)."""
         if self._hbm is None or self._closed:
             return False
         info = self._infos.get(ref.path)
@@ -474,7 +495,7 @@ class DeviceBlockLoader:
         (worker RPCs, mmap setup, making the mapping's pages present)
         ahead of the consumer, so the device_put stream never stalls on
         per-block host latency. On an HBM miss that one thread sets the
-        pace: the consumer waits on it for 98% of a scan's window
+        pace: the consumer waits on it for 80% of a scan's window
         (``loader.get_wait_share``; PERF.md section 5 has the split).
         The queue is bounded, and an abandoned generator unblocks the
         producer via a stop flag.

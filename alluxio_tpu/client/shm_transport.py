@@ -185,7 +185,16 @@ class ShmTransport:
                          self._renew_fraction, mm)
         victims = []
         with self._lock:
-            self._segments[block_id] = seg
+            held = self._segments.get(block_id)
+            if held is not None and not held.dead:
+                # another thread leased and mapped this block since our
+                # look-up missed: one segment a block. Ours goes back
+                # now; overwriting the entry would orphan ITS lease
+                # until the TTL
+                victims.append(seg)
+                seg = held
+            else:
+                self._segments[block_id] = seg
             self._segments.move_to_end(block_id)
             while len(self._segments) > self._cache_max:
                 victims.append(self._segments.popitem(last=False)[1])

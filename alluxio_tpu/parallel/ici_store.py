@@ -56,10 +56,6 @@ from alluxio_tpu.parallel.mesh import DATA_AXIS, named_sharding
 from alluxio_tpu.utils.tracing import tracer
 
 
-#: opens of one row before a segment released under it is an error
-_REOPEN_TRIES = 16
-
-
 @contextlib.contextmanager
 def _timed(span, counter):
     """Enter ``span``; its time is also added to ``counter``, in us."""
@@ -228,19 +224,10 @@ class MeshBlockCache:
         if g >= n:  # pad the ragged tail with zeros
             return np.zeros(elems, self.dtype)
         from alluxio_tpu import native
-        from alluxio_tpu.shm import ShmSegmentUnavailableError
 
-        # the pool's threads open through ONE segment cache: where it is
-        # smaller than their number, a row's segment can be released
-        # (the others' opens turn the LRU over) between its open and its
-        # view, which raises, typed; the next open leases it again
-        for attempt in range(_REOPEN_TRIES):
-            try:
-                host = loader.host_block(*self.plan[g])
-                break
-            except ShmSegmentUnavailableError:
-                if attempt == _REOPEN_TRIES - 1:
-                    raise
+        # (the loader opens a row again where another pool thread's open
+        # released its segment between open and view)
+        host = loader.host_block(*self.plan[g])
         # one kernel call maps the block; np.stack would fault it a page
         native.prefault(host)
         if host.shape[0] != elems:

@@ -15,6 +15,15 @@ resident in which tier when the consumer arrives:
   consumer's :class:`~alluxio_tpu.client.jax_io.DeviceBlockLoader`;
 - :mod:`~alluxio_tpu.prefetch.service` assembles the control loop from
   configuration and binds it to a loader.
+
+Where the data set is already whole in a same-host worker's MEM tier
+(the benchmark's ``shuffled-32m``), set ``atpu.prefetch.hbm.fraction``
+to 1.0: a DRAM placement there is a ``get_block_info`` and a
+``prefetch_pin`` RPC that stage nothing, and only an HBM placement
+moves bytes. On the chip (PERF.md section 6, PR 35) the service's ORDER
+is what such a job needs; its placements won and cost nothing at any
+budget tried, because the plan starts at the cursor, where the loader's
+producer already is (docs/prefetch.md, "A same-host warm worker").
 """
 
 from alluxio_tpu.prefetch.oracle import (  # noqa: F401

@@ -18,6 +18,9 @@ from typing import Iterator, List, Sequence, Tuple
 
 import numpy as np
 
+from alluxio_tpu.client.file_system import STATUS_BATCH_PATHS
+from alluxio_tpu.utils.tracing import tracer
+
 #: epoch sequences kept hot: the live epoch plus a lookahead window
 #: several epochs deep (planner) plus the previous epoch (stragglers)
 _EPOCH_CACHE_SIZE = 12
@@ -53,20 +56,27 @@ class DatasetManifest:
     def from_fs(cls, fs, paths: Sequence[str]) -> "DatasetManifest":
         """Resolve paths through the metadata master into block refs
         (block ids, per-block lengths, and the UFS coordinates the
-        async-cache path needs for cold loads)."""
+        async-cache path needs for cold loads). ONE status call for the
+        list: a ``FileInfo`` carries its block ids, length and block
+        size, and a block's offset and length follow from those, so a
+        job's start makes no call a file."""
         blocks: List[BlockRef] = []
         file_infos: List[tuple] = []
-        for path, info in zip(paths, fs.get_status_many(paths)):
-            file_infos.append((str(path), info))
-            fbis = fs.fs_master.get_file_block_info_list(info.path)
-            for i, fbi in enumerate(fbis):
-                blocks.append(BlockRef(
-                    path=info.path, block_index=i,
-                    block_id=fbi.block_info.block_id,
-                    length=fbi.block_info.length,
-                    offset=fbi.offset, file_id=info.file_id,
-                    ufs_path=info.ufs_path, mount_id=info.mount_id,
-                    persisted=info.persisted))
+        with tracer().span("atpu.prefetch.manifest", files=len(paths),
+                           calls=-(-len(paths) // STATUS_BATCH_PATHS)
+                           ) as sp:
+            for path, info in zip(paths, fs.get_status_many(paths)):
+                file_infos.append((str(path), info))
+                bs = info.block_size_bytes
+                for i, block_id in enumerate(info.block_ids):
+                    blocks.append(BlockRef(
+                        path=info.path, block_index=i, block_id=block_id,
+                        length=max(0, min(bs, info.length - i * bs)),
+                        offset=i * bs, file_id=info.file_id,
+                        ufs_path=info.ufs_path, mount_id=info.mount_id,
+                        persisted=info.persisted))
+            if sp is not None:
+                sp.tags["blocks"] = len(blocks)
         return cls(blocks=tuple(blocks), file_infos=tuple(file_infos))
 
     @property

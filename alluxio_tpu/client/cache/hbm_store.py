@@ -20,12 +20,14 @@ the window between ``get`` and dispatch.
 from __future__ import annotations
 
 import threading
-from typing import Dict, Optional, TYPE_CHECKING
+from typing import Dict, Optional, TYPE_CHECKING, Union
 
 from alluxio_tpu.client.cache.meta import PageId
 
 if TYPE_CHECKING:  # pragma: no cover
     import jax
+
+    from alluxio_tpu.client.cache.evictor import CacheEvictor
 
 
 def default_device():
@@ -73,14 +75,22 @@ class DevicePageLease:
 class HbmPageStore:
     """Device-memory page store with pin-lease eviction safety.
 
-    Eviction policy is a pluggable :class:`CacheEvictor` (LRU default) —
-    the same SPI the host page cache uses (reference:
-    ``client/file/cache/evictor/CacheEvictor.java``) — with pinned pages
-    skipped: the evictor nominates victims, the store vetoes pinned ones.
+    Eviction policy is a :class:`CacheEvictor`, the SPI the host page
+    cache uses (reference: ``client/file/cache/evictor/
+    CacheEvictor.java``), with pinned pages skipped: the evictor
+    nominates victims, the store vetoes pinned ones. ``evictor`` is a
+    kind ``CacheEvictor.create`` knows (LRU unless said) or an
+    instance: the store's OWNER chooses, from what it knows of its
+    reads. A ``DeviceBlockLoader`` bound to a prefetch service reads in
+    the oracle's order and hands in a :class:`NextUseCacheEvictor`
+    (the page used farthest ahead goes); every other owner keeps LRU.
+    The evictor is called under the store's lock and may take locks of
+    its own (store -> evictor -> prefetch scheduler -> oracle, never
+    back: nothing below calls into the store).
     """
 
     def __init__(self, capacity_bytes: int, device=None,
-                 evictor: str = "LRU") -> None:
+                 evictor: "Union[str, CacheEvictor]" = "LRU") -> None:
         import jax  # deferred: control-plane processes never import jax
 
         from alluxio_tpu.client.cache.evictor import CacheEvictor

@@ -34,6 +34,7 @@ from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from alluxio_tpu.client.cache.evictor import NextUseCacheEvictor
 from alluxio_tpu.client.cache.hbm_store import HbmPageStore, default_device
 from alluxio_tpu.client.cache.meta import PageId
 from alluxio_tpu.client.file_system import STATUS_BATCH_PATHS, FileSystem
@@ -227,6 +228,18 @@ class StepStats:
                 "verdict": verdict}
 
 
+def _next_use_evictor(svc, block_of: dict) -> NextUseCacheEvictor:
+    """The HBM tier's evictor under a prefetch service: a page's key is
+    when the oracle's order reads its block next (``served``: after the
+    access at the cursor, which is this page's own hit); a page the
+    loader does not plan is never read. It holds the service and the
+    loader's page -> block id map and NOT the loader: a closed loader
+    must go with its last reference, not wait in a cycle for the
+    collector (whose pass then lands in the next job start)."""
+    return NextUseCacheEvictor(
+        lambda pid, served: svc.next_use(block_of.get(pid), served))
+
+
 class DeviceBlockLoader:
     """Loads whole blocks of one or more files as device-resident uint8
     arrays, with an HBM retention cache and transfer prefetch."""
@@ -241,7 +254,18 @@ class DeviceBlockLoader:
         self._fs = fs
         self._dtype = np.dtype(dtype)
         self._device = device or default_device()
-        self._hbm = HbmPageStore(hbm_bytes, self._device) \
+        #: page -> master block id, the oracle's name for it
+        self._block_of: dict = {}
+        # which page the tier gives up is decided HERE, from whether
+        # the loader knows its future: with a prefetch service its
+        # order is the oracle's, so the page whose next use lies
+        # farthest ahead goes (on a fresh permutation every epoch what
+        # was read last says nothing of what is read next); without
+        # one, LRU
+        self._hbm = HbmPageStore(
+            hbm_bytes, self._device,
+            evictor="LRU" if prefetch_service is None else
+            _next_use_evictor(prefetch_service, self._block_of)) \
             if hbm_bytes > 0 else None
         if prefetch is None:
             # double-buffer depth for the zero-copy iterator
@@ -287,8 +311,10 @@ class DeviceBlockLoader:
             info = resolved[str(path)]
             self._infos[path] = info
             self.block_ids_by_path[path] = list(info.block_ids)
-            for i in range(len(info.block_ids)):
-                self._plan.append((path, i, PageId(f"{info.file_id:x}", i)))
+            for i, block_id in enumerate(info.block_ids):
+                pid = PageId(f"{info.file_id:x}", i)
+                self._plan.append((path, i, pid))
+                self._block_of[pid] = block_id
         # streams are per-thread: FileInStream holds per-block state, so
         # concurrent host_block callers (mesh load thread pool) must not
         # share one (close()-races would silently yield empty views)

@@ -14,7 +14,7 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Iterator, List, Sequence, Tuple
+from typing import Dict, Iterator, List, Sequence, Tuple
 
 import numpy as np
 
@@ -24,6 +24,10 @@ from alluxio_tpu.utils.tracing import tracer
 #: epoch sequences kept hot: the live epoch plus a lookahead window
 #: several epochs deep (planner) plus the previous epoch (stragglers)
 _EPOCH_CACHE_SIZE = 12
+
+#: ``next_use`` of a block this host does not read within the epochs
+#: the oracle keeps: later than every access it can name
+NEVER = 1 << 62
 
 
 @dataclass(frozen=True)
@@ -118,25 +122,56 @@ class AccessOracle:
         #: eviction rule would thrash (regenerate O(n) permutations
         #: every tick, inside the scheduler's lock)
         self._cache: "OrderedDict[int, List[BlockRef]]" = OrderedDict()
+        #: epoch -> {block id: position}, the inverse of a kept
+        #: sequence; built when first asked for (never at job start)
+        #: and dropped with its sequence
+        self._positions: Dict[int, Dict[int, int]] = {}
 
     # -- sequences ----------------------------------------------------------
     def epoch_sequence(self, epoch: int) -> List[BlockRef]:
         """This host's exact access order for ``epoch`` (stable across
         calls and processes)."""
         with self._lock:
-            seq = self._cache.get(epoch)
-            if seq is not None:
-                self._cache.move_to_end(epoch)
-                return seq
-            rng = np.random.default_rng(
-                np.random.SeedSequence([self.seed, int(epoch)]))
-            perm = rng.permutation(len(self.manifest.blocks))
-            seq = [self.manifest.blocks[i]
-                   for i in perm[self.host_index::self.num_hosts]]
-            self._cache[epoch] = seq
-            while len(self._cache) > _EPOCH_CACHE_SIZE:
-                self._cache.popitem(last=False)
+            return self._sequence(epoch)
+
+    def _sequence(self, epoch: int) -> List[BlockRef]:
+        """``epoch_sequence`` under the caller's hold of the lock."""
+        seq = self._cache.get(epoch)
+        if seq is not None:
+            self._cache.move_to_end(epoch)
             return seq
+        rng = np.random.default_rng(
+            np.random.SeedSequence([self.seed, int(epoch)]))
+        perm = rng.permutation(len(self.manifest.blocks))
+        seq = [self.manifest.blocks[i]
+               for i in perm[self.host_index::self.num_hosts]]
+        self._cache[epoch] = seq
+        while len(self._cache) > _EPOCH_CACHE_SIZE:
+            self._positions.pop(self._cache.popitem(last=False)[0], None)
+        return seq
+
+    def _position_in(self, epoch: int) -> Dict[int, int]:
+        """Where this host reads each block in ``epoch``: the inverse
+        of :meth:`epoch_sequence`."""
+        with self._lock:
+            seq = self._sequence(epoch)
+            inverse = self._positions.get(epoch)
+            if inverse is None:
+                inverse = self._positions[epoch] = {
+                    ref.block_id: i for i, ref in enumerate(seq)}
+            return inverse
+
+    def next_use(self, block_id: int, epoch: int, pos: int) -> int:
+        """Global sequence number of this host's first access of
+        ``block_id`` at or after ``(epoch, pos)``: the rest of this
+        epoch, then the epochs that follow, as many as the oracle keeps
+        (a strided shard may skip a block for whole epochs); ``NEVER``
+        beyond them."""
+        for e in range(epoch, epoch + _EPOCH_CACHE_SIZE):
+            at = self._position_in(e).get(block_id)
+            if at is not None and (e > epoch or at >= pos):
+                return self.global_seq(e, at)
+        return NEVER
 
     def epoch_len(self) -> int:
         """Accesses this host makes per epoch."""

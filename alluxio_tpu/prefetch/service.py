@@ -136,6 +136,18 @@ class PrefetchService:
         return self.scheduler.on_consume(ref, resident_hint=resident_hint,
                                          generation=generation)
 
+    def next_use(self, block_id: int, served: bool = False) -> int:
+        """Global sequence number of the consumer's next access of
+        ``block_id``, against the scheduler's cursor: the first the
+        producer has not consumed yet, or, with ``served``, the first
+        after the one at the cursor (a hit is looked up BEFORE its
+        ``on_consume`` moves the cursor: the access at the cursor is
+        then the one being served, not a future one). ``oracle.NEVER``
+        past the oracle's horizon. What the loader's HBM tier evicts
+        by."""
+        epoch, pos = self.scheduler.cursor()
+        return self.oracle.next_use(block_id, epoch, pos + served)
+
     def release(self, ref: BlockRef) -> None:
         """Consume finished: drop the block's eviction pin (no-op when
         none is held)."""

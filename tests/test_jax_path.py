@@ -66,6 +66,113 @@ class TestHbmStore:
         assert bytes(np.asarray(held)[:2]) == b"kk"
 
 
+class TestHbmStoreByNextUse:
+    """The tier under a loader that knows its order: a store handed a
+    ``NextUseCacheEvictor`` (the loader does the handing; here a
+    scripted order stands for the oracle)."""
+
+    PAGE = 256
+
+    def _store(self, order, pages: int):
+        from alluxio_tpu.client.cache.evictor import NextUseCacheEvictor
+
+        state = {"cursor": 0}
+
+        def next_use(page_id, served):
+            start = state["cursor"] + served
+            return next((t for t in range(start, len(order))
+                         if order[t] == page_id.page_index), 1 << 40)
+
+        store = HbmPageStore(capacity_bytes=pages * self.PAGE,
+                             evictor=NextUseCacheEvictor(next_use))
+        return store, state
+
+    def _put(self, store, i):
+        return store.put(PageId("f", i), bytes([i]) * self.PAGE)
+
+    def test_the_page_read_farthest_ahead_goes_and_has_touches_nothing(
+            self):
+        store, _state = self._store([3, 0, 1, 2], pages=3)
+        for i in (0, 1, 2):  # 2 is the newest, and read last
+            assert self._put(store, i)
+        assert store.has(PageId("f", 0))  # no side effect to need
+        assert self._put(store, 3)
+        assert [store.has(PageId("f", i)) for i in range(4)] == \
+            [True, True, False, True]
+
+    def test_a_pinned_page_stays_whatever_its_key(self):
+        store, _state = self._store([0, 1], pages=2)
+        assert self._put(store, 0) and self._put(store, 7)  # 7: never
+        with store.get(PageId("f", 7)):
+            assert self._put(store, 1)  # 0 goes: 7 is pinned
+            assert store.has(PageId("f", 7))
+            assert not store.has(PageId("f", 0))
+        assert self._put(store, 0)  # unpinned: now 7 goes first
+        assert not store.has(PageId("f", 7))
+        assert store.used_bytes == 2 * self.PAGE
+
+    def test_three_threads_on_one_tier_keep_it_whole(self):
+        """The producer's look-ups, the consumer's adopts and the
+        agent's placements meet in the store: capacity holds, the
+        evictor's list stays one sorted entry a page."""
+        import random
+        import sys
+        import threading
+        import time
+
+        n, pages = 24, 8
+        rng = np.random.default_rng(36)
+        order = [int(x) for _ in range(40) for x in rng.permutation(n)]
+        store, state = self._store(order, pages=pages)
+        cap = pages * self.PAGE
+        stop = time.monotonic() + 3.0
+        errors = []
+
+        def guard(fn):
+            def run():
+                try:
+                    while time.monotonic() < stop and not errors:
+                        fn()
+                except BaseException as e:  # noqa: BLE001 - asserted
+                    errors.append(e)
+            return run
+
+        def producer():  # look up, then move the cursor
+            at = state["cursor"]
+            lease = store.get(PageId("f", order[at]))
+            if lease is not None:
+                lease.close()
+            state["cursor"] = (at + 1) % (len(order) - n)
+
+        def adopter():  # what was read a little while ago, or ahead
+            at = state["cursor"] + random.randint(-3, 5)
+            self._put(store, order[max(0, at)])
+            assert store.used_bytes <= cap
+
+        def checker():
+            ev = store._evictor
+            with store._lock:
+                assert ev._by_use == sorted(ev._by_use)
+                assert {e[2] for e in ev._by_use} == set(ev._entry) \
+                    == set(store._pages)
+                assert len(ev._by_use) == len(ev._entry)
+
+        threads = [threading.Thread(target=guard(fn))
+                   for fn in (producer, adopter, adopter, checker)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=30)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(interval)
+        assert errors == []
+        assert store.page_count == pages  # full, and never over
+
+
 class TestDecode:
     def test_image_record_round_trip(self):
         rng = np.random.default_rng(0)

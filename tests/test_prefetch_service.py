@@ -59,6 +59,59 @@ class TestOracle:
             [r.block_id for r in o.epoch_sequence(1)[:3]]
 
 
+    @pytest.mark.parametrize("num_hosts,host_index",
+                             [(1, 0), (2, 0), (2, 1)])
+    def test_next_use_is_a_search_of_the_sequences(self, num_hosts,
+                                                   host_index):
+        """At or after (epoch, pos): the rest of this epoch, then the
+        next ones, a strided shard's skipped epochs included; the
+        inverse is built when asked for, not with the oracle."""
+        from alluxio_tpu.prefetch import oracle as oracle_mod
+
+        m = make_manifest(11)
+        o = AccessOracle(m, seed=5, num_hosts=num_hosts,
+                         host_index=host_index)
+        assert o._positions == {}
+        per_epoch = o.epoch_len()
+        horizon = oracle_mod._EPOCH_CACHE_SIZE
+
+        def search(block_id, epoch, pos):
+            for e in range(epoch, epoch + horizon):
+                ids = [r.block_id for r in o.epoch_sequence(e)]
+                for at in range(pos if e == epoch else 0, len(ids)):
+                    if ids[at] == block_id:
+                        return e * per_epoch + at
+            return oracle_mod.NEVER
+
+        skipped = 0
+        for epoch in (0, 3):
+            for pos in (0, 1, per_epoch // 2, per_epoch - 1, per_epoch):
+                for b in m.blocks:
+                    want = search(b.block_id, epoch, pos)
+                    assert o.next_use(b.block_id, epoch, pos) == want
+                    skipped += want >= (epoch + 2) * per_epoch
+        # one host reads every block every epoch; a shard of two skips
+        assert (skipped > 0) == (num_hosts > 1)
+        assert o.next_use(999, 0, 0) == oracle_mod.NEVER  # no such block
+        assert set(o._positions) <= set(o._cache)
+
+    def test_the_service_answers_against_the_cursor(self):
+        """``served`` steps over the access at the cursor (a hit is
+        looked up before its consume moves the cursor)."""
+        o = AccessOracle(make_manifest(6), seed=9)
+        s = PrefetchScheduler(o, lookahead_blocks=6, budget_bytes=0)
+        svc = PrefetchService(o, s, agent=None)
+        seq = [r.block_id for r in o.epoch_sequence(0)]
+        nxt = [r.block_id for r in o.epoch_sequence(1)]
+        for _ in range(2):
+            s.on_consume(o.epoch_sequence(0)[s.cursor()[1]])
+        at = seq[2]  # the block at the cursor
+        assert svc.next_use(at) == 2
+        assert svc.next_use(at, served=True) == 6 + nxt.index(at)
+        assert svc.next_use(seq[0]) == 6 + nxt.index(seq[0])  # consumed
+        assert svc.next_use(seq[5]) == svc.next_use(seq[5], True) == 5
+
+
 class TestScheduler:
     def _sched(self, n=10, length=10, **kw):
         o = AccessOracle(make_manifest(n, length), seed=7)

@@ -15,6 +15,9 @@ from collections import OrderedDict
 import numpy as np
 import pytest
 
+from alluxio_tpu.client.cache.evictor import (
+    LRUCacheEvictor, NextUseCacheEvictor,
+)
 from alluxio_tpu.client.jax_io import DeviceBlockLoader
 from alluxio_tpu.conf import Keys
 from alluxio_tpu.metrics import metrics
@@ -150,30 +153,107 @@ def test_every_epoch_is_the_reference_permutation_byte_for_byte(
         svc.close()
 
 
-def test_the_order_alone_hits_what_an_lru_of_the_reference_order_hits(
-        cluster, dataset):
-    """Budget 0: the service gives the order and places nothing, so the
-    HBM hits are LRU's on the reference order, to within the items that
-    lie between the producer's look-up and the consumer's adopt."""
+def next_use_hits(orders, capacity: int, epochs: int):
+    """Hits an epoch of a plain next-use tier of ``capacity`` entries on
+    ``orders`` (one array an epoch, one more than ``epochs`` so that the
+    last epoch's pages have a next use): a miss is taken in, and where
+    the tier is full the held entry used farthest ahead goes first."""
+    seq = [int(x) for order in orders for x in order]
+    n = len(orders[0])
+
+    def next_use(x, t):
+        return next((u for u in range(t + 1, len(seq)) if seq[u] == x),
+                    len(seq))
+
+    held, hits = set(), [0] * epochs
+    for t in range(epochs * n):
+        if seq[t] in held:
+            hits[t // n] += 1
+            continue
+        if len(held) == capacity:
+            held.remove(max(held, key=lambda y: next_use(y, t)))
+        held.add(seq[t])
+    return hits
+
+
+@pytest.mark.parametrize("with_service", [True, False],
+                         ids=["oracle-order", "file-order"])
+def test_the_tier_evicts_by_next_use_only_where_the_order_is_known(
+        cluster, dataset, with_service):
+    """Budget 0: the service gives the order and places nothing. A
+    loader bound to it knows what is read next, and its HBM tier hits
+    what a plain next-use tier hits on the reference order (to within
+    the items that lie between the producer's look-up and the
+    consumer's adopt), which is more than LRU can. A loader with no
+    service reads in file order and keeps LRU: a cyclic scan of 12
+    through a tier of 8 hits nothing but what the queue's depth lets
+    through, where next use would hit 7 an epoch."""
     fs, paths, _ref = dataset
-    svc, loader = _job(cluster, fs, paths, budget_blocks=0)
+    if with_service:
+        svc, loader = _job(cluster, fs, paths, budget_blocks=0)
+        orders = [reference_order(SEED, e, N_FILES)
+                  for e in range(EPOCHS + 1)]
+    else:
+        svc = None
+        loader = DeviceBlockLoader(fs, paths,
+                                   hbm_bytes=TIER_BLOCKS * BLOCK)
+        orders = [np.arange(N_FILES)] * (EPOCHS + 1)
     lru = Lru(TIER_BLOCKS)
+    lru_hits = [lru.hits(order) for order in orders[:EPOCHS]]
+    ahead_hits = next_use_hits(orders, TIER_BLOCKS, EPOCHS)
+    assert sum(ahead_hits) > sum(lru_hits)  # the order is worth knowing
+    want = ahead_hits if with_service else lru_hits
     total = 0
     adopted = _count("Client.PrefetchHbmAdopted")
+    rejected = _count("Client.JaxHbmAdoptRejected")
+    cap = TIER_BLOCKS * BLOCK
     try:
         for epoch in range(EPOCHS):
             before = _count("Client.JaxHbmHits")
-            assert len(list(loader.epoch())) == N_FILES
+            got = 0
+            for _block in loader.epoch():
+                assert loader.hbm_stats()["hbm_bytes"] <= cap
+                got += 1
+            assert got == N_FILES
             hits = _count("Client.JaxHbmHits") - before
-            want = lru.hits(reference_order(SEED, epoch, N_FILES))
-            assert abs(hits - want) <= QUEUE_DEPTH, (epoch, hits, want)
+            assert abs(hits - want[epoch]) <= QUEUE_DEPTH, \
+                (epoch, hits, want)
             total += hits
-        assert 0 < total < EPOCHS * N_FILES
-        assert svc.stats()["hits"] == total  # the consumer's view agrees
+        if with_service:
+            assert sum(lru_hits) < total < EPOCHS * N_FILES
+            assert svc.stats()["hits"] == total  # the consumer's view
+        # ONE place chooses, from whether a service is bound
+        assert type(loader._hbm._evictor) is (
+            NextUseCacheEvictor if with_service else LRUCacheEvictor)
         assert _count("Client.PrefetchHbmAdopted") == adopted
+        assert _count("Client.JaxHbmAdoptRejected") == rejected
     finally:
         loader.close()
+        if svc is not None:
+            svc.close()
+
+
+def test_a_closed_loader_goes_with_its_last_reference(cluster, dataset):
+    """The tier's evictor asks the service, not the loader: a job that
+    closes its loader frees it (plan, statuses, tier) at once, with no
+    pass of the cycle collector, which would land in the next job's
+    start."""
+    import gc
+    import weakref
+
+    fs, paths, _ref = dataset
+    svc, loader = _job(cluster, fs, paths, budget_blocks=0)
+    assert len(list(loader.epoch())) == N_FILES
+    gone = weakref.ref(loader)
+    gc.collect()
+    gc.disable()
+    try:
+        loader.close()
         svc.close()
+        del loader
+        assert gone() is None
+    finally:
+        gc.enable()
 
 
 def test_a_shuffled_job_start_makes_no_call_a_file(cluster, dataset):

@@ -28,6 +28,7 @@ from alluxio_tpu.rpc.clients import BlockMasterClient, WorkerClient
 from alluxio_tpu.utils import ids as id_utils
 from alluxio_tpu.utils.exceptions import UnavailableError
 from alluxio_tpu.utils.retry import ExponentialTimeBoundedRetry
+from alluxio_tpu.utils.tracing import tracer
 from alluxio_tpu.utils.wire import (
     BlockInfo, FileBlockInfo, FileInfo, TieredIdentity, WorkerInfo,
     WorkerNetAddress,
@@ -164,14 +165,18 @@ class BlockStoreClient:
 
         info = fbi.block_info
         exclude = exclude or set()
-        local_hostname = socket.gethostname()
         # 1) same-host cached copy, whatever tier holds it: one lease
         # RPC and one mmap, then every read is a memoryview slice
         if self.shm is not None:
-            for loc in info.locations:
-                if loc.address.key() in exclude or \
-                        not is_local_worker(loc.address, local_hostname):
-                    continue
+            # which replicas are on this host: the host's name and a
+            # stat of each candidate's shm dir, system calls that each
+            # let go of the GIL (and wait to have it back)
+            with tracer().span("atpu.block.same_host"):
+                local_hostname = socket.gethostname()
+                local = [loc for loc in info.locations
+                         if loc.address.key() not in exclude
+                         and is_local_worker(loc.address, local_hostname)]
+            for loc in local:
                 try:
                     stream = self.shm.open_stream(
                         self.worker_client(loc.address), info.block_id)

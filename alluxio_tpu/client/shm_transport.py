@@ -38,6 +38,7 @@ import numpy as np
 
 from alluxio_tpu.client.block_streams import BlockInStream, _record_read
 from alluxio_tpu.rpc.clients import WorkerClient
+from alluxio_tpu.utils.tracing import tracer
 
 
 class ShmSegment:
@@ -96,8 +97,13 @@ class ShmSegment:
                 mm.close()
             except BufferError:
                 # a numpy view is still live (in-flight device_put);
-                # leave the mapping to GC — pages stay valid on Linux
-                pass
+                # leave the mapping to GC — pages stay valid on Linux.
+                # That munmap runs when the last view dies, under no
+                # span: counted, so a reader of atpu.shm.unmap knows
+                # how many it did not see
+                from alluxio_tpu.metrics import metrics
+
+                metrics().counter("Client.ShmUnmapDeferred").inc()
 
 
 class ShmTransport:
@@ -143,20 +149,14 @@ class ShmTransport:
     def _map(self, worker: WorkerClient, block_id: int) -> ShmSegment:
         from alluxio_tpu.metrics import metrics
         from alluxio_tpu.utils import faults
-        from alluxio_tpu.utils.tracing import current_span, tracer
 
-        # the enclosing span (ring on) takes each step as a typed phase;
-        # the step's own span reaches a profiler capture, ring on or off
-        outer = current_span()
         span = tracer().span
         # lease grant: the worker pins the block against eviction before
         # we touch the file — typed denials propagate to the router
-        with span("atpu.shm.lease") as sp:
+        with span("atpu.shm.lease"):
             lease = worker.shm_open(self._session, block_id)
-        if outer is not None and sp is not None:
-            outer.phase("lease_wait", sp.duration_ms)
         try:
-            with span("atpu.shm.map") as sp:
+            with span("atpu.shm.map"):
                 if faults.armed() and \
                         faults.injector().take_shm_map_error(self._host):
                     raise OSError(
@@ -173,13 +173,8 @@ class ShmTransport:
             metrics().counter("Client.ShmMapFailures").inc()
             # we hold a lease we cannot use; give it back now rather
             # than waiting out the TTL
-            try:
-                worker.shm_release(self._session, lease["lease_id"])
-            except Exception:  # noqa: BLE001 - TTL reclaims it anyway
-                pass
+            self._give_back(worker, lease["lease_id"])
             raise
-        if outer is not None and sp is not None:
-            outer.phase("shm_map", sp.duration_ms)
         seg = ShmSegment(block_id, lease["path"], lease["length"],
                          lease["lease_id"], lease["ttl_s"],
                          self._renew_fraction, mm)
@@ -199,7 +194,13 @@ class ShmTransport:
             while len(self._segments) > self._cache_max:
                 victims.append(self._segments.popitem(last=False)[1])
         for v in victims:
-            self._release(worker, v)
+            # on the opener's thread, before it gets its segment: a scan
+            # pays one of these a miss once the cache is full. A victim
+            # of this very block is our own segment, where another
+            # thread's open won; any other the cache's bound pushed out
+            with span("atpu.shm.evict", reason="lost_race"
+                      if v.block_id == block_id else "lru"):
+                self._release(worker, v)
         return seg
 
     # ------------------------------------------------------------- leases
@@ -223,11 +224,21 @@ class ShmTransport:
             seg.dead = True
 
     def _release(self, worker: WorkerClient, seg: ShmSegment) -> None:
-        seg.close_map()
-        try:
-            worker.shm_release(self._session, seg.lease_id)
-        except Exception:  # noqa: BLE001 - TTL reclaims it anyway
-            pass
+        # the munmap of the whole mapping (left to the collector where
+        # a view is still live: Client.ShmUnmapDeferred)
+        with tracer().span("atpu.shm.unmap", bytes=seg.length):
+            seg.close_map()
+        self._give_back(worker, seg.lease_id)
+
+    def _give_back(self, worker: WorkerClient, lease_id: int) -> None:
+        """The lease back to the worker, as the client sees the RPC:
+        the twin of ``atpu.shm.lease`` (the worker's own part is the
+        timer ``Worker.RpcServeTime.shm_release``)."""
+        with tracer().span("atpu.shm.release"):
+            try:
+                worker.shm_release(self._session, lease_id)
+            except Exception:  # noqa: BLE001 - TTL reclaims it anyway
+                pass
 
     def invalidate(self, block_id: int) -> None:
         with self._lock:

@@ -20,6 +20,7 @@ from alluxio_tpu.rpc.clients import FsMasterClient
 from alluxio_tpu.utils.exceptions import (
     BlockDoesNotExistError, InvalidArgumentError, UnavailableError,
 )
+from alluxio_tpu.utils.tracing import tracer
 from alluxio_tpu.utils.wire import FileBlockInfo, FileInfo
 
 
@@ -71,8 +72,11 @@ class FileInStream:
 
     def _blocks(self) -> List[FileBlockInfo]:
         if self._block_infos is None:
-            self._block_infos = self._fs.get_file_block_info_list(
-                self.info.path)
+            # a file's first open, and again after a location went
+            # stale: one call to the master
+            with tracer().span("atpu.fs.block_infos"):
+                self._block_infos = self._fs.get_file_block_info_list(
+                    self.info.path)
         return self._block_infos
 
     def _ufs_info_for(self, index: int) -> Optional[dict]:

@@ -297,18 +297,17 @@ class PrefetchAgent(HeartbeatExecutor):
 
     def _issue(self, action: PlacementAction) -> None:
         ref = action.ref
-        with tracer().span("atpu.prefetch.place"):
-            if action.tier == TIER_HBM and self._hbm_adopt is not None:
-                if self._hbm_pool is None:
-                    from concurrent.futures import ThreadPoolExecutor
+        if action.tier == TIER_HBM and self._hbm_adopt is not None:
+            if self._hbm_pool is None:
+                from concurrent.futures import ThreadPoolExecutor
 
-                    self._hbm_pool = ThreadPoolExecutor(
-                        max_workers=1,
-                        thread_name_prefix="prefetch-hbm-adopt")
-                self._hbm_pool.submit(self._adopt, ref)
-                return
-            if not self._executor.submit(ref):
-                self._scheduler.on_load_failed(ref.block_id)
+                self._hbm_pool = ThreadPoolExecutor(
+                    max_workers=1,
+                    thread_name_prefix="prefetch-hbm-adopt")
+            self._hbm_pool.submit(self._adopt, ref)
+            return
+        if not self._executor.submit(ref):
+            self._scheduler.on_load_failed(ref.block_id)
 
     def _adopt(self, ref: BlockRef) -> None:
         """HBM placement body (adopt worker thread): blocking host read

@@ -223,7 +223,7 @@ def run_shm(*, file_mb: int = 2, ops: int = 200,
     identity_ok = False
     bytes_ok = False
     wire_ms = 0.0
-    setup_phases = {}
+    setup_spans = {}
     reads_per_s = 0.0
     try:
         with tempfile.TemporaryDirectory(prefix="atpu-shm-") as base:
@@ -261,12 +261,14 @@ def run_shm(*, file_mb: int = 2, ops: int = 200,
                     identity_ok = bool(views) and all(
                         v.obj is views[0].obj for v in views) and \
                         np.shares_memory(nv, np.asarray(views[0]))
-                    for name, ms in (sp.phases or []):
-                        if name in ("wire", "serialize"):
-                            wire_ms += ms
-                        else:
-                            setup_phases[name] = round(
-                                setup_phases.get(name, 0.0) + ms, 3)
+                    wire_ms = sum(ms for name, ms in (sp.phases or [])
+                                  if name in ("wire", "serialize"))
+                    # what the one cold open cost: the lease and the
+                    # map, each a child span on the ring
+                    setup_spans = {
+                        d["name"]: d["duration_ms"]
+                        for d in tracer().snapshot()
+                        if d["name"] in ("atpu.shm.lease", "atpu.shm.map")}
                     del nv, views
                 fs.close()
     finally:
@@ -285,7 +287,7 @@ def run_shm(*, file_mb: int = 2, ops: int = 200,
                  "buffer_identity_ok": identity_ok,
                  "bytes_ok": bytes_ok,
                  "wire_serialize_ms": round(wire_ms, 3),
-                 "setup_phases": setup_phases,
+                 "setup_spans_ms": setup_spans,
                  "reads_per_s": round(reads_per_s, 1),
                  "zerocopy_ok": ok},
         errors=0 if ok else 1,

@@ -80,8 +80,6 @@ PHASES = (
     "tier_read",    # reading bytes out of a local tier
     "device_put",   # host->device transfer (shm staging / jax device_put)
     "drain",        # consumer draining/assembling delivered chunks
-    "shm_map",      # mmap-ing a leased same-host SHM segment
-    "lease_wait",   # client-observed shm_open/shm_renew lease RPC wait
     "batch_read",   # server-side scatter/gather assembly of a read_many
     "native_exec",  # GIL-free native execution of a packed read plan
     "table_plan",   # parquet footer fetch/parse + projection range planning

@@ -18,6 +18,7 @@ import pytest
 from alluxio_tpu.client.cache.evictor import (
     LRUCacheEvictor, NextUseCacheEvictor,
 )
+from alluxio_tpu.client.file_system import FileSystem
 from alluxio_tpu.client.jax_io import DeviceBlockLoader
 from alluxio_tpu.conf import Keys
 from alluxio_tpu.metrics import metrics
@@ -113,9 +114,33 @@ def _count(name: str) -> float:
     return metrics().snapshot().get(name, 0)
 
 
-def _served(method: str) -> float:
-    return sum(_count(f"Master.RpcServed.{route}.{method}")
+def _served(method: str, role: str = "Master") -> float:
+    return sum(_count(f"{role}.RpcServed.{route}.{method}")
                for route in ("fastpath", "grpc"))
+
+
+def _read_every_epoch(svc, loader, ref):
+    """Every block of every epoch byte for byte in the reference order,
+    every step classified once, the store within capacity, nothing
+    offered to the tier turned away."""
+    base = svc.stats()
+    rejected = _count("Client.JaxHbmAdoptRejected")
+    cap = TIER_BLOCKS * BLOCK
+    for epoch in range(EPOCHS):
+        want = reference_order(SEED, epoch, N_FILES)
+        assert sorted(want) == list(range(N_FILES))  # each once
+        got = 0
+        for i, block in zip(want, loader.epoch()):
+            assert np.array_equal(np.asarray(block), ref.file(i)), \
+                f"epoch {epoch} position {got}: not file {i}"
+            assert loader.hbm_stats()["hbm_bytes"] <= cap
+            got += 1
+        assert got == N_FILES
+    stats = svc.stats()
+    consumed = sum(stats[k] - base[k] for k in ("hits", "late", "misses"))
+    assert consumed == EPOCHS * N_FILES
+    assert loader.hbm_stats()["hbm_bytes"] <= cap
+    assert _count("Client.JaxHbmAdoptRejected") == rejected
 
 
 # budget 0 is the order alone; with a budget the agent's adopt thread
@@ -128,29 +153,52 @@ def test_every_epoch_is_the_reference_permutation_byte_for_byte(
     fs, paths, ref = dataset
     svc, loader = _job(cluster, fs, paths, budget_blocks=budget_blocks,
                        heartbeat=heartbeat)
-    base = svc.stats()
-    rejected = _count("Client.JaxHbmAdoptRejected")
-    cap = TIER_BLOCKS * BLOCK
     try:
-        for epoch in range(EPOCHS):
-            want = reference_order(SEED, epoch, N_FILES)
-            assert sorted(want) == list(range(N_FILES))  # each once
-            got = 0
-            for i, block in zip(want, loader.epoch()):
-                assert np.array_equal(np.asarray(block), ref.file(i)), \
-                    f"epoch {epoch} position {got}: not file {i}"
-                assert loader.hbm_stats()["hbm_bytes"] <= cap
-                got += 1
-            assert got == N_FILES
-        stats = svc.stats()
-        consumed = sum(stats[k] - base[k]
-                       for k in ("hits", "late", "misses"))
-        assert consumed == EPOCHS * N_FILES
-        assert loader.hbm_stats()["hbm_bytes"] <= cap
-        assert _count("Client.JaxHbmAdoptRejected") == rejected
+        _read_every_epoch(svc, loader, ref)
     finally:
         loader.close()
         svc.close()
+
+
+@pytest.mark.parametrize("budget_blocks", [2, 4])
+def test_two_threads_turn_one_segment_cache_over_on_every_open(
+        cluster, dataset, budget_blocks):
+    """The same job through a segment cache of 2 (the cell reads 384
+    blocks through 64): the producer and the agent's adopt thread turn
+    ONE cache over on every open, so ``ShmTransport._map``'s victim
+    loop, its unmap and its lease given back, run on each thread beside
+    the other's opens and views."""
+    _fs, paths, ref = dataset
+    conf = cluster.conf.copy()
+    conf.set(Keys.USER_SHM_SEGMENT_CACHE_MAX, 2)
+    client = FileSystem(cluster.master.address, conf=conf)
+    svc, loader = _job(cluster, client, paths,
+                       budget_blocks=budget_blocks, heartbeat="2ms")
+    opened = _count("Client.JaxShortCircuitBlocks")
+    leased = _served("shm_open", "Worker")
+    released = _served("shm_release", "Worker")
+    try:
+        _read_every_epoch(svc, loader, ref)
+        svc.close()  # the adopt thread is done before the counts are read
+        opened = _count("Client.JaxShortCircuitBlocks") - opened
+        leased = _served("shm_open", "Worker") - leased
+        released = _served("shm_release", "Worker") - released
+        # epoch 0 misses every block, a later one what the tier cannot hold
+        assert opened >= N_FILES + (EPOCHS - 1) * (N_FILES - TIER_BLOCKS)
+        held = client.store.shm.cached_blocks()
+        assert held <= 2
+        # no lease is orphaned and the cache DID turn over: every lease
+        # granted went back to the worker but those of the segments
+        # still held. (An open that took no lease found the segment the
+        # OTHER thread had just mapped, both having missed the block:
+        # ``opened`` counts views, and runs ahead of ``leased``.)
+        assert leased - released == held, (opened, leased, released)
+        assert leased >= N_FILES  # epoch 0 alone leases every block
+        assert released >= opened // 2, (opened, leased, released)
+    finally:
+        loader.close()
+        svc.close()
+        client.close()
 
 
 def next_use_hits(orders, capacity: int, epochs: int):

@@ -180,12 +180,16 @@ class TestLoaderSpans:
         assert parents("atpu.shm.map") == ["atpu.loader.open_block"] * 4
         opened = [s for s in spans if s["name"] == "atpu.loader.open_block"]
         assert all(s["tags"]["bucket"] == "shm" for s in opened)
-        # one clock reading serves span and phase
+        # the lease and the map are child spans and nothing else: the
+        # open takes no phase from them (the child carries the interval
+        # on both sinks), and each lies inside the open it belongs to
         for s in opened:
-            lease = next(c for c in spans if c["name"] == "atpu.shm.lease"
+            assert not s.get("phases")
+            for child in ("atpu.shm.lease", "atpu.shm.map"):
+                c = next(c for c in spans if c["name"] == child
                          and c["parent"] == s["span_id"])
-            assert dict(map(tuple, s["phases"]))["lease_wait"] == \
-                lease["duration_ms"]
+                assert s["start_ns"] <= c["start_ns"]
+                assert c["duration_ms"] <= s["duration_ms"]
         fault = next(s for s in spans
                      if s["name"] == "atpu.loader.prefault")
         assert fault["tags"]["bytes"] == str(BLOCK)
@@ -315,6 +319,157 @@ class TestLoaderSpans:
             assert _count("Client.JaxHbmAdopts") - ad0 == 6
         finally:
             loader.close()
+
+
+SEGMENTS = 2
+
+
+@pytest.fixture()
+def small_cache_cluster(tmp_path):
+    # a segment cache of 2, so a scan of a few blocks turns it over as
+    # the full-size scan turns over its 64
+    from alluxio_tpu.conf import Keys
+
+    with LocalCluster(str(tmp_path), num_workers=1, block_size=BLOCK,
+                      conf_overrides={
+                          Keys.USER_SHM_SEGMENT_CACHE_MAX: SEGMENTS}) as c:
+        yield c
+
+
+def _scan_loader(cluster, n_files, blocks_each):
+    from alluxio_tpu.client.jax_io import DeviceBlockLoader
+
+    fs = cluster.file_system()
+    paths = [f"/spans/scan-{i}.bin" for i in range(n_files)]
+    for i, path in enumerate(paths):
+        fs.write_all(path, bytes([i + 1]) * (blocks_each * BLOCK))
+    return DeviceBlockLoader(fs, paths)
+
+
+class TestOpenBlockSpans:
+    """What an open does beyond the lease and the map, by name: the
+    victim mapping's eviction (its unmap, its lease given back) on the
+    opener's thread, and a file's block list from the master."""
+
+    FILES, BLOCKS_EACH = 3, 3
+
+    def _scan(self, cluster, ring):
+        loader = _scan_loader(cluster, self.FILES, self.BLOCKS_EACH)
+        try:
+            ring.clear()
+            n = len(list(loader.epoch()))
+        finally:
+            loader.close()
+        assert n == self.FILES * self.BLOCKS_EACH
+        return n, ring.snapshot(limit=4000)
+
+    def test_a_scan_past_the_segment_cache_evicts_one_mapping_a_miss(
+            self, small_cache_cluster, ring):
+        n, spans = self._scan(small_cache_cluster, ring)
+        by_id = {s["span_id"]: s for s in spans}
+        evicts = [s for s in spans if s["name"] == "atpu.shm.evict"]
+        # none while the cache fills, then one a miss
+        assert len(evicts) == n - SEGMENTS
+        opens = set()
+        for ev in evicts:
+            assert ev["tags"] == {"reason": "lru"}
+            assert by_id[ev["parent"]]["name"] == "atpu.loader.open_block"
+            opens.add(ev["parent"])
+            kids = sorted((c for c in spans if c["parent"] == ev["span_id"]),
+                          key=lambda c: c["start_ns"])
+            assert [c["name"] for c in kids] == [
+                "atpu.shm.unmap", "atpu.shm.release"]
+            assert kids[0]["tags"] == {"bytes": str(BLOCK)}
+            assert kids[0]["duration_ms"] + kids[1]["duration_ms"] <= \
+                ev["duration_ms"] + 0.002  # each rounded to a us
+        assert len(opens) == len(evicts)  # one an open, never two
+        # the victim goes after the new block's lease and map: the
+        # order the statements have
+        for ev in evicts:
+            before = [c["name"] for c in spans
+                      if c["parent"] == ev["parent"]
+                      and c["start_ns"] < ev["start_ns"]]
+            assert "atpu.shm.lease" in before and "atpu.shm.map" in before
+
+    def test_a_files_block_list_is_asked_for_once_a_file(
+            self, small_cache_cluster, ring):
+        _n, spans = self._scan(small_cache_cluster, ring)
+        by_id = {s["span_id"]: s for s in spans}
+        asked = [s for s in spans if s["name"] == "atpu.fs.block_infos"]
+        assert len(asked) == self.FILES
+        assert {by_id[s["parent"]]["name"] for s in asked} == {
+            "atpu.loader.open_block"}
+        # the file's FIRST open, before that block's lease
+        for s in asked:
+            lease = min((c for c in spans if c["name"] == "atpu.shm.lease"
+                         and c["parent"] == s["parent"]),
+                        key=lambda c: c["start_ns"])
+            assert s["start_ns"] <= lease["start_ns"]
+
+    def test_the_eviction_spans_reach_a_capture_with_the_ring_off(
+            self, small_cache_cluster, tmp_path):
+        loader = _scan_loader(small_cache_cluster, self.FILES,
+                              self.BLOCKS_EACH)
+        try:
+            events = _capture(tmp_path / "cap",
+                              lambda: list(loader.epoch()))
+        finally:
+            loader.close()
+        n = self.FILES * self.BLOCKS_EACH
+        for name in ("atpu.shm.evict", "atpu.shm.unmap",
+                     "atpu.shm.release"):
+            assert len(events[name]) == n - SEGMENTS, name
+        assert len(events["atpu.fs.block_infos"]) == self.FILES
+        assert all(st["reason"] == "lru"
+                   for _s, _d, st in events["atpu.shm.evict"])
+        assert all(st["bytes"] == BLOCK
+                   for _s, _d, st in events["atpu.shm.unmap"])
+        # every eviction inside one open, its halves inside it in order
+        opens = sorted(events["atpu.loader.open_block"])
+        for (es, ed, _), (us, ud, _), (rs, rd, _) in zip(
+                sorted(events["atpu.shm.evict"]),
+                sorted(events["atpu.shm.unmap"]),
+                sorted(events["atpu.shm.release"])):
+            assert any(s <= es and es + ed <= s + d for s, d, _ in opens)
+            assert es <= us and us + ud <= rs and rs + rd <= es + ed
+
+    def test_an_unmap_under_a_live_view_is_counted_and_left_to_the_collector(
+            self, cluster):
+        fs = cluster.file_system()
+        fs.write_all("/spans/held.bin", b"\x07" * BLOCK)
+        try:
+            with fs.open_file("/spans/held.bin") as f:
+                stream = f.block_stream(0)
+                assert stream.last_source == "SHM"
+                held = stream.numpy_view()
+                seg = stream._seg
+                deferred = _count("Client.ShmUnmapDeferred")
+                seg.close_map()  # raises nothing
+                assert _count("Client.ShmUnmapDeferred") - deferred == 1
+                assert seg.released and seg.dead
+                # the pages outlive the segment for whoever holds them
+                assert int(held[0]) == 7 and int(held[-1]) == 7
+                del held
+                # with no view alive the mapping closes at once: no count
+                with fs.open_file("/spans/held.bin") as g:
+                    again = g.block_stream(0)._seg
+                    again.close_map()
+                assert _count("Client.ShmUnmapDeferred") - deferred == 1
+        finally:
+            fs.close()
+
+    def test_the_lease_and_the_map_are_spans_and_no_phase(self):
+        from alluxio_tpu.stress.smallread_bench import run_shm
+        from alluxio_tpu.utils.tracing import PHASES
+
+        assert "lease_wait" not in PHASES and "shm_map" not in PHASES
+        # the one reader the two phases had reads the child spans now
+        out = run_shm(file_mb=1, ops=8).metrics
+        assert out["zerocopy_ok"] and out["wire_serialize_ms"] == 0
+        assert sorted(out["setup_spans_ms"]) == [
+            "atpu.shm.lease", "atpu.shm.map"]
+        assert all(ms > 0 for ms in out["setup_spans_ms"].values())
+        assert "setup_phases" not in out
 
 
 class TestRoleTimersAndPull:

@@ -15,6 +15,17 @@ hit, not an RPC. Leases renew *lazily*: a read touching a segment past
 ``atpu.user.shm.lease.renew.fraction`` of its TTL fires one
 ``shm_renew``, amortized over every read in between.
 
+A miss that finds the cache at its bound makes room BEFORE it leases:
+the LRU victim leaves the cache first and goes to the transport's own
+daemon thread (started at the first eviction), which unmaps it and
+gives its lease back, so neither stands between the opener and the
+block it came for. The ``munmap`` still holds up whatever the opener
+asks of the same address space meanwhile (a page-table fill anywhere;
+on gVisor every system call, the lease's ``send`` too: PERF.md), so
+what leaves the opener's path is the release and the waiting, not the
+``munmap``'s own time. More than ``_RELEASE_BACKLOG`` victims waiting
+and the opener releases its own in line.
+
 Failure contract (the fallback matrix in docs/small_reads.md): every
 exit from this plane is a typed error the routing layer catches —
 ``ShmLeaseDeniedError`` / ``ShmSegmentUnavailableError`` from the
@@ -29,6 +40,7 @@ from __future__ import annotations
 
 import mmap
 import os
+import queue
 import threading
 import time
 from collections import OrderedDict
@@ -67,26 +79,44 @@ class ShmSegment:
         a non-empty block must be leased again, never read from here."""
         return self.mm is None and self.length > 0
 
+    def _gone(self) -> Exception:
+        from alluxio_tpu.shm import ShmSegmentUnavailableError
+
+        return ShmSegmentUnavailableError(
+            f"SHM segment of block {self.block_id} was released; "
+            f"open the block again")
+
     def live_map(self) -> Optional[mmap.mmap]:
         """The mapping, or None for an empty block; a released segment
         raises the typed fallback error — it used to read as an EMPTY
         block, silently."""
         mm = self.mm  # once: the transport may close it under us
         if mm is None and self.length > 0:
-            from alluxio_tpu.shm import ShmSegmentUnavailableError
-
-            raise ShmSegmentUnavailableError(
-                f"SHM segment of block {self.block_id} was released; "
-                f"open the block again")
+            raise self._gone()
         return mm
 
-    def view(self, offset: int = 0, length: int = -1) -> memoryview:
+    def export(self, wrap):
+        """``wrap(mapping)`` (a ``memoryview``, an ndarray over the
+        pages), or None for an empty block. The releaser thread may
+        close the mapping between the look and the wrap: that is a
+        released segment too, not a ``ValueError``."""
         mm = self.live_map()
         if mm is None:
+            return None
+        try:
+            return wrap(mm)
+        except ValueError:
+            if mm.closed:
+                raise self._gone() from None
+            raise
+
+    def view(self, offset: int = 0, length: int = -1) -> memoryview:
+        whole = self.export(memoryview)
+        if whole is None:
             return memoryview(b"")
         end = self.length if length < 0 else min(self.length,
                                                  offset + length)
-        return memoryview(mm)[offset:max(offset, end)]
+        return whole[offset:max(offset, end)]
 
     def close_map(self) -> None:
         """Drop the mapping; the segment serves no cache hit again."""
@@ -106,6 +136,13 @@ class ShmSegment:
                 metrics().counter("Client.ShmUnmapDeferred").inc()
 
 
+#: victims handed to the transport's thread and not yet released each
+#: hold a mapping and a worker-side lease: past this many the opener
+#: releases its victim in line, so mapped memory stays within
+#: ``cache_max`` + this many segments
+_RELEASE_BACKLOG = 4
+
+
 class ShmTransport:
     """Per-process segment cache + lease manager."""
 
@@ -122,6 +159,14 @@ class ShmTransport:
         self.native_fastpath = bool(native_fastpath)
         self._lock = threading.Lock()
         self._segments: "OrderedDict[int, ShmSegment]" = OrderedDict()
+        #: evicted segments on their way to the releaser thread, which
+        #: the first eviction starts; ``_backlog`` counts those handed
+        #: off and not yet released (under ``_lock``; ``_released_cv``
+        #: tells ``drain``)
+        self._victims: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._releaser: Optional[threading.Thread] = None
+        self._backlog = 0
+        self._released_cv = threading.Condition(self._lock)
 
     # -------------------------------------------------------------- open
     def open_stream(self, worker: WorkerClient, block_id: int
@@ -151,6 +196,13 @@ class ShmTransport:
         from alluxio_tpu.utils import faults
 
         span = tracer().span
+        # room first: the victim's munmap and release run on the
+        # releaser thread, not between this thread and the block it
+        # came for. A lease or a map that then fails leaves the cache
+        # one under its bound
+        with self._lock:
+            victims = self._pop_lru(self._cache_max - 1)
+        self._evict(worker, victims)
         # lease grant: the worker pins the block against eviction before
         # we touch the file — typed denials propagate to the router
         with span("atpu.shm.lease"):
@@ -178,7 +230,7 @@ class ShmTransport:
         seg = ShmSegment(block_id, lease["path"], lease["length"],
                          lease["lease_id"], lease["ttl_s"],
                          self._renew_fraction, mm)
-        victims = []
+        lost = None
         with self._lock:
             held = self._segments.get(block_id)
             if held is not None and not held.dead:
@@ -186,22 +238,76 @@ class ShmTransport:
                 # look-up missed: one segment a block. Ours goes back
                 # now; overwriting the entry would orphan ITS lease
                 # until the TTL
-                victims.append(seg)
-                seg = held
+                lost, seg = seg, held
             else:
                 self._segments[block_id] = seg
             self._segments.move_to_end(block_id)
-            while len(self._segments) > self._cache_max:
-                victims.append(self._segments.popitem(last=False)[1])
-        for v in victims:
-            # on the opener's thread, before it gets its segment: a scan
-            # pays one of these a miss once the cache is full. A victim
-            # of this very block is our own segment, where another
-            # thread's open won; any other the cache's bound pushed out
-            with span("atpu.shm.evict", reason="lost_race"
-                      if v.block_id == block_id else "lru"):
-                self._release(worker, v)
+            # another thread filled the slot we made: trim the same way
+            victims = self._pop_lru(self._cache_max)
+        if lost is not None:
+            # rare, and its pages were never touched: in line
+            with span("atpu.shm.evict", reason="lost_race"):
+                self._release(worker, lost)
+        self._evict(worker, victims)
         return seg
+
+    # ----------------------------------------------------------- eviction
+    def _pop_lru(self, keep: int) -> list:
+        """The cache's oldest segments beyond ``keep``, out of it
+        (the caller holds ``_lock``)."""
+        victims = []
+        while len(self._segments) > keep:
+            victims.append(self._segments.popitem(last=False)[1])
+        return victims
+
+    def _evict(self, worker: WorkerClient, victims: list) -> None:
+        """What the cache's bound pushed out goes to the releaser
+        thread: the opener pays the hand-off alone (the span). Where
+        ``_RELEASE_BACKLOG`` already wait it releases its victim
+        itself, which is the back-pressure."""
+        from alluxio_tpu.metrics import metrics
+
+        for v in victims:
+            with self._lock:
+                handoff = self._backlog < _RELEASE_BACKLOG
+                if handoff:
+                    self._backlog += 1
+                    if self._releaser is None:
+                        self._releaser = threading.Thread(
+                            target=self._release_loop,
+                            name="atpu-shm-release", daemon=True)
+                        self._releaser.start()
+            if handoff:
+                with tracer().span("atpu.shm.evict", reason="lru"):
+                    self._victims.put((worker, v))
+                metrics().counter("Client.ShmEvictHandoffs").inc()
+            else:
+                with tracer().span("atpu.shm.evict", reason="inline"):
+                    self._release(worker, v)
+                metrics().counter("Client.ShmEvictInline").inc()
+
+    def _release_loop(self) -> None:
+        """The releaser thread: one victim at a time until ``close``'s
+        sentinel. It brings its own fast-path connection (one a thread,
+        ``rpc/fastpath.py``)."""
+        while True:
+            item = self._victims.get()
+            if item is None:
+                return
+            try:
+                self._release(*item)
+            except Exception:  # noqa: BLE001 - the next victim still goes
+                pass
+            finally:
+                with self._released_cv:
+                    self._backlog -= 1
+                    self._released_cv.notify_all()
+
+    def drain(self, timeout: Optional[float] = None) -> bool:
+        """Wait until every victim handed off has been released."""
+        with self._released_cv:
+            return self._released_cv.wait_for(
+                lambda: self._backlog == 0, timeout)
 
     # ------------------------------------------------------------- leases
     def maybe_renew(self, worker: WorkerClient, seg: ShmSegment) -> None:
@@ -247,11 +353,16 @@ class ShmTransport:
             seg.close_map()
 
     def close(self) -> None:
-        """Unmap everything. The leases go with the session
+        """Let the releaser thread finish what waits and end, then
+        unmap everything. The leases still out go with the session
         (``cleanup_session`` on each worker), else with their TTL."""
         with self._lock:
+            releaser, self._releaser = self._releaser, None
             segs = list(self._segments.values())
             self._segments.clear()
+        if releaser is not None:
+            self._victims.put(None)
+            releaser.join()
         for seg in segs:
             seg.close_map()
 
@@ -360,11 +471,11 @@ class ShmBlockInStream(BlockInStream):
     def numpy_view(self, dtype=np.uint8) -> np.ndarray:
         """Zero-copy ndarray over the shared pages — feed straight to
         ``jax.device_put`` (the DLPack/``np.frombuffer`` handoff)."""
-        mm = self._seg.live_map()
-        if mm is None:
+        out = self._seg.export(lambda mm: np.frombuffer(mm, dtype=dtype))
+        if out is None:
             return np.empty(0, dtype=dtype)
         from alluxio_tpu.metrics import metrics
 
         metrics().counter("Client.ShmReads").inc()
         _record_read("shm", self._seg.length)
-        return np.frombuffer(mm, dtype=dtype)
+        return out

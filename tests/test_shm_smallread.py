@@ -523,3 +523,398 @@ class TestChaosFallback:
         # deterministic pacing: rate 0.5 fails every other op
         outcomes = [inj.take_shm_lease_deny("w0") for _ in range(4)]
         assert outcomes.count(True) == 2
+
+
+# ------------------------------------- eviction off the opener's thread
+RELEASER = "atpu-shm-release"
+
+
+class _RecordingWorker:
+    """A worker's lease plane, faked: one file a block under ``base``,
+    every call remembered with the thread that made it (and, given
+    ``cached``, with what it read when a lease was asked for).
+    ``gate``, when given, holds ``shm_release`` on the RELEASER thread
+    until it is set; ``raise_first`` makes the first release raise."""
+
+    def __init__(self, base, *, gate=None, raise_first=False,
+                 cached=None):
+        self._base = base
+        self._gate = gate
+        self._raise = raise_first
+        self._cached = cached  # how many segments the cache holds now
+        self._ids = iter(range(1, 1 << 30))
+        self.events = []  # (what, block or lease id, thread name)
+        self.lease_of = {}  # lease id -> block id
+        self.out = set()  # leases granted and not given back
+
+    def data(self, block_id):
+        return bytes([block_id % 251 + 1]) * (4 * KB)
+
+    def shm_open(self, session_id, block_id):
+        path = self._base / f"b{block_id}"
+        if not path.exists():
+            path.write_bytes(self.data(block_id))
+        lease_id = next(self._ids)
+        self.lease_of[lease_id] = block_id
+        self.out.add(lease_id)
+        me = threading.current_thread().name
+        self.events.append(("shm_open", block_id, me) if self._cached is None
+                           else ("shm_open", block_id, me, self._cached()))
+        return {"lease_id": lease_id, "path": str(path),
+                "length": 4 * KB, "ttl_s": 60.0}
+
+    def shm_release(self, session_id, lease_id):
+        me = threading.current_thread().name
+        if self._gate is not None and me == RELEASER:
+            assert self._gate.wait(30.0)
+        self.events.append(("shm_release", self.lease_of[lease_id], me))
+        self.out.discard(lease_id)
+        if self._raise:
+            self._raise = False
+            raise RuntimeError("injected: release failed")
+
+    def shm_renew(self, session_id, lease_id):
+        return {"ok": True, "ttl_s": 60.0}
+
+    def released(self):
+        return [b for what, b, _t in self.events if what == "shm_release"]
+
+
+def _transport(cache_max):
+    from alluxio_tpu.client.shm_transport import ShmTransport
+
+    return ShmTransport(SESSION, cache_max=cache_max)
+
+
+def _evict_counts():
+    snap = metrics().snapshot()
+    return (snap.get("Client.ShmEvictHandoffs", 0),
+            snap.get("Client.ShmEvictInline", 0))
+
+
+class TestEvictionOffTheOpenersThread:
+    """A miss at the cache's bound makes room BEFORE it leases, and the
+    victim's unmap + release run on the transport's own thread."""
+
+    def test_room_is_made_before_the_lease_and_off_the_openers_thread(
+            self, tmp_path, monkeypatch):
+        from alluxio_tpu.client.shm_transport import ShmSegment
+
+        t = _transport(2)
+        worker = _RecordingWorker(tmp_path, cached=t.cached_blocks)
+        me = threading.current_thread().name
+        real_close, real_put = ShmSegment.close_map, t._victims.put
+
+        def close_map(seg):
+            worker.events.append(("close_map", seg.block_id,
+                                  threading.current_thread().name))
+            real_close(seg)
+
+        class Q:  # SimpleQueue takes no attribute: stand in front of it
+            get = t._victims.get
+
+            @staticmethod
+            def put(item):
+                if item is not None:
+                    worker.events.append(("handoff", item[1].block_id, me))
+                real_put(item)
+
+        monkeypatch.setattr(ShmSegment, "close_map", close_map)
+        t._victims = Q
+        handoffs, inline = _evict_counts()
+        try:
+            for bid in range(6):
+                seg = t.segment(worker, bid)
+                assert bytes(seg.view()) == worker.data(bid)
+                assert t.cached_blocks() <= 2
+                assert t.drain(10.0)  # so fewer than the bound wait
+            ev = worker.events
+            for bid in range(2, 6):
+                # the victim (the block opened two before) is out of
+                # the cache (1 left of 2) and handed off BEFORE this
+                # block's lease is asked for
+                assert ev.index(("handoff", bid - 2, me)) < \
+                    ev.index(("shm_open", bid, me, 1))
+            # neither half ever ran on the opener's thread
+            assert [e for e in ev if e[0] in ("close_map", "shm_release")
+                    and e[2] == me] == []
+            assert [e[1:] for e in ev if e[0] == "close_map"] == \
+                [(b, RELEASER) for b in range(4)]
+            assert [e[1:] for e in ev if e[0] == "shm_release"] == \
+                [(b, RELEASER) for b in range(4)]
+            now = _evict_counts()
+            assert (now[0] - handoffs, now[1] - inline) == (4, 0)
+        finally:
+            t.close()
+
+    def test_a_lease_that_fails_still_hands_its_victim_off(self, tmp_path):
+        """The victim left the cache for a lease that was then denied:
+        it is released all the same and the cache stands one under its
+        bound, which the next open fills."""
+        worker = _RecordingWorker(tmp_path)
+        t = _transport(2)
+        try:
+            t.segment(worker, 0)
+            t.segment(worker, 1)
+            real_open = worker.shm_open
+
+            def denied(session_id, block_id):
+                raise ShmLeaseDeniedError("injected: table full")
+
+            worker.shm_open = denied
+            with pytest.raises(ShmLeaseDeniedError):
+                t.segment(worker, 2)
+            assert t.drain(10.0)
+            assert worker.released() == [0] and t.cached_blocks() == 1
+            worker.shm_open = real_open
+            assert bytes(t.segment(worker, 2).view()) == worker.data(2)
+            assert t.drain(10.0)
+            assert worker.released() == [0] and t.cached_blocks() == 2
+        finally:
+            t.close()
+
+    def test_a_slow_release_pushes_the_eviction_back_in_line(
+            self, tmp_path):
+        from alluxio_tpu.client.shm_transport import _RELEASE_BACKLOG
+
+        gate = threading.Event()
+        worker = _RecordingWorker(tmp_path, gate=gate)
+        t = _transport(2)
+        me = threading.current_thread().name
+        handoffs, inline = _evict_counts()
+        segs = []
+        try:
+            for bid in range(12):
+                segs.append(t.segment(worker, bid))
+                mapped = sum(1 for s in segs if s.mm is not None)
+                assert mapped <= 2 + _RELEASE_BACKLOG, bid
+                assert t.cached_blocks() <= 2
+            # 10 evictions: the first 4 wait behind the held release,
+            # every later one the opener released itself
+            now = _evict_counts()
+            assert now[0] - handoffs == _RELEASE_BACKLOG
+            assert now[1] - inline == 10 - _RELEASE_BACKLOG
+            assert [e[1] for e in worker.events
+                    if e[0] == "shm_release" and e[2] == me] == \
+                list(range(_RELEASE_BACKLOG, 10))
+            assert not t.drain(0.05)  # still held
+            gate.set()
+            assert t.drain(10.0)
+            assert sorted(worker.released()) == list(range(10))
+            assert sum(1 for s in segs if s.mm is not None) == 2
+            # the thread keeps up again: the next eviction is a hand-off
+            t.segment(worker, 12)
+            assert t.drain(10.0)
+            assert _evict_counts()[0] - handoffs == _RELEASE_BACKLOG + 1
+            assert ("shm_release", 10, RELEASER) in worker.events
+        finally:
+            gate.set()
+            t.close()
+
+    def test_close_joins_the_thread_and_closes_every_map(self, tmp_path):
+        before = set(threading.enumerate())
+        worker = _RecordingWorker(tmp_path)
+        t = _transport(2)
+        segs = [t.segment(worker, bid) for bid in range(2)]
+        # a transport that evicts nothing starts no thread
+        assert set(threading.enumerate()) == before
+        segs += [t.segment(worker, bid) for bid in range(2, 5)]
+        started = set(threading.enumerate()) - before
+        assert [th.name for th in started] == [RELEASER]
+        assert all(th.daemon for th in started)
+        t.close()
+        assert set(threading.enumerate()) == before
+        assert not any(th.is_alive() for th in started)
+        assert all(s.mm is None and s.dead for s in segs)
+        assert t.cached_blocks() == 0
+        # what waited went back through the RPC; the two still cached
+        # are the session's to return (cleanup_session), as before
+        assert sorted(worker.released()) == [0, 1, 2]
+        assert sorted(worker.lease_of[x] for x in worker.out) == [3, 4]
+
+    def test_a_transport_that_never_evicted_closes_without_a_thread(
+            self, tmp_path):
+        before = set(threading.enumerate())
+        worker = _RecordingWorker(tmp_path)
+        t = _transport(4)
+        segs = [t.segment(worker, bid) for bid in range(4)]
+        t.segment(worker, 1)  # a hit
+        assert t.drain(0.0)  # nothing ever waited
+        t.close()
+        assert set(threading.enumerate()) == before
+        assert all(s.mm is None for s in segs)
+        assert worker.released() == []
+
+    @pytest.mark.parametrize("what", ["the_rpc", "the_unmap"])
+    def test_a_release_that_raises_does_not_end_the_thread(
+            self, tmp_path, monkeypatch, what):
+        from alluxio_tpu.client.shm_transport import ShmSegment
+
+        worker = _RecordingWorker(tmp_path, raise_first=what == "the_rpc")
+        if what == "the_unmap":
+            real_close = ShmSegment.close_map
+            failed = []
+
+            def close_map(seg):
+                real_close(seg)
+                if not failed:
+                    failed.append(seg.block_id)
+                    raise RuntimeError("injected: unmap failed")
+
+            monkeypatch.setattr(ShmSegment, "close_map", close_map)
+        t = _transport(1)
+        try:
+            t.segment(worker, 0)
+            t.segment(worker, 1)  # victim 0: its release raises
+            assert t.drain(10.0)
+            t.segment(worker, 2)  # victim 1: released all the same
+            assert t.drain(10.0)
+            assert t._releaser.is_alive()
+            # an unmap that raised never reached its RPC (the TTL's)
+            assert worker.released() == \
+                ([0, 1] if what == "the_rpc" else [1])
+        finally:
+            t.close()
+
+    def test_a_map_closed_under_a_reader_is_a_released_segment(
+            self, tmp_path):
+        """The releaser may close a mapping between a reader's look at
+        it and its view of it: the typed error the re-open loops
+        handle, not the ``ValueError`` of a closed mmap."""
+        import numpy as np
+
+        from alluxio_tpu.client.shm_transport import ShmBlockInStream
+
+        worker = _RecordingWorker(tmp_path)
+        t = _transport(2)
+        try:
+            seg = t.segment(worker, 0)
+            stream = ShmBlockInStream(t, worker, seg)
+            # any other ValueError is the caller's own
+            with pytest.raises(ValueError, match="multiple of element"):
+                stream.numpy_view(np.dtype("V3"))
+            seg.mm.close()  # the look saw it open; the view finds it shut
+            for read in (seg.view, stream.numpy_view, stream.memoryview,
+                         lambda: stream.pread(0, 16)):
+                with pytest.raises(ShmSegmentUnavailableError):
+                    read()
+        finally:
+            t.close()
+
+    @pytest.mark.parametrize("openers", [1, 2])
+    def test_no_lease_is_orphaned_after_a_drain(self, cluster, openers):
+        """PR 38's invariant, over a real worker, with the releases on
+        their own thread: leases granted - given back = segments still
+        held, every block byte for byte, one and two opener threads on
+        ONE cache of 2."""
+        conf = cluster.conf.copy()
+        conf.set(Keys.USER_SHM_SEGMENT_CACHE_MAX, 2)
+        from alluxio_tpu.client.file_system import FileSystem
+
+        client = FileSystem(cluster.master.address, conf=conf)
+        shm_store = cluster.workers[0].worker.shm_store
+        n_blocks, rounds = 6, 3
+        data = _patterned(n_blocks * BLOCK, 0x39 + openers)
+        path = f"/shm-evict-{openers}"
+        handoffs, inline = _evict_counts()
+        try:
+            client.write_all(path, data, write_type="MUST_CACHE")
+            base = shm_store.stats()["live_leases"]
+            errors = []
+
+            def scan(first):
+                try:
+                    with client.open_file(path) as f:
+                        for k in range(rounds * n_blocks):
+                            i = (first + k) % n_blocks
+                            want = data[i * BLOCK:(i + 1) * BLOCK]
+                            for _try in range(50):
+                                # the other opener may push this segment
+                                # out between the open and the view: the
+                                # loader's re-open loop, in small
+                                try:
+                                    got = f.block_stream(i) \
+                                        .numpy_view().tobytes()
+                                    break
+                                except ShmSegmentUnavailableError:
+                                    continue
+                            assert got == want, (first, k)
+                except Exception as e:  # noqa: BLE001 - shown below
+                    errors.append(repr(e))
+
+            threads = [threading.Thread(target=scan, args=(j * 3,))
+                       for j in range(openers)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(120)
+            assert not errors, errors
+            shm = client.store.shm
+            assert shm.drain(10.0)
+            held = shm.cached_blocks()
+            assert 1 <= held <= 2
+            assert shm_store.stats()["live_leases"] - base == held
+            now = _evict_counts()
+            # a slow box may push some back in line: both ways count
+            assert (now[0] - handoffs) + (now[1] - inline) >= \
+                rounds * n_blocks - 2
+        finally:
+            client.close()
+
+    def test_a_block_opened_again_while_its_old_segment_waits(
+            self, cluster, monkeypatch):
+        conf = cluster.conf.copy()
+        conf.set(Keys.USER_SHM_SEGMENT_CACHE_MAX, 2)
+        from alluxio_tpu.client.file_system import FileSystem
+
+        client = FileSystem(cluster.master.address, conf=conf)
+        shm_store = cluster.workers[0].worker.shm_store
+        data = _patterned(3 * BLOCK, 0x396)
+        gate = threading.Event()
+        try:
+            client.write_all("/shm-again", data, write_type="MUST_CACHE")
+            base = shm_store.stats()["live_leases"]
+            shm = client.store.shm
+            real_release = shm._release
+
+            def held_release(worker, seg):
+                if threading.current_thread().name == RELEASER:
+                    assert gate.wait(30.0)
+                real_release(worker, seg)
+
+            monkeypatch.setattr(shm, "_release", held_release)
+            with client.open_file("/shm-again") as f, \
+                    client.open_file("/shm-again") as g:
+                first = f.block_stream(0)
+                old = first._seg
+                assert first.numpy_view().tobytes() == data[:BLOCK]
+                for i in (1, 2):  # 2 pushes block 0's segment out
+                    assert f.block_stream(i).numpy_view().tobytes() == \
+                        data[i * BLOCK:(i + 1) * BLOCK]
+                # handed off, not yet released: mapping and lease live
+                assert not shm.drain(0.05)
+                assert old.mm is not None and not old.released
+                assert shm_store.stats()["live_leases"] - base == 3
+                # a stream that holds the old segment reads on through
+                # it (mapped and leased until the thread gets to it)
+                assert f.block_stream(0) is first
+                assert first.numpy_view().tobytes() == data[:BLOCK]
+                # a NEW open asks the cache, which no longer knows the
+                # old segment: a fresh lease and a fresh map, the right
+                # bytes
+                again = g.block_stream(0)
+                assert again._seg is not old
+                assert again._seg.lease_id != old.lease_id
+                assert again.numpy_view().tobytes() == data[:BLOCK]
+                gate.set()
+                assert shm.drain(10.0)
+                assert old.released and first.stale()
+                with pytest.raises(ShmSegmentUnavailableError):
+                    first.numpy_view()
+                assert again.numpy_view().tobytes() == data[:BLOCK]
+                held = shm.cached_blocks()
+                assert held == 2
+                assert shm_store.stats()["live_leases"] - base == held
+        finally:
+            gate.set()
+            client.close()

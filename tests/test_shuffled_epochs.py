@@ -165,9 +165,10 @@ def test_two_threads_turn_one_segment_cache_over_on_every_open(
         cluster, dataset, budget_blocks):
     """The same job through a segment cache of 2 (the cell reads 384
     blocks through 64): the producer and the agent's adopt thread turn
-    ONE cache over on every open, so ``ShmTransport._map``'s victim
-    loop, its unmap and its lease given back, run on each thread beside
-    the other's opens and views."""
+    ONE cache over on every open, so ``ShmTransport._map`` makes room
+    on each thread beside the other's opens and views, and the victims'
+    unmaps and leases given back run on the transport's own thread
+    beside both."""
     _fs, paths, ref = dataset
     conf = cluster.conf.copy()
     conf.set(Keys.USER_SHM_SEGMENT_CACHE_MAX, 2)
@@ -180,6 +181,8 @@ def test_two_threads_turn_one_segment_cache_over_on_every_open(
     try:
         _read_every_epoch(svc, loader, ref)
         svc.close()  # the adopt thread is done before the counts are read
+        # and so is the transport's own, which gives the leases back
+        assert client.store.shm.drain(10.0)
         opened = _count("Client.JaxShortCircuitBlocks") - opened
         leased = _served("shm_open", "Worker") - leased
         released = _served("shm_release", "Worker") - released

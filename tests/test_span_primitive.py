@@ -343,21 +343,24 @@ def _scan_loader(cluster, n_files, blocks_each):
     paths = [f"/spans/scan-{i}.bin" for i in range(n_files)]
     for i, path in enumerate(paths):
         fs.write_all(path, bytes([i + 1]) * (blocks_each * BLOCK))
-    return DeviceBlockLoader(fs, paths)
+    return DeviceBlockLoader(fs, paths), fs
 
 
 class TestOpenBlockSpans:
     """What an open does beyond the lease and the map, by name: the
-    victim mapping's eviction (its unmap, its lease given back) on the
-    opener's thread, and a file's block list from the master."""
+    victim mapping's eviction (a hand-off on the opener's thread; its
+    unmap and its lease given back on the transport's own), and a
+    file's block list from the master."""
 
     FILES, BLOCKS_EACH = 3, 3
 
     def _scan(self, cluster, ring):
-        loader = _scan_loader(cluster, self.FILES, self.BLOCKS_EACH)
+        loader, fs = _scan_loader(cluster, self.FILES, self.BLOCKS_EACH)
         try:
             ring.clear()
             n = len(list(loader.epoch()))
+            # the releaser thread's spans are in the ring once it is idle
+            assert fs.store.shm.drain(10.0)
         finally:
             loader.close()
         assert n == self.FILES * self.BLOCKS_EACH
@@ -375,21 +378,28 @@ class TestOpenBlockSpans:
             assert ev["tags"] == {"reason": "lru"}
             assert by_id[ev["parent"]]["name"] == "atpu.loader.open_block"
             opens.add(ev["parent"])
-            kids = sorted((c for c in spans if c["parent"] == ev["span_id"]),
-                          key=lambda c: c["start_ns"])
-            assert [c["name"] for c in kids] == [
-                "atpu.shm.unmap", "atpu.shm.release"]
-            assert kids[0]["tags"] == {"bytes": str(BLOCK)}
-            assert kids[0]["duration_ms"] + kids[1]["duration_ms"] <= \
-                ev["duration_ms"] + 0.002  # each rounded to a us
+            # the hand-off alone: the victim's halves are not under it
+            assert not [c for c in spans if c["parent"] == ev["span_id"]]
+            # room is made first: the victim goes BEFORE the new
+            # block's lease and map
+            after = [c["name"] for c in spans
+                     if c["parent"] == ev["parent"]
+                     and c["start_ns"] >= ev["start_ns"]
+                     and c["span_id"] != ev["span_id"]]
+            assert "atpu.shm.lease" in after and "atpu.shm.map" in after
         assert len(opens) == len(evicts)  # one an open, never two
-        # the victim goes after the new block's lease and map: the
-        # order the statements have
-        for ev in evicts:
-            before = [c["name"] for c in spans
-                      if c["parent"] == ev["parent"]
-                      and c["start_ns"] < ev["start_ns"]]
-            assert "atpu.shm.lease" in before and "atpu.shm.map" in before
+        # the halves run on the transport's own thread, under no open:
+        # one unmap and one release a victim, each pair in that order
+        unmaps = sorted((s for s in spans if s["name"] == "atpu.shm.unmap"),
+                        key=lambda s: s["start_ns"])
+        gives = sorted((s for s in spans if s["name"] == "atpu.shm.release"),
+                       key=lambda s: s["start_ns"])
+        assert len(unmaps) == len(gives) == len(evicts)
+        for ev, un, give in zip(sorted(evicts, key=lambda s: s["start_ns"]),
+                                unmaps, gives):
+            assert un["tags"] == {"bytes": str(BLOCK)}
+            assert un["parent"] not in by_id and give["parent"] not in by_id
+            assert ev["start_ns"] <= un["start_ns"] <= give["start_ns"]
 
     def test_a_files_block_list_is_asked_for_once_a_file(
             self, small_cache_cluster, ring):
@@ -408,11 +418,15 @@ class TestOpenBlockSpans:
 
     def test_the_eviction_spans_reach_a_capture_with_the_ring_off(
             self, small_cache_cluster, tmp_path):
-        loader = _scan_loader(small_cache_cluster, self.FILES,
-                              self.BLOCKS_EACH)
+        loader, fs = _scan_loader(small_cache_cluster, self.FILES,
+                                  self.BLOCKS_EACH)
+
+        def body():
+            list(loader.epoch())
+            assert fs.store.shm.drain(10.0)  # inside the capture
+
         try:
-            events = _capture(tmp_path / "cap",
-                              lambda: list(loader.epoch()))
+            events = _capture(tmp_path / "cap", body)
         finally:
             loader.close()
         n = self.FILES * self.BLOCKS_EACH
@@ -424,14 +438,42 @@ class TestOpenBlockSpans:
                    for _s, _d, st in events["atpu.shm.evict"])
         assert all(st["bytes"] == BLOCK
                    for _s, _d, st in events["atpu.shm.unmap"])
-        # every eviction inside one open, its halves inside it in order
+        # every eviction inside one open; its halves after the hand-off
+        # began, in order, on another thread (so not held inside it)
         opens = sorted(events["atpu.loader.open_block"])
-        for (es, ed, _), (us, ud, _), (rs, rd, _) in zip(
+        for (es, ed, _), (us, ud, _), (rs, _rd, _) in zip(
                 sorted(events["atpu.shm.evict"]),
                 sorted(events["atpu.shm.unmap"]),
                 sorted(events["atpu.shm.release"])):
             assert any(s <= es and es + ed <= s + d for s, d, _ in opens)
-            assert es <= us and us + ud <= rs and rs + rd <= es + ed
+            assert es <= us and us + ud <= rs
+
+    def test_a_cold_start_starts_no_release_thread(self, cluster):
+        """A job start opens a handful of blocks and evicts nothing: it
+        must not pay for the transport's thread, and a scan that did
+        start one leaves none behind its ``close``."""
+        import threading
+
+        from alluxio_tpu.client.jax_io import DeviceBlockLoader
+
+        def releasers():
+            return {t for t in threading.enumerate()
+                    if t.name == "atpu-shm-release" and t.is_alive()}
+
+        before = releasers()
+        fs = cluster.file_system()
+        fs.write_all("/spans/cold.bin", b"\x05" * (4 * BLOCK))
+        loader = DeviceBlockLoader(fs, ["/spans/cold.bin"])
+        try:
+            first = next(iter(loader.epoch()))
+            first.block_until_ready()
+            assert int(first[0]) == 5
+            assert releasers() == before
+            assert fs.store.shm._releaser is None
+        finally:
+            loader.close()
+            fs.close()
+        assert releasers() == before
 
     def test_an_unmap_under_a_live_view_is_counted_and_left_to_the_collector(
             self, cluster):

@@ -102,6 +102,7 @@ class TestLeasePlaneRoute:
         before = {(r, m): _served(r, m) for r in (took, other)
                   for m in ("shm_open", "shm_release")}
         assert _loader_bytes(fs, "/lease/scan.bin") == data
+        assert fs.store.shm.drain(10.0)  # the releases run on its thread
         # a lease a block, a release for each mapping past the cache's 2
         assert _served(took, "shm_open") - before[took, "shm_open"] == 6
         assert _served(took, "shm_release") - \

@@ -296,6 +296,8 @@ class DeviceBlockLoader:
         #: get_status round-trip per path, e.g. placement reporting)
         self.block_ids_by_path: dict = {}
         self._infos = {}
+        #: the paths as given: ``windows``' file indices point here
+        self._paths = list(paths)
         # the prefetch service already resolved these paths for its
         # manifest: reuse those FileInfos rather than paying a second
         # get_status round per file on the startup path
@@ -517,73 +519,189 @@ class DeviceBlockLoader:
     def epoch(self) -> Iterator:
         """Iterate all blocks as device arrays with transfer prefetch.
 
-        Two-stage pipeline: a producer thread does ALL host-side work
-        (worker RPCs, mmap setup, making the mapping's pages present)
-        ahead of the consumer, so the device_put stream never stalls on
-        per-block host latency. On an HBM miss that one thread sets the
-        pace: the consumer waits on it for 80% of a scan's window
-        (``loader.get_wait_share``; PERF.md section 5 has the split).
-        The queue is bounded, and an abandoned generator unblocks the
-        producer via a stop flag.
+        Two-stage pipeline (:meth:`_pipeline`): a producer thread does
+        ALL host-side work (worker RPCs, mmap setup, making the
+        mapping's pages present) ahead of the consumer, so the
+        device_put stream never stalls on per-block host latency. On an
+        HBM miss that one thread sets the pace: the consumer waits on it
+        for 80% of a scan's window (``loader.get_wait_share``; PERF.md
+        section 5 has the split). The queue is bounded, and an abandoned
+        generator unblocks the producer via a stop flag.
 
         Early consumer exit (break mid-epoch) retires the producer
         executor: the queue is drained, the producer's streams closed,
         and the ``loader-host-prefetch`` thread joined before control
         returns — nothing leaks waiting for ``close()``."""
+
+        def begin():
+            epoch_no = self._epoch_counter
+            self._epoch_counter += 1
+            gen = self._svc.begin_epoch(epoch_no) \
+                if self._svc is not None else None
+            return self._epoch_entries(epoch_no), gen
+
+        def produce(put, stop, entries, gen):
+            for (path, index, pid, ref) in entries:
+                if stop.is_set():
+                    return
+                arr = self._hbm_hit(pid)
+                if arr is not None:
+                    if ref is not None:
+                        out = self._svc.on_consume(
+                            ref, resident_hint=True, generation=gen)
+                        if out != "stale":
+                            self._svc.release(ref)
+                    put((pid, arr, True, "hbm", getattr(arr, "nbytes", 0)))
+                    continue
+                outcome = None
+                if ref is not None:
+                    # classify BEFORE the read (ready state decides hit
+                    # vs late); the eviction pin is released only after
+                    # the read holds its own block lock. The generation
+                    # fences a superseded producer's last consume off
+                    # the new epoch's cursor.
+                    outcome = self._svc.on_consume(ref, generation=gen)
+                    t0 = time.monotonic()
+                host, bucket = self._host_present(path, index)
+                if ref is not None:
+                    if outcome != "stale":
+                        # a stale (superseded-epoch) consume must NOT
+                        # release: the scheduler still counts the block
+                        # ready, and the pin is what keeps that true —
+                        # the new epoch's own consume releases it
+                        self._svc.release(ref)
+                    if outcome not in ("hit", "stale"):
+                        # block-ready stall: how long the consumer
+                        # waited for data clairvoyance should have had
+                        # resident already
+                        self._svc.record_stall(time.monotonic() - t0)
+                put((pid, host, False, bucket, host.nbytes))
+
+        return self._pipeline(produce, begin)
+
+    def windows(self, batches, *, window_bytes: int) -> Iterator:
+        """Sample-grain reads: one device array of ``(rows,
+        window_bytes)`` uint8 a batch of windows.
+
+        ``batches`` is the user's sampler, iterated on the producer
+        thread: each item holds ``(file_index, byte_offset)`` rows, one
+        a window (``file_index`` into the loader's paths), e.g.
+        nanoGPT's ``randint`` offsets or a Megatron sample index. For
+        each batch the producer reads every window through the block's
+        zero-copy view (the same open as :meth:`epoch`, with its
+        per-thread stream cache and its retry of a released segment)
+        and copies it into row ``r`` of one fresh host buffer; a window
+        that crosses a block boundary is two slices, and a window past
+        the end of its file (or a file index out of range) fails the
+        pass, it is never a short row. Nothing is prefaulted: a window
+        touches one or two of a block's pages, so making 32 MiB present
+        for it would cost some 16,000 times the bytes it reads. The
+        consumer's side is :meth:`epoch`'s (:meth:`_pipeline`): the
+        bounded queue, one ``device_put`` a batch, ``StepStats``, and
+        closing the generator mid-pass retires the producer.
+
+        The HBM tier is not consulted: it keeps whole blocks, and a
+        window reads about 1/16,000 of one (a loader built for windows
+        takes ``hbm_bytes=0``). Off the same-host lease plane a window
+        costs its block's whole streamed read, as ``load_block`` does."""
+        window_bytes = int(window_bytes)
+        if window_bytes <= 0:
+            raise ValueError(f"window_bytes must be positive, not "
+                             f"{window_bytes}")
+        m = self._m
+        n_batches = m.counter("Client.JaxWindowBatches")
+        n_reads = m.counter("Client.JaxWindowReads")
+        #: windows whose every block the segment cache held before the
+        #: open (a dictionary look, no lease)
+        n_mapped = m.counter("Client.JaxWindowMapped")
+        #: windows that crossed a block boundary (two slices)
+        n_split = m.counter("Client.JaxWindowSplit")
+        shm = getattr(self._fs.store, "shm", None)
+
+        def produce(put, stop, it):
+            span = tracer().span
+            for rows in it:
+                if stop.is_set():
+                    return
+                n, plan = self._window_plan(rows, window_bytes)
+                out = np.empty((n, window_bytes), np.uint8)
+                held = [True] * n
+                with span("atpu.loader.host_read", windows=n,
+                          blocks=len(plan)):
+                    for r, dst, path, index, lo, hi in plan:
+                        if held[r] and (shm is None or not shm.holds(
+                                self.block_ids_by_path[path][index])):
+                            held[r] = False
+                        with span("atpu.loader.open_block"):
+                            host = self._host_bytes(path, index)
+                        out[r, dst:dst + hi - lo] = \
+                            host.view(np.uint8)[lo:hi]
+                        # no view may outlive the copy: the next open
+                        # can evict this segment
+                        del host
+                n_batches.inc()
+                n_reads.inc(n)
+                n_mapped.inc(sum(held))
+                n_split.inc(len(plan) - n)
+                put((None, out, False,
+                     getattr(self._tls, "last_bucket", "unknown"),
+                     out.nbytes))
+
+        return self._pipeline(produce, lambda: (iter(batches),))
+
+    def _window_plan(self, rows, window_bytes: int):
+        """``(windows, [(row, dst, path, block index, lo, hi)])``: the
+        block slices of one batch of ``(file_index, byte_offset)`` rows,
+        in order; raises before any read where a window is out of its
+        file."""
+        rows = np.asarray(rows, dtype=np.int64).reshape(-1, 2)
+        plan = []
+        for r, (fi, off) in enumerate(rows.tolist()):
+            if not 0 <= fi < len(self._paths):
+                raise IndexError(f"window {r}: file index {fi} is not one "
+                                 f"of the loader's {len(self._paths)}")
+            path = self._paths[fi]
+            length = self._infos[path].length
+            if off < 0 or off + window_bytes > length:
+                raise ValueError(
+                    f"window {r}: bytes [{off}, {off + window_bytes}) of "
+                    f"{path} lie outside its {length} bytes")
+            bs = self._infos[path].block_size_bytes or length
+            dst = 0
+            while dst < window_bytes:
+                index, lo = divmod(off + dst, bs)
+                take = min(window_bytes - dst, bs - lo)
+                plan.append((r, dst, path, index, lo, lo + take))
+                dst += take
+        return len(rows), plan
+
+    def _pipeline(self, produce, begin) -> Iterator:
+        """The loader's two stages, for :meth:`epoch` and
+        :meth:`windows`: ``produce(put, stop, *begin())`` runs on the
+        ONE producer thread and hands ``(pid, data, on_device, bucket,
+        nbytes)`` items to ``put`` (the bounded queue); this generator
+        is the consumer's side, which puts a host item on the device
+        (and offers it to the HBM tier where ``pid`` names a page).
+        ``begin`` runs under the epoch lock, once this iteration is the
+        live one."""
         span = tracer().span
         q: queue.Queue = queue.Queue(maxsize=max(1, self._prefetch) + 1)
         stop = threading.Event()
         retire = threading.Event()
         SENTINEL = object()
 
-        def producer(entries, gen):
+        def put(item) -> None:
+            self._put(q, stop, item)
+
+        def producer(args):
             try:
-                for (path, index, pid, ref) in entries:
-                    if stop.is_set():
-                        return
-                    arr = self._hbm_hit(pid)
-                    if arr is not None:
-                        if ref is not None:
-                            out = self._svc.on_consume(
-                                ref, resident_hint=True, generation=gen)
-                            if out != "stale":
-                                self._svc.release(ref)
-                        self._put(q, stop, (pid, arr, True, "hbm",
-                                            getattr(arr, "nbytes", 0)))
-                        continue
-                    outcome = None
-                    if ref is not None:
-                        # classify BEFORE the read (ready state decides
-                        # hit vs late); the eviction pin is released
-                        # only after the read holds its own block lock.
-                        # The generation fences a superseded producer's
-                        # last consume off the new epoch's cursor.
-                        outcome = self._svc.on_consume(ref,
-                                                       generation=gen)
-                        t0 = time.monotonic()
-                    host, bucket = self._host_present(path, index)
-                    if ref is not None:
-                        if outcome != "stale":
-                            # a stale (superseded-epoch) consume must
-                            # NOT release: the scheduler still counts
-                            # the block ready, and the pin is what
-                            # keeps that true — the new epoch's own
-                            # consume releases it
-                            self._svc.release(ref)
-                        if outcome not in ("hit", "stale"):
-                            # block-ready stall: how long the consumer
-                            # waited for data clairvoyance should have
-                            # had resident already
-                            self._svc.record_stall(
-                                time.monotonic() - t0)
-                    self._put(q, stop, (pid, host, False, bucket,
-                                        host.nbytes))
+                produce(put, stop, *args)
             except BaseException as e:  # noqa: BLE001 re-raised in consumer
                 # a read failure must FAIL the epoch, not silently end
                 # it short (a truncated epoch looks complete downstream)
-                self._put(q, stop, ("__error__", e))
+                put(("__error__", e))
             finally:
-                self._put(q, stop, SENTINEL)
+                put(SENTINEL)
                 # publish this thread's stream cache: if the consumer
                 # retires the pool AFTER we already exited (late break),
                 # it closes these post-join — retire.is_set() here alone
@@ -606,13 +724,7 @@ class DeviceBlockLoader:
 
                 self._producer_pool = ThreadPoolExecutor(
                     max_workers=1, thread_name_prefix="loader-host-prefetch")
-            epoch_no = self._epoch_counter
-            self._epoch_counter += 1
-            gen = self._svc.begin_epoch(epoch_no) \
-                if self._svc is not None else None
-            fut = self._producer_pool.submit(producer,
-                                             self._epoch_entries(epoch_no),
-                                             gen)
+            fut = self._producer_pool.submit(producer, begin())
         inflight: deque = deque()
         finished = False
         try:
@@ -664,7 +776,7 @@ class DeviceBlockLoader:
                             sp.phase("device_put",
                                      (time.perf_counter() - t_put)
                                      * 1000.0)
-                    if self._hbm is not None:
+                    if self._hbm is not None and pid is not None:
                         self._hbm.adopt(pid, arr)  # no second transfer
                 inflight.append(arr)
                 while len(inflight) > self._prefetch:

@@ -366,6 +366,13 @@ class ShmTransport:
         for seg in segs:
             seg.close_map()
 
+    def holds(self, block_id: int) -> bool:
+        """Whether the cache maps ``block_id`` now, so that an open of
+        it would be a dictionary look and no lease."""
+        with self._lock:
+            seg = self._segments.get(block_id)
+            return seg is not None and not seg.dead
+
     def cached_blocks(self) -> int:
         with self._lock:
             return len(self._segments)

@@ -52,9 +52,10 @@ def _row(config: str, metric: str, value: float, unit: str,
 def config2_random_4k(jax, fs, device, *, shard_bytes: int,
                       num_shards: int = 4, reads: int = 4096,
                       batch: int = 256) -> Dict:
-    """Random 4k reads from the warm host tier, batched into HBM."""
-    import jax.numpy as jnp
-
+    """Random 4k reads from the warm host tier, batched into HBM through
+    the loader's sample-grain entry (``DeviceBlockLoader.windows``): a
+    batch of ``batch`` windows is one ``device_put``."""
+    from alluxio_tpu.client.jax_io import DeviceBlockLoader
     from alluxio_tpu.client.streams import WriteType
 
     rng = np.random.default_rng(7)
@@ -72,24 +73,19 @@ def config2_random_4k(jax, fs, device, *, shard_bytes: int,
     jax.device_put(arr, device).block_until_ready()
     ceil_rate = shard_bytes / (time.monotonic() - t0)
 
-    handles = [fs.open_file(p) for p in paths]
     offsets = rng.integers(0, shard_bytes - 4096, size=reads)
     shards = rng.integers(0, num_shards, size=reads)
-    t0 = time.monotonic()
-    buf = np.empty((batch, 4096), dtype=np.uint8)
-    done = 0
-    devs = []
-    for i in range(reads):
-        h = handles[shards[i]]
-        h.seek(int(offsets[i]))
-        buf[done % batch] = np.frombuffer(h.read(4096), dtype=np.uint8)
-        done += 1
-        if done % batch == 0:  # batch lands in HBM
-            devs.append(jax.device_put(buf.copy(), device))
-    jax.block_until_ready(devs)
-    dt = time.monotonic() - t0
-    for h in handles:
-        h.close()
+    rows = np.stack([shards, offsets], axis=1)
+    loader = DeviceBlockLoader(fs, paths, device=device)
+    try:
+        t0 = time.monotonic()
+        devs = list(loader.windows(
+            (rows[i:i + batch] for i in range(0, reads, batch)),
+            window_bytes=4096))
+        jax.block_until_ready(devs)
+        dt = time.monotonic() - t0
+    finally:
+        loader.close()
     rate = reads * 4096 / dt
     return _row("2-random-4k",
                 "random 4k reads batched into HBM", rate / 1e6, "MB/s",

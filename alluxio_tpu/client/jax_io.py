@@ -31,7 +31,7 @@ import time
 import weakref
 from collections import deque
 from concurrent.futures import CancelledError
-from typing import Iterator, List, Optional, Sequence
+from typing import Iterator, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -248,6 +248,106 @@ def _next_use_evictor(svc, block_of: dict) -> NextUseCacheEvictor:
         lambda pid, served: svc.next_use(block_of.get(pid), served))
 
 
+class RowGroup(NamedTuple):
+    """One row group's projection as :meth:`DeviceBlockLoader.row_groups`
+    lands it: ONE pytree, so one ``device_put`` a row group. Every array
+    has the pass's one row count; rows from ``num_rows`` on are
+    padding."""
+
+    #: column -> fixed-width values; a NULL and the padding land as 0
+    values: dict
+    #: nullable column -> bool, False at a NULL and at the padding
+    valid: dict
+    #: int32 scalar: the rows the row group holds
+    num_rows: np.ndarray
+
+
+def _land_dtype(arrow_type) -> np.dtype:
+    """The dtype a fixed-width Arrow type lands as: a decimal as its
+    unscaled integer, int32 up to precision 9 and int64 up to 18; a
+    date, time, timestamp or duration as its integer."""
+    import pyarrow as pa
+
+    t = arrow_type
+    if pa.types.is_decimal(t) and t.precision <= 18:
+        return np.dtype("<i4" if t.precision <= 9 else "<i8")
+    if pa.types.is_boolean(t):
+        return np.dtype(bool)
+    if pa.types.is_integer(t) or pa.types.is_floating(t):
+        return np.dtype(t.to_pandas_dtype())
+    if (pa.types.is_date(t) or pa.types.is_time(t)
+            or pa.types.is_timestamp(t) or pa.types.is_duration(t)):
+        return np.dtype(f"<i{t.bit_width // 8}")
+    raise TypeError(f"a column of type {t} has no fixed-width landing")
+
+
+def _bits(buf, offset: int, n: int) -> np.ndarray:
+    """``n`` bools of an Arrow bitmap from bit ``offset`` (LSB first)."""
+    return np.unpackbits(np.frombuffer(buf, np.uint8), count=offset + n,
+                         bitorder="little")[offset:].view(bool)
+
+
+def _chunk_values(arr, dtype: np.dtype) -> np.ndarray:
+    """A view of one Arrow chunk's values as ``dtype``, no copy but a
+    boolean's unpacking. A decimal's view is its values' low words
+    (every fourth 32-bit word of a ``decimal128``), which two's
+    complement makes the value wherever it fits."""
+    import pyarrow as pa
+
+    n, off = len(arr), arr.offset
+    data = arr.buffers()[1]
+    if not n or data is None:
+        return np.zeros(0, dtype)
+    if pa.types.is_boolean(arr.type):
+        return _bits(data, off, n)
+    step = arr.type.bit_width // 8 // dtype.itemsize \
+        if pa.types.is_decimal(arr.type) else 1
+    return np.frombuffer(data, dtype, count=(off + n) * step)[
+        off * step::step]
+
+
+def _pool_array(n: int, dtype) -> np.ndarray:
+    """``n`` uninitialised values in Arrow's memory pool, which keeps the
+    pages of a freed landing mapped for the next row group's: a fresh
+    NumPy array of 8 MB is 2,048 page faults on a host whose fault maps
+    one page (the decode's own arrays come from the same pool)."""
+    import pyarrow as pa
+
+    dtype = np.dtype(dtype)
+    return np.frombuffer(pa.allocate_buffer(n * dtype.itemsize), dtype)
+
+
+def _land_columns(table, columns: Sequence[str], rows: int):
+    """``(values, valid)`` of :class:`RowGroup` from a decoded pyarrow
+    ``Table``, each array ``rows`` long; a mask for every column the
+    schema calls nullable, whether or not this row group holds a
+    NULL, so every item has one structure."""
+    values, valid = {}, {}
+    for name in columns:
+        field = table.schema.field(name)
+        out = _pool_array(rows, _land_dtype(field.type))
+        mask = _pool_array(rows, bool) if field.nullable else None
+        pos = 0
+        for arr in table.column(name).chunks:
+            k = len(arr)
+            src = _chunk_values(arr, out.dtype)
+            if mask is None:
+                out[pos:pos + k] = src
+            else:
+                bitmap = arr.buffers()[0]
+                mask[pos:pos + k] = True if bitmap is None else \
+                    _bits(bitmap, arr.offset, k)
+                out[pos:pos + k] = 0
+                np.copyto(out[pos:pos + k], src, where=mask[pos:pos + k])
+            pos += k
+        out[pos:] = 0
+        values[name] = out
+        if mask is not None:
+            mask[pos:] = False
+            valid[name] = mask
+    return values, valid
+
+
 class DeviceBlockLoader:
     """Loads whole blocks of one or more files as device-resident uint8
     arrays, with an HBM retention cache and transfer prefetch."""
@@ -372,10 +472,9 @@ class DeviceBlockLoader:
         return self._host_bytes(path, index)
 
     # -- single block --------------------------------------------------------
-    def _host_bytes(self, path: str, index: int):
-        """Host-side view of one block: zero-copy numpy over mmap when the
-        same-host lease plane serves it, else a bytes copy from the
-        stream."""
+    def _stream(self, path: str):
+        """This thread's stream of ``path``, opened on first use with
+        the ``FileInfo`` the constructor resolved."""
         streams = getattr(self._tls, "streams", None)
         if streams is None:
             streams = self._tls.streams = {}
@@ -396,6 +495,13 @@ class DeviceBlockLoader:
                     raise RuntimeError("loader is closed")
                 self._all_streams.append(f)
             streams[path] = f
+        return f
+
+    def _host_bytes(self, path: str, index: int):
+        """Host-side view of one block: zero-copy numpy over mmap when the
+        same-host lease plane serves it, else a bytes copy from the
+        stream."""
+        f = self._stream(path)
         # every thread of the process opens through ONE segment cache
         # (the producer, the prefetch agent's adopt thread, a mesh
         # load's pool): another thread's opens can turn its LRU over and
@@ -669,6 +775,100 @@ class DeviceBlockLoader:
                          out.nbytes))
 
         return self._pipeline(produce, lambda: (iter(batches),))
+
+    def row_groups(self, columns: Sequence[str], *,
+                   rows: Optional[int] = None) -> Iterator:
+        """A projection of the loader's Parquet files on the device, one
+        :class:`RowGroup` a row group, files in the loader's order.
+
+        On the producer thread, for each row group: the footer when the
+        pass reaches its file (read through the thread's stream of the
+        file with the ``FileInfo`` the constructor resolved, and cached
+        as ``read_columns`` caches it: a job start pays one), then the
+        planned read that ``read_columns`` runs (``PlannedFile.fetch``:
+        ``pread_ranges`` of the projection's coalesced ranges, zero-copy
+        views on the same-host plane, each range made present and never
+        its block; ``PlannedFile.decode``: pyarrow's decode), then the
+        landing (:func:`_land_columns`): every column as ONE fixed-width
+        array of ``rows`` (default: the pass's first row group's), a
+        decimal as its unscaled integer (int32 up to precision 9, int64
+        up to 18) read from Arrow's buffer, never a Python object;
+        a nullable column with a validity mask; a shorter row group
+        padded, the padding False in every mask and beyond ``num_rows``,
+        so one program takes every item. A row group longer than
+        ``rows`` fails the pass, as does a file the planner cannot plan
+        (``ParquetPlanError``). The consumer's side is :meth:`epoch`'s
+        (:meth:`_pipeline`): the bounded queue, ONE ``device_put`` a row
+        group, and closing the generator mid-pass retires the producer.
+        The HBM tier is not consulted: it keeps whole blocks, and a
+        projection reads a share of one."""
+        from alluxio_tpu.table.reader import PlannedFile
+
+        columns = [str(c) for c in columns]
+        if rows is not None and rows <= 0:
+            raise ValueError(f"rows must be positive, not {rows}")
+        m = self._m
+        n_groups = m.counter("Client.JaxTableRowGroups")
+        n_rows = m.counter("Client.JaxTableRows")
+        #: the planned (coalesced) range bytes fetched
+        n_fetched = m.counter("Client.JaxTableFetchedBytes")
+        #: the row groups' compressed bytes, every column
+        n_group_bytes = m.counter("Client.JaxTableRowGroupBytes")
+        #: reads pyarrow made outside the fetched ranges
+        n_misses = m.counter("Client.JaxTablePlanMisses")
+        conf = self._fs.conf
+
+        def produce(put, stop):
+            span = tracer().span
+            pad = rows
+            for path in self._paths:
+                pfile, index = None, 0
+                while pfile is None or index < len(pfile.plans):
+                    if stop.is_set():
+                        return
+                    route: dict = {}
+                    with span("atpu.loader.host_read",
+                              row_group=index) as sp:
+                        if pfile is None:
+                            pfile = PlannedFile(
+                                self._stream(path), path, self._infos[path],
+                                columns, conf)
+                            if not pfile.plans:
+                                break
+                        plan = pfile.plans[index]
+                        pad = plan.num_rows if pad is None else pad
+                        if plan.num_rows > pad:
+                            raise ValueError(
+                                f"row group {plan.index} of {path} holds "
+                                f"{plan.num_rows} rows, more than the "
+                                f"pass's {pad}")
+                        if sp is not None:
+                            sp.tags["rows"] = str(plan.num_rows)
+                        pfile.fetch(plan, route)
+                        misses = pfile.src.misses
+                        table = pfile.decode([plan])
+                        with span("atpu.table.land", rows=pad,
+                                  columns=len(columns)):
+                            values, valid = _land_columns(table, columns,
+                                                          pad)
+                        del table
+                    group = pfile.metadata.row_group(plan.index)
+                    n_groups.inc()
+                    n_rows.inc(plan.num_rows)
+                    n_fetched.inc(sum(n for _off, n in plan.reads))
+                    n_group_bytes.inc(sum(
+                        group.column(c).total_compressed_size
+                        for c in range(group.num_columns)))
+                    n_misses.inc(pfile.src.misses - misses)
+                    item = RowGroup(values, valid, np.int32(plan.num_rows))
+                    put((None, item, False,
+                         "shm" if "shm" in route else
+                         "remote" if route else "unknown",
+                         sum(a.nbytes for a in values.values())
+                         + sum(a.nbytes for a in valid.values())))
+                    index += 1
+
+        return self._pipeline(produce, tuple)
 
     def _pin_room(self) -> int:
         """The segments a windows pass may keep leased: a lease pins its

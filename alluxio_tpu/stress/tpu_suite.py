@@ -162,7 +162,7 @@ def config4_projection(jax, fs, device, *, rows_per_part: int = 30_000,
     import pyarrow as pa
     import pyarrow.parquet as pq
 
-    from alluxio_tpu.table.reader import open_parquet
+    from alluxio_tpu.table.reader import read_columns
 
     rng = np.random.default_rng(13)
     cols = {f"c{i}": rng.standard_normal(rows_per_part).astype(np.float32)
@@ -181,18 +181,18 @@ def config4_projection(jax, fs, device, *, rows_per_part: int = 30_000,
         fs.write_all(path, blob)
         paths.append(path)
     want = ["c0", "label", "weight"]
-    # warm footers
+    # warm the planner's footer and plan caches: the planned path
     for p in paths:
-        open_parquet(fs, p)
+        read_columns(fs, [p], columns=want)
     t0 = time.monotonic()
-    full = [open_parquet(fs, p).read() for p in paths]
+    full = [read_columns(fs, [p]) for p in paths]
     t_full = time.monotonic() - t0
     n_full = sum(t.nbytes for t in full)
     del full
     t0 = time.monotonic()
     devs = []
     for p in paths:
-        t = open_parquet(fs, p).read(columns=want)
+        t = read_columns(fs, [p], columns=want)
         for name in want:
             devs.append(jax.device_put(
                 np.ascontiguousarray(t.column(name).to_numpy()), device))

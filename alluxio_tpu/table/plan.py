@@ -129,6 +129,14 @@ def footer_cache() -> FooterCache:
     return _FOOTER_CACHE
 
 
+def clear_caches() -> None:
+    """Empty the process-wide footer and plan LRUs: the next planned
+    read of every file fetches and parses its footer again, as the
+    first read of a new job's process does."""
+    _FOOTER_CACHE.clear()
+    _PLAN_CACHE.clear()
+
+
 def metadata_version(info) -> tuple:
     """The (file_id, length, mtime) stamp a footer cache entry is keyed
     on — the fields the PR-10 metadata cache keeps heartbeat-coherent."""

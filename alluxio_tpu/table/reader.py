@@ -36,6 +36,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence
 
 from alluxio_tpu.table import plan as _plan
+from alluxio_tpu.utils.tracing import tracer
 
 
 def _metrics():
@@ -110,6 +111,9 @@ class _RangeCachedFile:
         self._closed = False
         self._starts: List[int] = []       # sorted buffer start offsets
         self._bufs: Dict[int, object] = {}  # start offset -> buffer
+        #: reads the staged buffers did not cover, this file's share of
+        #: ``Client.TableProjectionPlanMisses``
+        self.misses = 0
 
     # -- staging -------------------------------------------------------------
     def install(self, offset: int, buf) -> None:
@@ -153,6 +157,7 @@ class _RangeCachedFile:
                     min(n, self._starts[j] - self._pos)
                 _metrics().counter(
                     "Client.TableProjectionPlanMisses").inc()
+                self.misses += 1
                 with self._lock:
                     got = self._s.pread(self._pos, take)
                 if not got:
@@ -238,6 +243,88 @@ def _pread_ranges(stream, ranges, route_stats):
     return out
 
 
+def _make_present(buf) -> None:
+    """Map the pages under a zero-copy range view in one kernel call
+    (``native.prefault``), the range and never its whole block: on a
+    host whose fault maps one page, pyarrow would otherwise fault a
+    column chunk in page by page inside its decode. A copied range
+    (``bytes``) is present already."""
+    if isinstance(buf, memoryview) and buf.nbytes:
+        from alluxio_tpu import native
+
+        native.prefault(buf)
+
+
+class PlannedFile:
+    """One Parquet file opened for planned reads over a stream the
+    caller holds: its footer (LRU-cached), the projection's row-group
+    plans, and the range cache pyarrow decodes from. :meth:`fetch` and
+    :meth:`decode` are the two halves of one row group's planned read:
+    :class:`_PlannedRead` pipelines them across a file, and
+    ``DeviceBlockLoader.row_groups`` runs them a row group at a time on
+    its producer thread. A lock serialises the stream between a fetch
+    and a plan miss's fall-through read, which the pipeline runs on two
+    threads. Raises ``ParquetPlanError`` when the file cannot be
+    planned."""
+
+    def __init__(self, stream, path: str, info,
+                 columns: Optional[Sequence[str]], conf) -> None:
+        import pyarrow.parquet as pq
+
+        from alluxio_tpu.conf import Keys
+
+        self.columns = list(columns) if columns is not None else None
+        self._stream = stream
+        self._lock = threading.Lock()
+        cache_max = conf.get_int(Keys.USER_TABLE_FOOTER_CACHE_MAX)
+        with tracer().span("atpu.table.footer", path=path) as sp:
+            footer = _plan.cached_footer(
+                stream.pread, path, info,
+                guess_bytes=max(_plan._TAIL_FIXED, conf.get_bytes(
+                    Keys.USER_TABLE_FOOTER_READ_BYTES)),
+                cache_max=cache_max)
+            self.plans = _plan.cached_plan(
+                path, info, footer.metadata, self.columns,
+                slack=max(0, conf.get_bytes(
+                    Keys.USER_TABLE_COALESCE_SLACK_BYTES)),
+                cache_max=cache_max)
+            self.metadata = footer.metadata
+            self.src = _RangeCachedFile(stream, info.length, self._lock)
+            self.src.install(footer.tail_offset, footer.tail)
+            # hand the cached FileMetaData over: construction skips the
+            # (already-done) footer re-parse. No pre-buffering: the
+            # range cache holds the plan already, and pyarrow's own
+            # coalescing (holes up to 8 KiB) would read across the gaps
+            # between two row groups decoded in one call, which the plan
+            # does not fetch
+            self.pf = pq.ParquetFile(self.src, metadata=footer.metadata,
+                                     pre_buffer=False)
+            if sp is not None:
+                sp.tags["row_groups"] = str(len(self.plans))
+
+    def fetch(self, plan, route_stats: Optional[Dict[str, int]] = None
+              ) -> None:
+        """Stage one row group's planned (coalesced) reads for pyarrow,
+        each made present."""
+        with tracer().span("atpu.table.fetch",
+                           bytes=sum(n for _off, n in plan.reads),
+                           ranges=len(plan.reads)):
+            with self._lock:
+                bufs = _pread_ranges(self._stream, plan.reads, route_stats)
+            for (off, _n), buf in zip(plan.reads, bufs):
+                _make_present(buf)
+                self.src.install(off, buf)
+
+    def decode(self, plans):
+        """pyarrow's decode of the projection of fetched row groups, in
+        one call; their staged buffers go."""
+        with tracer().span("atpu.table.decode", row_groups=len(plans)):
+            table = self.pf.read_row_groups([p.index for p in plans],
+                                            columns=self.columns)
+        self.src.drop([off for p in plans for off, _n in p.reads])
+        return table
+
+
 class _PlannedRead:
     """One file's planned projection read: footer -> range plan ->
     pipelined fetch/decode.
@@ -255,71 +342,50 @@ class _PlannedRead:
         self._fs = fs
         self._path = path
         self._columns = list(columns) if columns is not None else None
+        self._conf = conf
         self._depth = max(1, conf.get_int(Keys.USER_TABLE_PIPELINE_DEPTH))
-        self._slack = max(0, conf.get_bytes(
-            Keys.USER_TABLE_COALESCE_SLACK_BYTES))
-        self._footer_guess = max(_plan._TAIL_FIXED, conf.get_bytes(
-            Keys.USER_TABLE_FOOTER_READ_BYTES))
-        self._cache_max = conf.get_int(Keys.USER_TABLE_FOOTER_CACHE_MAX)
 
     def run(self):
         """Execute the planned read; raises ``ParquetPlanError`` (before
         any partial decode) when the file cannot be planned."""
-        import pyarrow.parquet as pq
-
-        from alluxio_tpu.utils.tracing import tracer
-
         m = _metrics()
         with tracer().span("atpu.client.table_read",
                            path=self._path) as sp:
             t_plan0 = time.perf_counter()
             info = self._fs.get_status(self._path)
             stream = self._fs.open_file(self._path, info=info)
-            lock = threading.Lock()
             try:
-                footer = _plan.cached_footer(
-                    stream.pread, self._path, info,
-                    guess_bytes=self._footer_guess,
-                    cache_max=self._cache_max)
-                plans = _plan.cached_plan(
-                    self._path, info, footer.metadata, self._columns,
-                    slack=self._slack, cache_max=self._cache_max)
+                f = PlannedFile(stream, self._path, info, self._columns,
+                                self._conf)
+                plans = f.plans
                 m.counter("Client.TableProjectionRanges").inc(
                     sum(len(p.ranges) for p in plans))
                 m.counter("Client.TableProjectionRangesCoalesced").inc(
                     sum(len(p.reads) for p in plans))
                 m.counter("Client.TableProjectionBytes").inc(
                     sum(p.projected_bytes for p in plans))
-                src = _RangeCachedFile(stream, info.length, lock)
-                src.install(footer.tail_offset, footer.tail)
-                # hand the cached FileMetaData over: construction skips
-                # the (already-done) footer re-parse
-                pf = pq.ParquetFile(src, metadata=footer.metadata)
                 if sp is not None:
                     sp.phase("table_plan",
                              (time.perf_counter() - t_plan0) * 1000.0)
                 if not plans:
-                    return pf.read(columns=self._columns)
-                return self._pipeline(pf, src, stream, lock, plans, sp, m)
+                    return f.pf.read(columns=self._columns)
+                return self._pipeline(f, sp, m)
             finally:
                 stream.close()
 
-    def _pipeline(self, pf, src, stream, lock, plans, sp, m):
+    def _pipeline(self, f: PlannedFile, sp, m):
         import pyarrow as pa
 
         route_stats: Dict[str, int] = {}
 
         def fetch(p):
-            with lock:
-                bufs = _pread_ranges(stream, p.reads, route_stats)
-            for (off, _n), buf in zip(p.reads, bufs):
-                src.install(off, buf)
+            f.fetch(p, route_stats)
             return p
 
         parts = []
         decode_ms = 0.0
         overlap_ms = 0.0
-        pending = deque(plans)
+        pending = deque(f.plans)
         inflight: "deque" = deque()
         pool = _fetch_pool()
         try:
@@ -337,13 +403,11 @@ class _PlannedRead:
                     inflight.append(pool.submit(fetch, pending.popleft()))
                 overlapped = bool(inflight)
                 t0 = time.perf_counter()
-                parts.append(pf.read_row_groups(
-                    [p.index for p in ready], columns=self._columns))
+                parts.append(f.decode(ready))
                 d = (time.perf_counter() - t0) * 1000.0
                 decode_ms += d
                 if overlapped:
                     overlap_ms += d
-                src.drop([off for p in ready for off, _n in p.reads])
         finally:
             # teardown on mid-read error: every in-flight fetch must
             # finish or cancel before the stream under it closes (the

@@ -94,8 +94,7 @@ def _parquet(table, row_group_size: int, compression="none") -> bytes:
 
 @pytest.fixture(autouse=True)
 def _fresh_caches():
-    tplan.footer_cache().clear()
-    tplan._PLAN_CACHE.clear()
+    tplan.clear_caches()
     yield
 
 

@@ -14,10 +14,12 @@ per block:
    intermediate copy), then the HBM store retains it for next epoch.
 3. **Cold** — worker read-through from the UFS (caching it), then (2).
 
-``device_put`` dispatches asynchronously, so the loader keeps
-``prefetch`` transfers in flight while the consumer computes — the
-double-buffering that hides H2D latency behind step time (SURVEY.md hard
-part: "prefetch collectives must overlap compute").
+``device_put`` dispatches asynchronously, so while the consumer sets
+the pace the loader keeps ``prefetch`` transfers in flight behind the
+block it hands out — the double-buffering that hides H2D latency behind
+step time (SURVEY.md hard part: "prefetch collectives must overlap
+compute"); where the producer sets it, a block is handed out as soon as
+its transfer is issued.
 """
 
 from __future__ import annotations
@@ -391,6 +393,11 @@ class DeviceBlockLoader:
         #: time the producer spent blocked on a FULL queue (reads 0, not
         #: "absent", for a loader whose producer never was)
         self._blocked_us = self._m.counter("Client.JaxProducerBlockedUs")
+        #: items the consumer handed out, and of them those handed out
+        #: with fewer than ``prefetch`` behind them because the queue
+        #: held none
+        self._yields = self._m.counter("Client.JaxLoaderYields")
+        self._early_yields = self._m.counter("Client.JaxLoaderEarlyYields")
         # how often the kernel mapped a missed block whole, of the
         # non-empty host views the producer made present
         self._prefault_blocks = self._m.counter("Client.JaxPrefaultBlocks")
@@ -635,11 +642,15 @@ class DeviceBlockLoader:
 
         Two-stage pipeline (:meth:`_pipeline`): a producer thread does
         ALL host-side work (worker RPCs, mmap setup, making the
-        mapping's pages present) ahead of the consumer, so the
-        device_put stream never stalls on per-block host latency. On an
-        HBM miss that one thread sets the pace: the consumer waits on it
-        for 80% of a scan's window (``loader.get_wait_share``; PERF.md
-        section 5 has the split). The queue is bounded, and an abandoned
+        mapping's pages present) ahead of the consumer, which only puts
+        items on the device. A block is handed out as soon as its
+        transfer is issued unless the queue already holds the next one:
+        up to ``prefetch`` + 1 are in flight only while the consumer
+        sets the pace. On an HBM miss the one producer sets it: the
+        consumer waits on it for 80% of a scan's window
+        (``loader.get_wait_share``; PERF.md section 5 has the split),
+        and the first block leaves after one host read, not
+        ``prefetch`` + 1. The queue is bounded, and an abandoned
         generator unblocks the producer via a stop flag.
 
         Early consumer exit (break mid-epoch) retires the producer
@@ -910,14 +921,27 @@ class DeviceBlockLoader:
         return len(rows), plan
 
     def _pipeline(self, produce, begin) -> Iterator:
-        """The loader's two stages, for :meth:`epoch` and
-        :meth:`windows`: ``produce(put, stop, *begin())`` runs on the
-        ONE producer thread and hands ``(pid, data, on_device, bucket,
-        nbytes)`` items to ``put`` (the bounded queue); this generator
-        is the consumer's side, which puts a host item on the device
-        (and offers it to the HBM tier where ``pid`` names a page).
-        ``begin`` runs under the epoch lock, once this iteration is the
-        live one."""
+        """The loader's two stages, for :meth:`epoch`, :meth:`windows`
+        and :meth:`row_groups`: ``produce(put, stop, *begin())`` runs on
+        the ONE producer thread and hands ``(pid, data, on_device,
+        bucket, nbytes)`` items to ``put`` (the bounded queue); this
+        generator is the consumer's side, which puts a host item on the
+        device (and offers it to the HBM tier where ``pid`` names a
+        page). ``begin`` runs under the epoch lock, once this iteration
+        is the live one.
+
+        The consumer blocks on the queue only when nothing is in
+        flight. With something in flight it takes only what the queue
+        already holds, up to ``prefetch`` + 1 in flight, and hands out
+        the oldest as soon as the queue holds none or the depth is
+        full: a producer-paced pass yields each item the moment its
+        transfer is issued (the first after ONE host read), a
+        consumer-paced one, whose queue is full, keeps ``prefetch``
+        behind every item. Items leave in the producer's order, and a
+        superseded or closed iteration raises before it would hand one
+        out. ``Client.JaxLoaderYields`` counts the items handed out,
+        ``Client.JaxLoaderEarlyYields`` those with fewer than
+        ``prefetch`` behind them."""
         span = tracer().span
         q: queue.Queue = queue.Queue(maxsize=max(1, self._prefetch) + 1)
         stop = threading.Event()
@@ -961,30 +985,54 @@ class DeviceBlockLoader:
             fut = self._producer_pool.submit(producer, begin())
         inflight: deque = deque()
         finished = False
+
+        def superseded() -> None:
+            if stop.is_set():
+                # cancelled by close()/a newer epoch(): fail loudly — a
+                # silently-truncated epoch looks complete downstream
+                raise RuntimeError(
+                    "epoch cancelled: the loader was closed or a newer "
+                    "epoch() superseded this iterator")
+
         try:
             # input-doctor accounting: each queue wait is attributed to
             # the serving tier of the item that ends it; elapsed-since-
             # last-item bounds the rolling input-bound fraction
             last_item_t = time.monotonic()
             while True:
-                # the consumer's side of the queue: the span covers the
-                # interval StepStats.record is given as wait_s
-                with span("atpu.loader.get_wait"):
-                    wait_t0 = time.monotonic()
-                    while True:
+                item = None
+                if inflight:
+                    room = len(inflight) <= self._prefetch
+                    if room:
+                        # something is on its way to the device: take
+                        # only what the queue already holds
                         try:
-                            item = q.get(timeout=0.5)
-                            break
+                            item = q.get_nowait()
                         except queue.Empty:
-                            if stop.is_set():
-                                # cancelled by close()/a newer epoch():
-                                # fail loudly — a silently-truncated
-                                # epoch looks complete downstream
-                                raise RuntimeError(
-                                    "epoch cancelled: the loader was "
-                                    "closed or a newer epoch() "
-                                    "superseded this iterator")
-                    now = time.monotonic()
+                            pass
+                    if item is None:
+                        # the queue held none (the producer sets the
+                        # pace) or prefetch + 1 are in flight: hand out
+                        # the oldest
+                        superseded()
+                        self._yields.inc()
+                        if room:
+                            self._early_yields.inc()
+                        yield inflight.popleft()
+                        continue
+                    wait_t0 = now = time.monotonic()
+                else:
+                    # nothing in flight: block. The span covers the
+                    # interval StepStats.record is given as wait_s
+                    with span("atpu.loader.get_wait"):
+                        wait_t0 = time.monotonic()
+                        while True:
+                            try:
+                                item = q.get(timeout=0.5)
+                                break
+                            except queue.Empty:
+                                superseded()
+                        now = time.monotonic()
                 if item is SENTINEL:
                     break
                 if item[0] == "__error__":
@@ -1013,9 +1061,9 @@ class DeviceBlockLoader:
                     if self._hbm is not None and pid is not None:
                         self._hbm.adopt(pid, arr)  # no second transfer
                 inflight.append(arr)
-                while len(inflight) > self._prefetch:
-                    yield inflight.popleft()
             while inflight:
+                superseded()
+                self._yields.inc()
                 yield inflight.popleft()
             finished = True
         finally:

@@ -210,22 +210,28 @@ def leg_resident(fs, data: Dataset, device, *,
         _check_sums(loader, data.sums)
         e1 = _delta(client_counters(), c0)
         # how many of them the kernel mapped whole is the host's answer
-        # (0 where it has neither the advice nor mlock): shown, not held
+        # (0 where it has neither the advice nor mlock), and how many
+        # blocks left before the queue held their successor is the
+        # pacing's: shown, not held
         populated = e1.pop("Client.JaxPrefaultPopulated", 0)
+        early = [e1.pop("Client.JaxLoaderEarlyYields", 0)]
         if e1 != {"Client.JaxShortCircuitBlocks": n,
                   "Client.JaxHbmAdopts": n,
                   "Client.JaxPrefaultBlocks": n,
+                  "Client.JaxLoaderYields": n,
                   "Client.BytesRead.shm": data.total_bytes}:
             raise AssertionError(f"epoch 1 was not all SHM->HBM: {e1}")
         c1 = client_counters()
         _check_sums(loader, data.sums)
         e2 = _delta(client_counters(), c1)
-        if e2 != {"Client.JaxHbmHits": n}:
+        early.append(e2.pop("Client.JaxLoaderEarlyYields", 0))
+        if e2 != {"Client.JaxHbmHits": n, "Client.JaxLoaderYields": n}:
             raise AssertionError(f"epoch 2 was not all HBM hits: {e2}")
         stats = loader.hbm_stats()
     if stats != {"hbm_bytes": data.total_bytes, "hbm_pages": n}:
         raise AssertionError(f"HBM tier does not hold the set: {stats}")
-    say("resident", epoch1=e1, populated=populated, epoch2=e2, **stats)
+    say("resident", epoch1=e1, populated=populated, epoch2=e2,
+        early_yields=early, **stats)
 
 
 def leg_pallas(fs, data: Dataset, device, *,

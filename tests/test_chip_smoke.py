@@ -65,9 +65,12 @@ def test_legs_tiny_with_real_role_processes(tmp_path, capsys):
         "Client.JaxShortCircuitBlocks": n,
         "Client.JaxHbmAdopts": n,
         "Client.JaxPrefaultBlocks": n,
+        "Client.JaxLoaderYields": n,
         "Client.BytesRead.shm": n * block}
     assert facts["resident"]["populated"] in (0, n)  # one kernel, one rung
-    assert facts["resident"]["epoch2"] == {"Client.JaxHbmHits": n}
+    assert facts["resident"]["epoch2"] == {"Client.JaxHbmHits": n,
+                                           "Client.JaxLoaderYields": n}
+    assert all(0 <= e <= n for e in facts["resident"]["early_yields"])
     assert facts["evict"]["high_water"] <= facts["evict"]["capacity"]
     assert facts["pallas"]["equal_to_xla"]
     assert facts["consumer"]["steps"] == (2 * (block // 772)) // 128

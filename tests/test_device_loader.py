@@ -3,11 +3,14 @@ HBM-retention hits, and lifecycle edge cases (the close()/second-epoch
 deadlock regression for the single-producer design)."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
 
+from alluxio_tpu.metrics import metrics
 from alluxio_tpu.minicluster import LocalCluster
+from tests.testutils.loader_gates import FullQueue, held_after_first
 
 BLOCK = 64 * 1024
 
@@ -30,15 +33,30 @@ def _make_loader(cluster, n_blocks=4, hbm_bytes=0, prefetch=2):
     return loader, data
 
 
+def _count(name: str) -> float:
+    return metrics().snapshot().get(name, 0)
+
+
 class TestEpoch:
-    def test_epoch_yields_all_blocks_in_order(self, cluster):
+    @pytest.mark.parametrize("producer", ["fast", "slow"])
+    def test_epoch_yields_all_blocks_in_order(self, cluster, producer):
         loader, data = _make_loader(cluster)
+        if producer == "slow":
+            host_present = loader._host_present
+
+            def slowly(path, index):
+                time.sleep(0.02)
+                return host_present(path, index)
+
+            loader._host_present = slowly
+        yields0 = _count("Client.JaxLoaderYields")
         try:
             out = b"".join(
                 np.asarray(b).tobytes() for b in loader.epoch())
             assert out == data
         finally:
             loader.close()
+        assert _count("Client.JaxLoaderYields") - yields0 == len(loader)
 
     def test_hbm_retention_serves_second_epoch(self, cluster):
         loader, data = _make_loader(cluster, hbm_bytes=16 << 20)
@@ -93,10 +111,50 @@ class TestEpoch:
             loader.close()
 
 
-def _hbm_hits():
-    from alluxio_tpu.metrics import metrics
+class TestYieldRule:
+    """The consumer blocks on the queue only when nothing is in
+    flight: the first block leaves after ONE host read where the
+    producer sets the pace, and after ``prefetch`` + 1 transfers where
+    the queue is full."""
 
-    return metrics().counter("Client.JaxHbmHits").count
+    def test_the_first_block_leaves_while_the_producer_holds_its_second(
+            self, cluster):
+        loader, data = _make_loader(cluster, n_blocks=4, prefetch=2)
+        gate, timed_out = held_after_first(loader, "_host_present")
+        early0 = _count("Client.JaxLoaderEarlyYields")
+        try:
+            it = loader.epoch()
+            first = np.asarray(next(it)).tobytes()
+            # handed out before the producer read a second block
+            assert timed_out == []
+            assert _count("Client.JaxLoaderEarlyYields") - early0 >= 1
+            gate.set()
+            out = first + b"".join(np.asarray(b).tobytes() for b in it)
+            assert out == data
+        finally:
+            gate.set()
+            loader.close()
+
+    @pytest.mark.parametrize("prefetch", [0, 1, 2])
+    def test_a_full_queue_keeps_prefetch_behind_the_first_block(
+            self, cluster, prefetch):
+        loader, data = _make_loader(cluster, n_blocks=6, prefetch=prefetch)
+        gate = FullQueue(loader, prefetch + 1)
+        early0 = _count("Client.JaxLoaderEarlyYields")
+        try:
+            it = loader.epoch()
+            first = np.asarray(next(it)).tobytes()
+            assert gate.puts == prefetch + 1
+            assert _count("Client.JaxLoaderEarlyYields") == early0
+            out = first + b"".join(np.asarray(b).tobytes() for b in it)
+            assert out == data
+            assert gate.puts == len(loader)  # one device_put a block
+        finally:
+            loader.close()
+
+
+def _hbm_hits():
+    return _count("Client.JaxHbmHits")
 
 
 class TestLifecycle:
@@ -120,20 +178,31 @@ class TestLifecycle:
             loader.load_block(0)
         assert loader._producer_pool is None  # nothing resurrected
 
-    def test_new_epoch_cancels_stale_generator(self, cluster):
+    @pytest.mark.parametrize("in_flight", [0, 2])
+    def test_new_epoch_cancels_stale_generator(self, cluster, in_flight):
         """Regression: a second epoch() must not queue forever behind a
         producer whose abandoned-but-referenced generator never ran its
-        finally block."""
-        loader, data = _make_loader(cluster, n_blocks=6, prefetch=1)
+        finally block; and a superseded iterator hands out none of the
+        blocks it still has in flight."""
+        loader, data = _make_loader(cluster, n_blocks=6,
+                                    prefetch=max(1, in_flight))
+        gate = FullQueue(loader, in_flight + 1) if in_flight else None
         try:
             stale = loader.epoch()
             next(stale)  # keep a reference; never exhaust it
+            if gate is not None:
+                # ``in_flight`` blocks on the device, not handed out
+                assert gate.puts == in_flight + 1
             out = b"".join(
                 np.asarray(b).tobytes() for b in loader.epoch())
             assert out == data
             # the superseded iterator fails loudly, never truncates
+            got = []
             with pytest.raises(RuntimeError, match="cancelled"):
-                list(stale)
+                for b in stale:
+                    got.append(b)
+            assert got == []
+            assert list(stale) == []
         finally:
             loader.close()
 

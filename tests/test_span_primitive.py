@@ -18,6 +18,7 @@ from alluxio_tpu.minicluster import LocalCluster
 from alluxio_tpu.utils.tracing import (
     set_tracing_enabled, to_trace_clock, tracer,
 )
+from tests.testutils.loader_gates import Lockstep
 
 BLOCK = 64 * 1024
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -160,9 +161,13 @@ class TestDeviceTimelineSink:
 class TestLoaderSpans:
     def test_miss_path_spans_nest(self, cluster, ring):
         loader = _loader(cluster, 4)
+        # each block put only once the one before is handed out: every
+        # take of the consumer blocks on the queue
+        step = Lockstep(loader)
         try:
             ring.clear()
-            assert len(list(loader.epoch())) == 4
+            assert len(list(step.iterate(loader.epoch()))) == 4
+            assert step.timed_out == 0
         finally:
             loader.close()
         spans = ring.snapshot(limit=4000)
@@ -200,9 +205,11 @@ class TestLoaderSpans:
     def test_miss_path_spans_reach_a_capture_with_the_ring_off(
             self, cluster, tmp_path):
         loader = _loader(cluster, 3)
+        step = Lockstep(loader)
         try:
             events = _capture(tmp_path / "cap",
-                              lambda: list(loader.epoch()))
+                              lambda: list(step.iterate(loader.epoch())))
+            assert step.timed_out == 0
         finally:
             loader.close()
         for name in ("atpu.loader.host_read", "atpu.loader.open_block",

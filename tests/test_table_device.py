@@ -22,6 +22,7 @@ from alluxio_tpu.minicluster import LocalCluster
 from alluxio_tpu.table import plan as tplan
 from alluxio_tpu.table.reader import read_columns
 from alluxio_tpu.utils.tracing import set_tracing_enabled, tracer
+from tests.testutils.loader_gates import held_after_first
 
 #: small enough that a column chunk of a file crosses a block
 BLOCK = 64 << 10
@@ -186,6 +187,7 @@ def test_row_groups_land_the_reference(cluster, case):
     fs = cluster.file_system()
     assert any(_chunk_crosses_a_block(fs, p) for p in paths)
     before = _counts()
+    yields0 = metrics().snapshot().get("Client.JaxLoaderYields", 0)
     loader = DeviceBlockLoader(fs, paths)
     try:
         with jax.enable_x64(True):  # int64 columns stay int64 on device
@@ -194,6 +196,8 @@ def test_row_groups_land_the_reference(cluster, case):
         loader.close()
     want = list(reference_groups(n_files, rows, group, columns, group))
     assert len(items) == len(want)
+    assert metrics().snapshot().get("Client.JaxLoaderYields", 0) \
+        - yields0 == len(want)
     for item, w in zip(items, want):
         _assert_item(item, w)
     d = _delta(before)
@@ -312,6 +316,32 @@ def test_closing_mid_pass_retires_the_producer(cluster):
         assert len(list(loader.row_groups(["key"]))) == 18
     finally:
         loader.close()
+
+
+def test_the_first_row_group_leaves_while_the_producer_holds_its_second(
+        cluster, monkeypatch):
+    from alluxio_tpu.table.reader import PlannedFile
+
+    import jax
+
+    paths = _write(cluster, 2, 400, 100)
+    monkeypatch.setattr(PlannedFile, "fetch", PlannedFile.fetch)
+    gate, timed_out = held_after_first(PlannedFile, "fetch")
+    loader = DeviceBlockLoader(cluster.file_system(), paths)
+    try:
+        with jax.enable_x64(True):
+            it = loader.row_groups(["key", "ticket"])
+            items = [next(it)]
+            assert timed_out == []
+            gate.set()
+            items += list(it)
+    finally:
+        gate.set()
+        loader.close()
+    want = list(reference_groups(2, 400, 100, ["key", "ticket"], 100))
+    assert len(items) == len(want)
+    for item, w in zip(items, want):
+        _assert_item(item, w)
 
 
 def test_a_read_after_clear_caches_reads_its_footer_again(cluster):

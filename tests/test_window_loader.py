@@ -17,6 +17,7 @@ from alluxio_tpu.conf import Keys
 from alluxio_tpu.metrics import metrics
 from alluxio_tpu.minicluster import LocalCluster
 from alluxio_tpu.utils.tracing import set_tracing_enabled, tracer
+from tests.testutils.loader_gates import Lockstep, held_after_first
 
 BLOCK = 64 << 10
 #: one nanoGPT window of 1,025 uint16 tokens
@@ -140,6 +141,7 @@ def test_windows_are_the_reference_byte_for_byte(tmp_path, case):
         client = _client(cluster, segments)
         loader = DeviceBlockLoader(client, paths)
         before = _counts()
+        yields0 = _count("Client.JaxLoaderYields")
         try:
             got = [np.asarray(b) for b in
                    loader.windows(batches, window_bytes=WINDOW)]
@@ -155,6 +157,7 @@ def test_windows_are_the_reference_byte_for_byte(tmp_path, case):
         assert batch.dtype == np.uint8 and batch.shape == (len(rows_),
                                                             WINDOW)
         assert np.array_equal(batch, reference_batch(files, rows_))
+    assert _count("Client.JaxLoaderYields") - yields0 == len(batches)
     delta = {k: _count(k) - v for k, v in before.items()}
     n_windows = sum(len(b) for b in batches)
     assert delta["Client.JaxWindowBatches"] == len(batches)
@@ -364,9 +367,13 @@ def test_spans_a_batch_and_a_window(cluster, ring):
     batches = uniform_batches(files, 3, 6)
     batches.append(np.array([[1, BLOCK - 10]]))  # one split window
     loader = DeviceBlockLoader(cluster.file_system(), paths)
+    # each batch read only once the one before is handed out: every
+    # take of the consumer blocks on the queue
+    step = Lockstep(loader)
     try:
         ring.clear()
-        list(loader.windows(batches, window_bytes=WINDOW))
+        list(step.iterate(loader.windows(batches, window_bytes=WINDOW)))
+        assert step.timed_out == 0
     finally:
         loader.close()
     spans = ring.snapshot(limit=4000)
@@ -385,8 +392,31 @@ def test_spans_a_batch_and_a_window(cluster, ring):
     assert not [s for s in spans if s["name"] == "atpu.loader.prefault"]
     assert len([s for s in spans if s["name"] == "atpu.loader.h2d"]) == \
         len(batches)
+    # the consumer's side: one wait a batch, and one for the end
     assert len([s for s in spans if s["name"] == "atpu.loader.get_wait"]) \
-        >= len(batches)
+        == len(batches) + 1
+
+
+def test_the_first_batch_leaves_while_the_producer_holds_its_second(
+        cluster):
+    """One window a batch, inside one block: the producer's second
+    read waits until the consumer has handed out the first batch."""
+    paths, files = _write(cluster, 2, 1)
+    batches = [np.array([[i % 2, 100 * i]]) for i in range(4)]
+    loader = DeviceBlockLoader(cluster.file_system(), paths)
+    gate, timed_out = held_after_first(loader, "_host_bytes")
+    try:
+        it = loader.windows(batches, window_bytes=WINDOW)
+        got = [np.asarray(next(it))]
+        assert timed_out == []
+        gate.set()
+        got += [np.asarray(b) for b in it]
+    finally:
+        gate.set()
+        loader.close()
+    assert len(got) == len(batches)
+    for rows, batch in zip(batches, got):
+        assert np.array_equal(batch, reference_batch(files, rows))
 
 
 def test_the_suites_random_4k_row_reads_through_windows(cluster):

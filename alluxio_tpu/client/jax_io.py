@@ -350,6 +350,82 @@ def _land_columns(table, columns: Sequence[str], rows: int):
     return values, valid
 
 
+class _ReadLane:
+    """One thread of :meth:`DeviceBlockLoader.row_groups`' host reads: a
+    single-thread executor, so the row groups of a file, all sent to one
+    lane, are read in order on one thread (its ``PlannedFile`` is never
+    shared), and that thread's stream cache, handed to the thread as it
+    starts so that retiring the lane can close it."""
+
+    def __init__(self, tls, k: int) -> None:
+        from concurrent.futures import ThreadPoolExecutor
+
+        self.streams: dict = {}
+
+        def init() -> None:
+            tls.streams = self.streams
+
+        self.pool = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix=f"loader-host-prefetch-read{k}",
+            initializer=init)
+
+
+class _TableFile:
+    """One file of a ``row_groups`` pass: the lane that reads it (None:
+    the producer thread does), its ``PlannedFile``, built by the read of
+    its first row group, and ``count``, its number of row groups, which
+    that read resolves as soon as it has the footer (None where the read
+    stopped or failed before)."""
+
+    __slots__ = ("path", "lane", "pfile", "count")
+
+    def __init__(self, path: str, lane: Optional[_ReadLane]) -> None:
+        from concurrent.futures import Future
+
+        self.path = path
+        self.lane = lane
+        self.pfile = None
+        self.count = Future()
+
+    def submit(self, fn, *args):
+        """``fn(*args)`` on the file's lane, or where there is none, on
+        the caller's thread when the returned callable is called; the
+        callable returns its result or raises its error."""
+        if self.lane is None:
+            return functools.partial(fn, *args)
+        return self.lane.pool.submit(fn, *args).result
+
+
+class _Overlaps:
+    """The host reads of one pass running now. ``running()`` yields a
+    read's record, whose ``beside`` is True once the read has run beside
+    another read of the pass for any part of its time: it began while
+    another ran, or another began while it ran."""
+
+    class _Read:
+        __slots__ = ("beside",)
+
+        def __init__(self) -> None:
+            self.beside = False
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._running: list = []
+
+    @contextlib.contextmanager
+    def running(self):
+        me = self._Read()
+        with self._lock:
+            for other in self._running:
+                other.beside = me.beside = True
+            self._running.append(me)
+        try:
+            yield me
+        finally:
+            with self._lock:
+                self._running.remove(me)
+
+
 class DeviceBlockLoader:
     """Loads whole blocks of one or more files as device-resident uint8
     arrays, with an HBM retention cache and transfer prefetch."""
@@ -445,6 +521,9 @@ class DeviceBlockLoader:
         # per epoch would miss the thread-local stream cache and reopen
         # every stream each epoch (fd/mmap leak over a training run)
         self._producer_pool = None
+        #: ``row_groups``' read lanes (:class:`_ReadLane`), kept with
+        #: their threads' stream caches across the passes that end whole
+        self._lanes: Optional[List[_ReadLane]] = None
         # at most one live epoch: starting a new one (or close()) cancels
         # the previous producer, else an abandoned-but-referenced
         # generator parks the single producer thread forever and
@@ -792,10 +871,10 @@ class DeviceBlockLoader:
         """A projection of the loader's Parquet files on the device, one
         :class:`RowGroup` a row group, files in the loader's order.
 
-        On the producer thread, for each row group: the footer when the
-        pass reaches its file (read through the thread's stream of the
-        file with the ``FileInfo`` the constructor resolved, and cached
-        as ``read_columns`` caches it: a job start pays one), then the
+        A row group's host read: the footer when the pass reaches its
+        file (read through the reading thread's stream of the file with
+        the ``FileInfo`` the constructor resolved, and cached as
+        ``read_columns`` caches it: a job start pays one), then the
         planned read that ``read_columns`` runs (``PlannedFile.fetch``:
         ``pread_ranges`` of the projection's coalesced ranges, zero-copy
         views on the same-host plane, each range made present and never
@@ -808,11 +887,28 @@ class DeviceBlockLoader:
         padded, the padding False in every mask and beyond ``num_rows``,
         so one program takes every item. A row group longer than
         ``rows`` fails the pass, as does a file the planner cannot plan
-        (``ParquetPlanError``). The consumer's side is :meth:`epoch`'s
-        (:meth:`_pipeline`): the bounded queue, ONE ``device_put`` a row
-        group, and closing the generator mid-pass retires the producer.
-        The HBM tier is not consulted: it keeps whole blocks, and a
-        projection reads a share of one."""
+        (``ParquetPlanError``).
+
+        Up to ``max(1, prefetch)`` row groups' host reads run at once,
+        on the loader's read lanes (:class:`_ReadLane`: threads named
+        ``loader-host-prefetch-read<k>``, file ``i`` on lane ``i %
+        depth``, so a file's row groups are read in order on one thread
+        and reads overlap across files), started at the first pass that
+        needs them and kept, with their streams, across passes that end
+        whole; with ``prefetch`` 0 the producer thread reads one row
+        group at a time itself. The pass's first row group is read
+        alone: no second read begins before its item is on the queue,
+        so a cold start's first item waits for one uncontended read.
+        The producer thread hands the items to the queue strictly in
+        the pass's order, and a failed read fails the pass at its place
+        in it, after the items before it. ``Client.JaxTableReadsOverlapped``
+        counts the row groups whose read ran beside another read of the
+        pass. The consumer's side is :meth:`epoch`'s (:meth:`_pipeline`):
+        the bounded queue, ONE ``device_put`` a row group. Closing the
+        generator mid-pass, a newer pass, a failed read and :meth:`close`
+        retire the lanes (a read not begun is cancelled, one running
+        ends) and close their streams. The HBM tier is not consulted: it
+        keeps whole blocks, and a projection reads a share of one."""
         from alluxio_tpu.table.reader import PlannedFile
 
         columns = [str(c) for c in columns]
@@ -827,59 +923,133 @@ class DeviceBlockLoader:
         n_group_bytes = m.counter("Client.JaxTableRowGroupBytes")
         #: reads pyarrow made outside the fetched ranges
         n_misses = m.counter("Client.JaxTablePlanMisses")
+        #: row groups whose host read ran beside another of the pass
+        n_overlapped = m.counter("Client.JaxTableReadsOverlapped")
         conf = self._fs.conf
+        depth = max(1, self._prefetch)
 
         def produce(put, stop):
             span = tracer().span
-            pad = rows
-            for path in self._paths:
-                pfile, index = None, 0
-                while pfile is None or index < len(pfile.plans):
+            overlaps = _Overlaps()
+
+            def read(f: _TableFile, index: int, pad: Optional[int]):
+                """Row group ``index`` of ``f`` read on the host:
+                ``(item, pad)``, or None where the file holds no row
+                group or the pass has stopped."""
+                try:
                     if stop.is_set():
-                        return
+                        return None
                     route: dict = {}
-                    with span("atpu.loader.host_read",
-                              row_group=index) as sp:
-                        if pfile is None:
-                            pfile = PlannedFile(
-                                self._stream(path), path, self._infos[path],
-                                columns, conf)
-                            if not pfile.plans:
-                                break
-                        plan = pfile.plans[index]
-                        pad = plan.num_rows if pad is None else pad
-                        if plan.num_rows > pad:
-                            raise ValueError(
-                                f"row group {plan.index} of {path} holds "
-                                f"{plan.num_rows} rows, more than the "
-                                f"pass's {pad}")
-                        if sp is not None:
-                            sp.tags["rows"] = str(plan.num_rows)
-                        pfile.fetch(plan, route)
-                        misses = pfile.src.misses
-                        table = pfile.decode([plan])
-                        with span("atpu.table.land", rows=pad,
-                                  columns=len(columns)):
-                            values, valid = _land_columns(table, columns,
-                                                          pad)
-                        del table
-                    group = pfile.metadata.row_group(plan.index)
-                    n_groups.inc()
-                    n_rows.inc(plan.num_rows)
-                    n_fetched.inc(sum(n for _off, n in plan.reads))
-                    n_group_bytes.inc(sum(
-                        group.column(c).total_compressed_size
-                        for c in range(group.num_columns)))
-                    n_misses.inc(pfile.src.misses - misses)
-                    item = RowGroup(values, valid, np.int32(plan.num_rows))
-                    put((None, item, False,
-                         "shm" if "shm" in route else
-                         "remote" if route else "unknown",
-                         sum(a.nbytes for a in values.values())
-                         + sum(a.nbytes for a in valid.values())))
-                    index += 1
+                    with overlaps.running() as me:
+                        with span("atpu.loader.host_read",
+                                  row_group=index) as sp:
+                            if f.pfile is None:
+                                f.pfile = PlannedFile(
+                                    self._stream(f.path), f.path,
+                                    self._infos[f.path], columns, conf)
+                                f.count.set_result(len(f.pfile.plans))
+                                if not f.pfile.plans:
+                                    return None
+                            pfile = f.pfile
+                            plan = pfile.plans[index]
+                            pad = plan.num_rows if pad is None else pad
+                            if plan.num_rows > pad:
+                                raise ValueError(
+                                    f"row group {plan.index} of {f.path} "
+                                    f"holds {plan.num_rows} rows, more "
+                                    f"than the pass's {pad}")
+                            if sp is not None:
+                                sp.tags["rows"] = str(plan.num_rows)
+                            pfile.fetch(plan, route)
+                            misses = pfile.src.misses
+                            table = pfile.decode([plan])
+                            with span("atpu.table.land", rows=pad,
+                                      columns=len(columns)):
+                                values, valid = _land_columns(
+                                    table, columns, pad)
+                            del table
+                finally:
+                    if not f.count.done():
+                        f.count.set_result(None)
+                # no read begins beside this one any more: ``beside``
+                # is final
+                n_overlapped.inc(me.beside)
+                group = pfile.metadata.row_group(plan.index)
+                n_groups.inc()
+                n_rows.inc(plan.num_rows)
+                n_fetched.inc(sum(n for _off, n in plan.reads))
+                n_group_bytes.inc(sum(
+                    group.column(c).total_compressed_size
+                    for c in range(group.num_columns)))
+                n_misses.inc(pfile.src.misses - misses)
+                item = RowGroup(values, valid, np.int32(plan.num_rows))
+                return (None, item, False,
+                        "shm" if "shm" in route else
+                        "remote" if route else "unknown",
+                        sum(a.nbytes for a in values.values())
+                        + sum(a.nbytes for a in valid.values())), pad
+
+            lanes = self._table_lanes(depth) if depth > 1 else None
+            files = enumerate(self._paths)
+            f, index = None, 0  # the file being granted, its next group
+            pending: deque = deque()  # granted reads, in the pass's order
+            pad, granting, queued, whole = rows, True, False, False
+            try:
+                while not stop.is_set():
+                    # one read until the pass's first item is queued
+                    while granting and \
+                            len(pending) < (depth if queued else 1):
+                        if f is not None:
+                            # its first read resolves the count with the
+                            # footer; None: it failed, and raises in order
+                            count = f.count.result()
+                            granting = count is not None
+                            if index >= (count or 0):
+                                f = None
+                        if f is None and granting:
+                            i, path = next(files, (0, None))
+                            granting = path is not None
+                            if granting:
+                                f, index = _TableFile(
+                                    path, lanes[i % depth] if lanes
+                                    else None), 0
+                        if granting:
+                            pending.append(f.submit(read, f, index, pad))
+                            index += 1
+                    if not pending:
+                        whole = True
+                        return
+                    got = pending.popleft()()
+                    if got is not None:
+                        item, pad = got
+                        put(item)
+                        queued = True
+            finally:
+                if not whole:
+                    # stopped or failed: every read granted ends or is
+                    # cancelled, and the lanes' streams close
+                    self._retire_lanes()
 
         return self._pipeline(produce, tuple)
+
+    def _table_lanes(self, n: int) -> List[_ReadLane]:
+        """The loader's ``n`` read lanes for ``row_groups``, started by
+        the first pass that needs them."""
+        with self._epoch_lock:
+            if self._closed:
+                raise RuntimeError("loader is closed")
+            if self._lanes is None:
+                self._lanes = [_ReadLane(self._tls, k) for k in range(n)]
+            return self._lanes
+
+    def _retire_lanes(self) -> None:
+        """Join the read lanes, cancelling the reads not yet begun, and
+        close every stream their threads opened."""
+        with self._epoch_lock:
+            lanes, self._lanes = self._lanes, None
+        for lane in lanes or ():
+            lane.pool.shutdown(wait=True, cancel_futures=True)
+            self._close_streams_dict(lane.streams)
 
     def _pin_room(self) -> int:
         """The segments a windows pass may keep leased: a lease pins its
@@ -1182,6 +1352,7 @@ class DeviceBlockLoader:
             pool, self._producer_pool = self._producer_pool, None
         if pool is not None:
             pool.shutdown(wait=True)
+        self._retire_lanes()
         shm = getattr(self._fs.store, "shm", None)
         if shm is not None:
             # every windows pass has ended with its producer: what its

@@ -430,3 +430,335 @@ def test_narrow_decimals_land_as_their_integers(cluster):
         assert got.dtype == dtype, name
         assert np.array_equal(got, np.where(valid, want, 0)), name
         assert np.array_equal(np.asarray(item.valid[name]), valid), name
+
+
+# -- host reads in flight ---------------------------------------------------
+class _Reads:
+    """Wraps ``PlannedFile``'s constructor and ``fetch`` and
+    ``jax_io._land_columns`` to log each row group's read by ``(path,
+    row group)``: ``log`` holds ``("fetch", key)`` as a fetch begins and
+    ``("land_end", key)`` as a landing ends, in order. ``hold`` maps a
+    row group to another: its landing waits until the other's fetch has
+    begun (``timed_out`` lists the landings that gave up waiting, which
+    a pass that reads one row group at a time makes)."""
+
+    def __init__(self, monkeypatch, hold=None, timeout: float = 10.0):
+        from alluxio_tpu.client import jax_io
+        from alluxio_tpu.table.reader import PlannedFile
+
+        self.hold = dict(hold or {})
+        self.log, self.timed_out = [], []
+        self._lock = threading.Lock()
+        self._fetched: dict = {}
+        local = threading.local()
+        init, fetch, land = (PlannedFile.__init__, PlannedFile.fetch,
+                             jax_io._land_columns)
+
+        def init_(pf, stream, path, *a, **k):
+            init(pf, stream, path, *a, **k)
+            pf.test_path = path
+
+        def fetch_(pf, plan, *a, **k):
+            local.key = key = (pf.test_path, plan.index)
+            self.note("fetch", key)
+            self._event(key).set()
+            return fetch(pf, plan, *a, **k)
+
+        def land_(table, columns, rows):
+            key = local.key
+            if key in self.hold and \
+                    not self._event(self.hold[key]).wait(timeout):
+                self.timed_out.append(key)
+            try:
+                return land(table, columns, rows)
+            finally:
+                self.note("land_end", key)
+
+        monkeypatch.setattr(PlannedFile, "__init__", init_)
+        monkeypatch.setattr(PlannedFile, "fetch", fetch_)
+        monkeypatch.setattr(jax_io, "_land_columns", land_)
+
+    def _event(self, key) -> threading.Event:
+        with self._lock:
+            return self._fetched.setdefault(key, threading.Event())
+
+    def note(self, what: str, key) -> None:
+        with self._lock:
+            self.log.append((what, key))
+
+    def reset(self) -> None:
+        """A new pass: no fetch has begun."""
+        with self._lock:
+            self._fetched.clear()
+            self.log.clear()
+        self.timed_out.clear()
+
+
+def _overlapped() -> int:
+    return metrics().snapshot().get("Client.JaxTableReadsOverlapped", 0)
+
+
+#: (files, rows a file, rows a row group, the landing held, the fetch it
+#: waits for: (file, row group) each)
+OVERLAP_CASES = {
+    "one-group-a-file": (4, 600, 600, (1, 0), (2, 0)),
+    "groups-a-file": (3, 900, 300, (0, 2), (1, 0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OVERLAP_CASES))
+def test_two_reads_in_flight_land_the_reference_in_order(
+        cluster, monkeypatch, case):
+    """Prefetch 2: the landing of one row group waits for the next
+    one's fetch to begin, which only a second read in flight can do;
+    every item is still the reference's, in the pass's order."""
+    import jax
+
+    n_files, rows, group, (fa, ga), (fb, gb) = OVERLAP_CASES[case]
+    columns = ["date_sk", "key", "price"]
+    paths = _write(cluster, n_files, rows, group)
+    first, second = (paths[fa], ga), (paths[fb], gb)
+    reads = _Reads(monkeypatch, hold={first: second})
+    loader = DeviceBlockLoader(cluster.file_system(), paths, prefetch=2)
+    before = _counts()
+    try:
+        with jax.enable_x64(True):
+            items = list(loader.row_groups(columns))
+    finally:
+        loader.close()
+    assert reads.timed_out == []
+    assert reads.log.index(("fetch", second)) \
+        < reads.log.index(("land_end", first))
+    want = list(reference_groups(n_files, rows, group, columns, group))
+    assert len(items) == len(want)
+    for item, w in zip(items, want):
+        _assert_item(item, w)
+    d = _delta(before)
+    assert d["Client.JaxTableRowGroups"] == len(want)
+    assert d["Client.TableFooterReads"] == n_files  # one a file
+    assert d["Client.JaxTablePlanMisses"] == 0
+
+
+def test_the_first_row_group_of_every_pass_is_read_alone(cluster,
+                                                        monkeypatch):
+    """The first item's put is slow; no second fetch begins before it
+    is on the queue, in a cold pass and in one on warm lanes."""
+    import time
+
+    paths = _write(cluster, 4, 300, 300)
+    reads = _Reads(monkeypatch)
+    loader = DeviceBlockLoader(cluster.file_system(), paths, prefetch=2)
+    real_put, puts = loader._put, []
+
+    def put(q, stop, item):
+        if not puts:
+            time.sleep(0.3)  # the first item, slow to the queue
+        real_put(q, stop, item)
+        puts.append(1)
+        reads.note("put", len(puts))
+
+    loader._put = put
+    try:
+        for _ in range(2):
+            reads.reset()
+            puts.clear()
+            assert len(list(loader.row_groups(["key"]))) == 4
+            fetches = [i for i, (what, _k) in enumerate(reads.log)
+                       if what == "fetch"]
+            assert len(fetches) == 4
+            assert reads.log.index(("put", 1)) < fetches[1]
+    finally:
+        loader.close()
+
+
+def test_a_loader_with_prefetch_0_reads_one_row_group_at_a_time(cluster):
+    import jax
+
+    paths = _write(cluster, 3, 600, 300)
+    before = _overlapped()
+    set_tracing_enabled(True)
+    tracer().clear()
+    try:
+        loader = DeviceBlockLoader(cluster.file_system(), paths,
+                                   prefetch=0)
+        try:
+            with jax.enable_x64(True):
+                items = list(loader.row_groups(["key", "ticket"]))
+            assert loader._lanes is None
+        finally:
+            loader.close()
+        spans = tracer().snapshot(limit=10_000,
+                                  prefix="atpu.loader.host_read")
+    finally:
+        set_tracing_enabled(False)
+        tracer().clear()
+    want = list(reference_groups(3, 600, 300, ["key", "ticket"], 300))
+    assert len(items) == len(want) == len(spans) == 6
+    for item, w in zip(items, want):
+        _assert_item(item, w)
+    assert _overlapped() == before
+    # every read on the producer thread, each ended before the next
+    assert len({s["thread"] for s in spans}) == 1
+    spans.sort(key=lambda s: s["start_ns"])
+    for a, b in zip(spans, spans[1:]):
+        assert a["start_ns"] + a["duration_ms"] * 1e6 <= b["start_ns"]
+
+
+def test_the_overlap_counter_counts_every_row_group_but_a_passs_first(
+        cluster, monkeypatch):
+    """Files of one row group: each landing from the second to the last
+    but one waits for the next fetch, so every read after the first runs
+    beside another, on cold lanes and on warm ones."""
+    n = 5
+    paths = _write(cluster, n, 300, 300)
+    reads = _Reads(monkeypatch, hold={(paths[k], 0): (paths[k + 1], 0)
+                                      for k in range(1, n - 1)})
+    loader = DeviceBlockLoader(cluster.file_system(), paths, prefetch=2)
+    try:
+        for _ in range(2):
+            reads.reset()
+            before, groups = _overlapped(), _counts()
+            assert len(list(loader.row_groups(["key"]))) == n
+            assert reads.timed_out == []
+            assert _overlapped() - before == n - 1
+            assert _delta(groups)["Client.JaxTableRowGroups"] == n
+    finally:
+        loader.close()
+
+
+def test_a_failed_read_fails_the_pass_after_the_items_before_it(cluster):
+    """The third row group's read (the second of two in flight) fails
+    once the consumer holds the two before it: the pass hands out those
+    two, then raises the read's error."""
+    import jax
+
+    paths = _write(cluster, 4, 300, 300)
+    loader = DeviceBlockLoader(cluster.file_system(), paths, prefetch=2)
+    real, gate = loader._stream, threading.Event()
+
+    def stream(path):
+        if path == paths[2]:
+            gate.wait(10)
+            raise OSError("injected read failure")
+        return real(path)
+
+    loader._stream = stream
+    try:
+        with jax.enable_x64(True):
+            it = loader.row_groups(["key", "ticket"])
+            got = [next(it), next(it)]
+            gate.set()
+            with pytest.raises(OSError, match="injected read failure"):
+                next(it)
+    finally:
+        gate.set()
+        loader.close()
+    want = list(reference_groups(2, 300, 300, ["key", "ticket"], 300))
+    for item, w in zip(got, want):
+        _assert_item(item, w)
+    assert not [t for t in _readers() if t.is_alive()]
+
+
+def _readers() -> list:
+    return [t for t in threading.enumerate()
+            if t.name.startswith("loader-host-prefetch-read")]
+
+
+def _held_fetches(monkeypatch):
+    """Every fetch after the pass's first waits for the returned
+    ``release`` event; ``entered`` is released once a held fetch
+    begins."""
+    from alluxio_tpu.table.reader import PlannedFile
+
+    real, calls = PlannedFile.fetch, []
+    entered, release = threading.Semaphore(0), threading.Event()
+
+    def fetch(pf, *a, **k):
+        calls.append(1)
+        if len(calls) > 1 and not release.is_set():
+            entered.release()
+            release.wait(10)
+        return real(pf, *a, **k)
+
+    monkeypatch.setattr(PlannedFile, "fetch", fetch)
+    return entered, release
+
+
+def test_closing_with_two_reads_in_flight_retires_every_thread(
+        cluster, monkeypatch):
+    paths = _write(cluster, 4, 300, 300)
+    entered, release = _held_fetches(monkeypatch)
+    loader = DeviceBlockLoader(cluster.file_system(), paths, prefetch=2)
+    try:
+        it = loader.row_groups(["key"])
+        next(it)
+        # the second and third row groups' reads are both in their fetch
+        assert entered.acquire(timeout=10) and entered.acquire(timeout=10)
+        assert len([t for t in _readers() if t.is_alive()]) == 2
+        threading.Timer(0.2, release.set).start()
+        it.close()
+        assert loader._producer_pool is None
+        assert loader._lanes is None
+        assert not [t for t in _producers() if t.is_alive()]
+        # the loader is whole: a pass after it reads every row group
+        assert len(list(loader.row_groups(["key"]))) == 4
+    finally:
+        release.set()
+        loader.close()
+
+
+@pytest.mark.parametrize("when", ["after-a-pass", "mid-pass",
+                                  "superseded"])
+def test_no_stream_of_a_read_lane_outlives_its_lane(cluster, monkeypatch,
+                                                    when):
+    """The streams the read lanes open stay open across whole passes
+    and are closed by ``loader.close()`` (after a pass, or with reads in
+    flight) or by a newer pass that retires them."""
+    paths = _write(cluster, 4, 300, 300)
+    fs = cluster.file_system()
+    real_open, opened = fs.open_file, []
+
+    def open_file(*a, **k):
+        s = real_open(*a, **k)
+        real_close, closed = s.close, []
+
+        def close():
+            closed.append(1)
+            real_close()
+
+        s.close = close
+        opened.append((threading.current_thread(), closed))
+        return s
+
+    monkeypatch.setattr(fs, "open_file", open_file)
+    entered, release = _held_fetches(monkeypatch)
+    if when != "mid-pass":
+        release.set()
+    loader = DeviceBlockLoader(fs, paths, prefetch=2)
+    try:
+        it = loader.row_groups(["key"])
+        if when == "mid-pass":
+            next(it)
+            assert entered.acquire(timeout=10)
+            threading.Timer(0.2, release.set).start()
+        else:
+            assert len(list(it)) == 4
+        lanes = [(t, c) for t, c in opened
+                 if t.name.startswith("loader-host-prefetch-read")]
+        assert lanes
+        if when == "after-a-pass":
+            assert not any(c for _t, c in lanes)  # kept for the next pass
+        if when == "superseded":
+            it = loader.row_groups(["key"])
+            next(it)  # a pass that began after a whole one keeps its lanes
+            assert not any(c for _t, c in lanes)
+            assert len(list(loader.row_groups(["key"]))) == 4
+            lanes = [(t, c) for t, c in lanes if not t.is_alive()]
+            assert lanes  # the superseded pass's lanes are gone
+        else:
+            loader.close()
+            assert not [t for t in _readers() if t.is_alive()]
+        assert all(c for _t, c in lanes)
+    finally:
+        release.set()
+        loader.close()
